@@ -61,8 +61,8 @@ benched figures exercise the insample path on device.  fig7a additionally
 *gates* on python-vs-batch parity: each (method, fraction) cell's mean
 wastage must agree within 5% or the run fails (the CI smoke canary).  The
 cluster benches keep the "progressive" mode.
-``REPRO_PALLAS_INTERPRET=0`` additionally switches the ``kernels`` bench to
-the compiled Pallas path on TPU hosts (see repro.kernels.ops).
+The ``kernels`` bench runs the Pallas kernels compiled on a TPU and in
+interpret mode on the CPU backend (see repro.kernels.ops).
 
 ``cluster`` itself picks between two batched placement engines
 (``run_cluster_batched(placement=...)``) by a measured per-row cost model
@@ -82,11 +82,10 @@ windows engine, gated on the compaction contract (deep per-row cost
 within ``_SWEEP_DEEP_MAX_RATIO`` of the shallow sweep's, carried
 breakpoint high-water recorded per lane).
 
-The persistent XLA compile cache is ON by default for every bench run
-(``repro.compat.enable_compile_cache``; dir ``~/.cache/repro-xla``, override
-with ``REPRO_COMPILE_CACHE=<dir>``, disable with ``REPRO_COMPILE_CACHE=off``)
-— the cluster variants' ~45 s cold compile otherwise dominates any fresh
-run.  Each cluster variant records the hits observed during its cold
+The persistent XLA compile cache is ON for every bench run
+(``repro.compat.enable_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` where
+set, else the git-ignored ``.jax_cache/`` in the checkout) — the cluster
+variants' ~45 s cold compile otherwise dominates any fresh run.  Each cluster variant records the hits observed during its cold
 section (``hits_cold``, non-zero on a cache-warm rerun) and ``hits_warm``:
 the hits serving a from-scratch re-lowering of the variant's programs
 after the in-process executable caches are dropped (``jax.clear_caches``)
@@ -179,16 +178,13 @@ def _audit_payload(cc, name: str, enforce: bool) -> dict:
 
 
 def _enable_compile_cache() -> None:
-    """Turn the persistent XLA compile cache ON (default ~/.cache/repro-xla;
-    ``REPRO_COMPILE_CACHE=off|0`` opts out) and start counting cache hits.
-    Must run before any bench compiles — main() calls it first."""
+    """Turn the persistent XLA compile cache ON (``JAX_COMPILATION_CACHE_DIR``
+    where set, else the checkout's ``.jax_cache/``) and start counting cache
+    hits.  Must run before any bench compiles — main() calls it first."""
     global COMPILE_CACHE_DIR, _CACHE_LISTENING
     from repro.compat import enable_compile_cache
 
-    path = os.environ.get("REPRO_COMPILE_CACHE", "~/.cache/repro-xla")
-    if path.lower() in ("", "0", "off", "none"):
-        return
-    COMPILE_CACHE_DIR = enable_compile_cache(path)
+    COMPILE_CACHE_DIR = enable_compile_cache()
     try:
         from jax._src import monitoring
     except ImportError:  # a future jax moving the private module: run uncounted
@@ -827,13 +823,14 @@ def _cluster_variant(name: str, policies: tuple[str, ...], kw: dict) -> dict:
                 row["wall_s"] = round(py_wall[p], 4)
             rows.append(row)
     # the default policy makes identical decisions on identical allocations
-    # in both engines, so with f64 device-side wastage accumulation its
-    # wastage must agree BIT FOR BIT with the sequential oracle (the other
-    # policies' residues come from f32 prediction paths, not accumulation)
+    # in both engines, so its wastage differs from the sequential oracle's
+    # float64 sums only by the device ladder's float32 per-attempt
+    # accumulation (measured 9e-9 relative on the full corpus; the other
+    # policies' residues also come from f32 prediction paths)
     if "default" in policies:
         wp, wb = res_py["default"].wastage_gib_s, res_b["default"].wastage_gib_s
-        if wp != wb:
-            _fail(f"cluster/{name}: default-policy wastage not bit-equal across engines ({wp!r} != {wb!r})")
+        if not np.isclose(wp, wb, rtol=1e-6, atol=0.0):
+            _fail(f"cluster/{name}: default-policy wastage differs across engines ({wp!r} != {wb!r})")
     _row(
         f"cluster/{name}/placement_program",
         place_stats.get("program_wall_s", 0.0) * 1e6 / max(place_stats.get("program_calls", 1), 1),

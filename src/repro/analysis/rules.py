@@ -67,10 +67,10 @@ RULES: dict[str, str] = {
         "(e.g. jnp.float64 if x64 else jnp.float32)"
     ),
     "RA005": (
-        "raw jax.experimental.enable_x64 import: use the shared "
-        "device_timeline._x64_ctx, which no-ops when x64 is already the "
-        "global default instead of re-entering the config context (and "
-        "keeps one trace-context story for the jit caches)"
+        "jax.experimental.enable_x64 is gone from the installed jax: use the "
+        "jax.enable_x64 context, and only in CPU reference code — no device "
+        "program of the main path runs in float64 (the TPU cannot compile "
+        "its f64 ops)"
     ),
     "RA006": (
         "Python control flow on a tracer-valued test inside a traced body: "
@@ -196,7 +196,7 @@ class _Checker:
         for node in ast.walk(self.tree):
             if isinstance(node, ast.ImportFrom):
                 for al in node.names:
-                    if al.name in ("enable_x64", "_x64_ctx"):
+                    if al.name == "enable_x64":
                         return True
             elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 all_args = node.args.args + node.args.kwonlyargs
@@ -233,17 +233,14 @@ class _Checker:
 
     # -- imports (RA005) --
 
-    def _ra005_allowed(self) -> bool:
-        return self.path.replace("\\", "/").endswith("device_timeline.py")
-
     def _visit_ImportFrom(self, node: ast.ImportFrom):
-        if node.module == "jax.experimental" and not self._ra005_allowed():
+        if node.module == "jax.experimental":
             for al in node.names:
                 if al.name == "enable_x64":
                     self._emit(
                         "RA005",
                         node,
-                        "raw enable_x64 import; use device_timeline._x64_ctx",
+                        "jax.experimental.enable_x64 import; use the jax.enable_x64 context",
                     )
         self._generic(node)
 
@@ -315,12 +312,9 @@ class _Checker:
                     "lax.cummax / lax.associative_scan",
                 )
         full = self._dotted(node)
-        if (
-            full == "jax.experimental.enable_x64"
-            and not self._ra005_allowed()
-        ):
+        if full == "jax.experimental.enable_x64":
             self._emit(
-                "RA005", node, "raw enable_x64 use; use device_timeline._x64_ctx"
+                "RA005", node, "jax.experimental.enable_x64 use; use the jax.enable_x64 context"
             )
         # RA004: hard-coded f32 inside an x64-laddered function body.
         if (

@@ -55,6 +55,7 @@ class CompileCounter:
     def __init__(self) -> None:
         self.traces = 0
         self.compiles = 0
+        self.compile_s = 0.0  # summed backend-compile durations
         self.cache_hits = 0
         self.cache_misses = 0
         self._active = False
@@ -66,6 +67,7 @@ class CompileCounter:
             self.traces += 1
         elif event == COMPILE_EVENT:
             self.compiles += 1
+            self.compile_s += duration
 
     def _on_event(self, event: str, **kw: Any) -> None:
         if not self._active:
@@ -157,7 +159,7 @@ class ConstReport:
 
 
 def _sub_jaxprs(params: dict) -> Iterator[Any]:
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     for v in params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
@@ -270,7 +272,7 @@ def closure_constants(
                 )
         jaxpr = getattr(item, "jaxpr", item)
         for eqn in getattr(jaxpr, "eqns", ()):
-            import jax.core as jcore
+            import jax.extend.core as jcore
 
             for v in eqn.params.values():
                 vals = v if isinstance(v, (list, tuple)) else (v,)

@@ -1,75 +1,30 @@
-"""Version-compat shims for the pinned jax.
+"""Device-mesh and compilation-cache helpers shared by the entry points.
 
-The container pins jax 0.4.37, which predates the mesh-context API the
-model/launch code targets (``jax.set_mesh`` / ``jax.sharding.use_mesh`` /
-``jax.sharding.get_abstract_mesh``).  Every mesh-context read or entry in
-this repo goes through this module so the same source runs on both API
-generations:
-
-* on new jax the shims delegate to the real functions;
-* on 0.4.x they fall back to the thread-local resource env (``with mesh:``
-  — the legacy ``Mesh`` context manager — and its ``physical_mesh``), which
-  carries the same axis names/shape the sharding helpers consume.
-
-Also home to the opt-in persistent compilation cache: the batched engines
-compile ~12 bucket shapes (~20 s cold on CPU); with ``REPRO_COMPILE_CACHE``
-set to a directory, XLA executables persist across processes and a warm
-process deserializes them instead of recompiling (see
-tests/test_compile_cache.py).
+* ``device_mesh`` — the one 1-D mesh constructor for data-parallel
+  ``jax.shard_map`` callers (the sharded admission control plane, the
+  multi-device smoke checks).
+* ``enable_compile_cache`` — JAX's persistent compilation cache, turned on
+  by the entry points (``chip_smoke.py``, ``benchmarks/run.py``), never on
+  import.
 """
 
 from __future__ import annotations
 
 import os
+import pathlib
 
 import jax
 
-
-def get_abstract_mesh():
-    """The ambient mesh: ``jax.sharding.get_abstract_mesh()`` when it exists,
-    else the 0.4.x thread-local physical mesh (an *empty* ``Mesh`` outside
-    any mesh context — callers check ``mesh.empty``, which both objects
-    provide, as well as ``axis_names`` / ``shape``)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh for sharding
-    constraints: ``jax.sharding.use_mesh`` / ``jax.set_mesh`` when present;
-    on 0.4.x the ``Mesh`` object itself (its legacy context manager installs
-    the resource env that ``with_sharding_constraint`` consults)."""
-    fn = getattr(jax.sharding, "use_mesh", None) or getattr(jax, "set_mesh", None)
-    if fn is not None:
-        return fn(mesh)
-    return mesh
-
-
-# The newer spelling some call sites prefer; identical semantics here.
-set_mesh = use_mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` when it exists, else the 0.4.x experimental one
-    (same call contract for the keyword form the model code uses)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+# The in-checkout cache directory (git-ignored) used when the environment
+# does not place the cache itself.  Fixed: the path is part of the cache
+# key, so a directory that moved would never hit.
+CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def device_mesh(n_dev: int, axis: str = "shards"):
     """A 1-D ``Mesh`` over the first ``n_dev`` visible devices.
 
-    The one mesh constructor for data-parallel ``shard_map`` callers (the
-    sharded admission control plane, the multi-device smoke canaries) —
-    kept here so CPU emulation via ``--xla_force_host_platform_device_count``
+    Kept here so CPU emulation via ``--xla_force_host_platform_device_count``
     and real multi-device runs build meshes identically.  Raises if fewer
     than ``n_dev`` devices are visible rather than silently wrapping."""
     import numpy as np
@@ -81,22 +36,18 @@ def device_mesh(n_dev: int, axis: str = "shards"):
     return Mesh(np.asarray(devs[:n_dev]), (axis,))
 
 
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Point jax's persistent compilation cache at ``path`` (default: the
-    ``REPRO_COMPILE_CACHE`` env var; no-op when neither is set).  Thresholds
-    drop to zero so even sub-second bucket programs are cached — the batched
-    engines' cold start is dominated by many small compiles, not one big
-    one.  Returns the cache directory actually enabled, or None.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-    Caveat (jax 0.4.37, XLA:CPU): executables jitted with ``donate_argnums``
-    must not run in a process with this cache enabled — donated buffers
-    corrupt the heap and the process later dies in unrelated native code
-    (see Trainer._step_fn, which drops donation on the CPU backend)."""
-    path = path or os.environ.get("REPRO_COMPILE_CACHE")
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets no directory of its own; otherwise the cache goes to
+    ``CHECKOUT_CACHE_DIR`` inside the checkout.  Thresholds drop to zero so
+    even sub-second bucket programs are cached — the batched engines' cold
+    start is many small compiles, not one big one."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        return None
-    path = os.path.expanduser(path)  # env vars arrive tilde-unexpanded (CI sets ~/.cache/...)
-    jax.config.update("jax_compilation_cache_dir", path)
+        path = str(CHECKOUT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path

@@ -27,7 +27,9 @@ from repro.core.timeline import (  # noqa: F401  (re-exports)
     Timeline,
     demand_exceeds,
     demand_exceeds_many,
+    pack_steps,
     plan_profile_events,
+    quantize_steps,
     shared_probe_set,
     step_demand_profile,
 )
@@ -114,22 +116,6 @@ def score_attempt_np(series_mib: np.ndarray, interval_s: float, alloc: StepAlloc
     alloc_int = float(np.sum(a) * interval_s)
     waste = float(np.sum(a - y) * interval_s)
     return AttemptOutcome(False, -1, waste / MIB_PER_GIB, alloc_int / MIB_PER_GIB)
-
-
-def pack_step_allocations(allocs: list[StepAllocation]) -> tuple[np.ndarray, np.ndarray]:
-    """Pad R step allocations into the layout ``step_demand_profile``
-    consumes: (R, kmax) inf-padded boundaries and (R, kmax + 1) hold-last
-    values (the extra column is the value held past the final boundary)."""
-    R = len(allocs)
-    kmax = max((a.k for a in allocs), default=1)
-    bnd = np.full((R, kmax), np.inf)
-    val = np.empty((R, kmax + 1))
-    for r, a in enumerate(allocs):
-        kk = a.k
-        bnd[r, :kk] = a.boundaries
-        val[r, :kk] = a.values
-        val[r, kk:] = a.values[-1]
-    return bnd, val
 
 
 @dataclasses.dataclass

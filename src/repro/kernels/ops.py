@@ -1,15 +1,17 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles padding to TPU tile multiples, sentinel finalization, and backend
-selection: on CPU (this container) the kernels execute in interpret mode,
-which runs the exact kernel bodies in Python — the TPU lowering is identical
-code with ``interpret=False``.
+selection: on a TPU every kernel runs compiled; on the CPU backend (the
+test suite) the same kernel bodies run in interpret mode.  There is no
+switch between the two and no silent fallback to a jnp twin: a kernel on
+the main path runs in that path's own dtype, and an input it cannot take
+raises.  ``kernel_traces`` reports which kernels were lowered and how.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -23,18 +25,29 @@ from repro.kernels import wastage as _wastage
 MIB_PER_GIB = 1024.0
 
 
-def _use_interpret() -> bool:
-    """Backend selection for the Pallas kernels.
+_TRACED: collections.Counter = collections.Counter()  # (kernel, mode) -> traces
 
-    ``REPRO_PALLAS_INTERPRET=1|0`` overrides; otherwise interpret mode is the
-    default everywhere except on a real TPU (where the compiled lowering
-    runs).  Resolved outside the jitted wrappers on every call, so flipping
-    the env var mid-process retraces through the static ``interpret`` arg.
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in ("0", "false", "no", "off")
-    return jax.default_backend() != "tpu"
+
+def _use_interpret(kernel: str) -> bool:
+    """Interpret mode on the CPU backend only; compiled everywhere else.
+    Counts the call (a trace, inside a jitted program) under ``kernel``."""
+    interpret = jax.default_backend() == "cpu"
+    _TRACED[(kernel, "interpret" if interpret else "compiled")] += 1
+    return interpret
+
+
+def kernel_traces() -> dict[tuple[str, str], int]:
+    """``(kernel, mode) -> count`` of the kernel wrappers this process has
+    traced or called, mode "compiled" or "interpret": what ``chip_smoke.py``
+    diffs per phase to show that the main path's kernels ran compiled."""
+    return dict(_TRACED)
+
+
+def _check_kernel_input(kernel: str, x: jax.Array) -> None:
+    """The kernels take 2-D int32 or float32 rows; anything else is a caller
+    bug, not a reason to leave the kernel path."""
+    if x.ndim != 2 or x.dtype not in (jnp.int32, jnp.float32):
+        raise TypeError(f"{kernel} takes 2-D int32/float32 rows, got {x.dtype}{x.shape}")
 
 
 def _pad_rows(a: jax.Array, mult: int, fill=0):
@@ -54,17 +67,13 @@ def _pad_cols(a: jax.Array, mult: int, fill=0):
     return jnp.pad(a, [(0, 0), (0, pad)], constant_values=fill)
 
 
-def segment_peaks(y: jax.Array, lengths: jax.Array, k: int, *, interpret: bool | None = None) -> jax.Array:
+def segment_peaks(y: jax.Array, lengths: jax.Array, k: int) -> jax.Array:
     """(B, T) padded series + (B,) lengths -> (B, k) segment peaks.
 
     Matches ``core.segmentation.segment_peaks`` (the jnp oracle): empty
     segments inherit the running peak from the left.
     """
-    # Resolve the backend OUTSIDE the jit so the env override participates in
-    # the cache key (resolving inside the traced body would pin the first
-    # call's choice forever).
-    interpret = _use_interpret() if interpret is None else interpret
-    return _segment_peaks_jit(y, lengths, k, interpret=interpret)
+    return _segment_peaks_jit(y, lengths, k, interpret=_use_interpret("segment_peaks"))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -83,13 +92,12 @@ def _segment_peaks_jit(y: jax.Array, lengths: jax.Array, k: int, *, interpret: b
     return jnp.where(out <= jnp.float32(-1.0e38), 0.0, out)
 
 
-def fit_stats(x: jax.Array, peaks: jax.Array, valid: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+def fit_stats(x: jax.Array, peaks: jax.Array, valid: jax.Array) -> jax.Array:
     """(B,) inputs + (B, k) segment peaks + (B,) mask -> (k, 5) OLS bank.
 
     ``x`` should be pre-shifted (u = x - x0) for f32 conditioning.
     """
-    interpret = _use_interpret() if interpret is None else interpret
-    return _fit_stats_jit(x, peaks, valid, interpret=interpret)
+    return _fit_stats_jit(x, peaks, valid, interpret=_use_interpret("fit_stats"))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -106,15 +114,14 @@ def attempt_wastage(
     bounds: jax.Array,
     values: jax.Array,
     interval_s: float,
-    *,
-    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch attempt scoring -> (wastage GiB*s (B,), failure index (B,), -1 on success).
 
     Matches ``core.allocation.attempt_outcomes_batch`` / ``score_attempt_np``.
     """
-    interpret = _use_interpret() if interpret is None else interpret
-    return _attempt_wastage_jit(y, lengths, bounds, values, interval_s, interpret=interpret)
+    return _attempt_wastage_jit(
+        y, lengths, bounds, values, interval_s, interpret=_use_interpret("attempt_wastage")
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interval_s", "interpret"))
@@ -139,48 +146,45 @@ def _attempt_wastage_jit(
     return waste, fail_idx
 
 
-def range_max_table(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
-    """(..., B, L) demand rows -> (..., B, P, L) sparse-table range-max levels.
+def range_max_table(x: jax.Array) -> jax.Array:
+    """(B, L) demand rows -> (B, P, L) sparse-table range-max levels.
 
     ``out[..., p, i] = max(x[..., i : i + 2**p])`` — the doubling table the
     scheduling programs' wait probes query in O(log L) (two lookups per
     window; see ``sim.device_timeline``).  Padded/masked positions should
-    carry -inf (the max identity).
-
-    Float32 inputs route through the Pallas kernel (padded to tile
-    multiples); float64 — the scheduling programs' working precision, which
-    the TPU kernel cannot hold — uses the jnp twin, bit-identical by
-    construction (both are the same max/shift recurrence).  Safe to call
-    from inside traced programs: dispatch happens at trace time.
+    carry the dtype's max identity (``rangemax.max_identity``).  int32 (the
+    scheduling programs' demand sums) and float32 rows run the Pallas kernel
+    in their own dtype; anything else raises.  Safe to call from inside
+    traced programs: dispatch happens at trace time.
     """
-    if x.dtype != jnp.float32 or x.ndim != 2:
-        return _rangemax.table_levels_jnp(x)
-    interpret = _use_interpret() if interpret is None else interpret
-    return _range_max_table_jit(x, interpret=interpret)
+    _check_kernel_input("range_max_table", x)
+    return _range_max_table_jit(x, interpret=_use_interpret("range_max_table"))
 
 
-def compact_events(
-    tl_t: jax.Array, tl_d: jax.Array, keep: jax.Array, *, interpret: bool | None = None
-) -> tuple[jax.Array, jax.Array]:
+def compact_events(tl_t: jax.Array, tl_d: jax.Array, keep: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(N, L) sorted event rows + keep mask -> rows with the kept entries
-    moved to the front (order preserved) and (+inf, 0) identities behind.
+    moved to the front (order preserved) and (``NEVER`` or +inf, 0)
+    identities behind.
 
     The sweep program's chunk-boundary compaction step
     (``sim.device_timeline._sweep_lane``): the keep mask marks the
     demand-shape-changing breakpoints, everything else is dropped so the
-    carried axis stays sized by live breakpoints.  A pure permutation in
-    both backends — no kept value is recomputed.
-
-    Float32 inputs route through the Pallas kernel (padded to tile
-    multiples); float64 — the scheduling programs' working precision, which
-    the TPU kernel cannot hold — uses the jnp rank-scatter twin,
-    bit-identical by construction.  Safe to call from inside traced
-    programs: dispatch happens at trace time.
+    carried axis stays sized by live breakpoints.  A pure permutation — no
+    kept value is recomputed.  int32 key rows (the scheduling programs') and
+    float32 rows run the Pallas kernel in their own dtype; anything else
+    raises.  Safe to call from inside traced (and vmapped) programs:
+    dispatch happens at trace time.
     """
-    if tl_t.dtype != jnp.float32 or tl_t.ndim != 2:
-        return _compaction.compact_events_jnp(tl_t, tl_d, keep)
-    interpret = _use_interpret() if interpret is None else interpret
-    return _compact_events_jit(tl_t, tl_d, keep, interpret=interpret)
+    _check_kernel_input("compact_events", tl_t)
+    return _compact_events_jit(tl_t, tl_d, keep, interpret=_use_interpret("compact_events"))
+
+
+def _time_fill(dtype):
+    """The empty-slot time of a timeline row: ``NEVER`` for int32 keys, +inf
+    for float rows."""
+    from repro.core.timeline import NEVER
+
+    return NEVER if jnp.issubdtype(dtype, jnp.integer) else float("inf")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -188,10 +192,11 @@ def _compact_events_jit(
     tl_t: jax.Array, tl_d: jax.Array, keep: jax.Array, *, interpret: bool
 ) -> tuple[jax.Array, jax.Array]:
     B, L = tl_t.shape
-    tp = _pad_cols(_pad_rows(tl_t, _compaction.BLOCK_B, fill=jnp.inf), _compaction.LANE, fill=jnp.inf)
+    fill = _time_fill(tl_t.dtype)
+    tp = _pad_cols(_pad_rows(tl_t, _compaction.BLOCK_B, fill=fill), _compaction.LANE, fill=fill)
     dp = _pad_cols(_pad_rows(tl_d, _compaction.BLOCK_B), _compaction.LANE)
     kp = _pad_cols(_pad_rows(keep.astype(jnp.int32), _compaction.BLOCK_B), _compaction.LANE)
-    t2, d2 = _compaction.compact_pallas(tp, dp, kp, interpret=interpret)
+    t2, d2 = _compaction.compact_pallas(tp, dp, kp, fill, interpret=interpret)
     # kept counts never exceed L, so the compacted prefix fits the caller's axis
     return t2[:B, :L], d2[:B, :L]
 
@@ -199,9 +204,10 @@ def _compact_events_jit(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _range_max_table_jit(x: jax.Array, *, interpret: bool) -> jax.Array:
     B, L = x.shape
-    xp = _pad_cols(_pad_rows(x, _rangemax.BLOCK_B, fill=-jnp.inf), _rangemax.LANE, fill=-jnp.inf)
+    neg = _rangemax.max_identity(x.dtype)
+    xp = _pad_cols(_pad_rows(x, _rangemax.BLOCK_B, fill=neg), _rangemax.LANE, fill=neg)
     P = _rangemax.num_levels(L)
     out = _rangemax.rangemax_pallas(xp, interpret=interpret)[:B]
     # the padded axis may add levels the caller's L never needs; the first P
-    # levels are span-identical because the pad region is the -inf identity
+    # levels are span-identical because the pad region is the max identity
     return out[:, :P, :L]

@@ -15,10 +15,10 @@ under the lane budget), so all ``P = floor(log2(L)) + 1`` levels are
 computed from one HBM read per row: each level is a circular lane roll of
 the previous one, masked past the row end with the -inf identity.
 
-The jnp twin (``table_levels_jnp``) is the same recurrence in any dtype;
-the float64 scheduling programs use it directly (``nextafter`` switch
-instants sit below float32 resolution), while float32 callers route through
-the kernel (``ops.range_max_table``).
+The kernel keeps its input dtype (the scheduling programs' int32 demand
+sums, or float32), with the dtype's max identity as the fill.  The jnp twin
+(``table_levels_jnp``) is the same recurrence, the reference the tests
+compare the kernel with.
 """
 
 from __future__ import annotations
@@ -34,7 +34,15 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_B = 8
 LANE = 128
 
-_NEG = float("-inf")  # max identity (plain float: jnp consts would be captured)
+
+
+def max_identity(dtype):
+    """The max identity of ``dtype`` as a plain Python scalar (jnp constants
+    would be captured): -inf for floats, the minimum for integers."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return float("-inf")
+    return int(jnp.iinfo(dtype).min)
 
 
 def num_levels(L: int) -> int:
@@ -51,11 +59,11 @@ def table_levels_jnp(x: jax.Array) -> jax.Array:
     runs past the end hold the max of the in-range suffix (queries never
     read them with a longer span than the window, so the tail values only
     need to be <= the true max over any window containing them — which a
-    -inf fill guarantees).
+    max-identity fill guarantees).
     """
     L = x.shape[-1]
     P = num_levels(L)
-    neg = jnp.asarray(_NEG, x.dtype)
+    neg = jnp.asarray(max_identity(x.dtype), x.dtype)
     levels = [x]
     span = 1
     for _ in range(1, P):
@@ -67,7 +75,7 @@ def table_levels_jnp(x: jax.Array) -> jax.Array:
     return jnp.stack(levels, axis=-2)
 
 
-def _rangemax_kernel(x_ref, out_ref, *, P: int, L: int):
+def _rangemax_kernel(x_ref, out_ref, *, P: int, L: int, neg):
     """Grid (B/BLOCK_B,); one block computes every level of its rows."""
     x = x_ref[...]  # (BLOCK_B, L)
     pos = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
@@ -75,15 +83,17 @@ def _rangemax_kernel(x_ref, out_ref, *, P: int, L: int):
     span = 1
     for p in range(1, P):
         # level p = max of two level p-1 spans offset by 2**(p-1): a circular
-        # lane roll (Mosaic-native) with the wrapped tail masked to -inf
+        # lane roll (Mosaic-native) with the wrapped tail masked to the
+        # max identity
         rolled = pltpu.roll(x, L - span, 1)
-        x = jnp.maximum(x, jnp.where(pos < L - span, rolled, _NEG))
+        x = jnp.maximum(x, jnp.where(pos < L - span, rolled, neg))
         out_ref[:, p, :] = x
         span *= 2
 
 
-def rangemax_pallas(x: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """Raw pallas_call wrapper: (B, L) float32 -> (B, P, L) table levels.
+def rangemax_pallas(x: jax.Array, *, interpret: bool) -> jax.Array:
+    """Raw pallas_call wrapper: (B, L) int32 or float32 -> (B, P, L) table
+    levels in the input dtype.
 
     Requires B % BLOCK_B == 0 and L % LANE == 0 (ops.py pads).
     """
@@ -92,10 +102,11 @@ def rangemax_pallas(x: jax.Array, *, interpret: bool = True) -> jax.Array:
     P = num_levels(L)
     grid = (B // BLOCK_B,)
     return pl.pallas_call(
-        functools.partial(_rangemax_kernel, P=P, L=L),
+        functools.partial(_rangemax_kernel, P=P, L=L, neg=max_identity(x.dtype)),
         grid=grid,
         in_specs=[pl.BlockSpec((BLOCK_B, L), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_B, P, L), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, P, L), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, P, L), x.dtype),
         interpret=interpret,
-    )(x.astype(jnp.float32))
+        name="range_max_table",
+    )(x)

@@ -24,7 +24,6 @@ import traceback
 
 import jax
 
-from repro.compat import use_mesh
 from repro.configs import ARCHS, SHAPES, get_config, input_specs, shape_applicable
 from repro.distributed.sharding import cache_specs, data_specs, param_specs
 from repro.launch import roofline as RL
@@ -73,7 +72,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, cfg=None, accum_over
     specs = input_specs(cfg, shape)
     key = jax.random.PRNGKey(0)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             accum, mdt = TRAIN_SETTINGS[arch]
             # mesh-aware clamp: the microbatch must fill the data axes, or

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh, shard_map
+from jax.sharding import get_abstract_mesh
 from repro.configs.base import ModelConfig
 
 NEG_INF = -1.0e30
@@ -504,7 +504,7 @@ def moe_shard_map(p, x, cfg: ModelConfig):
     wspec = P("model", None, None) if ep else P(None, None, "model")
     wospec = P("model", None, None) if ep else P(None, "model", None)
     ba_spec = ba if len(ba) != 1 else ba[0]
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(ba_spec), P(), wspec, wspec, wospec),

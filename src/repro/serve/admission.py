@@ -25,10 +25,7 @@ Three controllers implement the same policy:
   over the batch threads the within-batch sequential dependency (an admitted
   candidate's demand is visible to every later candidate, exactly as if the
   scalar controller had processed them one at a time).  Decision parity with
-  the oracle is exact on randomized streams (``tests/test_serve_batch.py``);
-  the device program runs in float64 (``jax.experimental.enable_x64``)
-  because the profile's ``nextafter`` switch events are below float32
-  resolution at serving timestamps.
+  the oracle is exact on randomized streams (``tests/test_serve_batch.py``).
 * ``ShardedAdmissionController`` — the long-lived control plane: the active
   set is sharded across ``n_shards`` by a deterministic crc32 placement,
   each shard owns ``budget / n_shards`` HBM, and the whole per-shard state
@@ -39,6 +36,14 @@ Three controllers implement the same policy:
   oracle is ``ShardedScalarController`` (one scalar ``AdmissionController``
   per shard over the same placement), which the parity suite
   (``tests/test_serve_sharded.py``) holds it to decision-for-decision.
+
+Every controller decides in ``core.timeline``'s integer units: arrival
+times and step boundaries become time keys (1 ms ticks), allocations round
+up and budgets down to whole MiB, so the host oracles and the int32 device
+programs compute the same exact sums.  A plan holds through its final
+boundary inclusive (Eq. 1's domain [0, r_e]), so it releases at the key just
+after its end.  Plans keep the predictor's float allocation for the
+wastage accounting.
 """
 
 from __future__ import annotations
@@ -48,14 +53,20 @@ import zlib
 
 import numpy as np
 
-from repro.core.allocation import StepAllocation, pack_step_allocations
+from repro.core.allocation import StepAllocation
 from repro.core.ksegments import KSegmentsConfig, KSegmentsModel
 from repro.core.timeline import (
+    NEVER,
     Timeline,
+    budget_units,
+    check_horizon,
     demand_exceeds,
+    pack_steps,
     plan_profile_events,
+    quantize_steps,
     shared_probe_set,
     step_demand_profile,
+    time_keys,
 )
 
 # Historical alias kept for external callers of the controller internals.
@@ -117,31 +128,46 @@ class _AdmissionBase:
         5%-of-budget placeholder reservation."""
         return StepAllocation(np.asarray([1.0]), np.asarray([self.budget * 0.05]))
 
+    def _predict_many(self, prompt_lens, C: int) -> tuple[np.ndarray, np.ndarray]:
+        """(C, k) boundaries (seconds) and values (MiB) for a candidate batch."""
+        if self.model.n_observations == 0:
+            d = self._default_alloc()
+            return np.tile(d.boundaries, (C, 1)), np.tile(d.values, (C, 1))
+        return self.model.predict_batch(np.asarray(prompt_lens, dtype=np.float64))
+
+
+def _windows(starts: np.ndarray, b: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Admission windows in keys: a plan from ``starts`` holds through its
+    final boundary inclusive and releases just after it.  Returns (end keys,
+    release keys), checked against the horizon."""
+    ends = starts + b[..., -1]
+    check_horizon(ends)
+    return ends, ends + 1
+
 
 class AdmissionController(_AdmissionBase):
     """Online segment-wise HBM packing for a decode engine (scalar oracle)."""
 
     def __init__(self, hbm_budget_mib: float, k: int = 4, interval_s: float = 0.5):
         super().__init__(hbm_budget_mib, k, interval_s)
+        self._units = int(budget_units(self.budget))
+        self._steps: dict[str, tuple[np.ndarray, np.ndarray, int]] = {}  # rid -> (b, v, start)
         self._prof: tuple | None = None  # cached demand profile; dropped on admit/release
 
     # -- admission ----------------------------------------------------------
 
     def _profile(self) -> tuple[np.ndarray, np.ndarray]:
         """Active plans' total demand as a cumulative step profile (event
-        times, running sum) — ``core.allocation.step_demand_profile``, shared
+        keys, running sum) — ``core.timeline.step_demand_profile``, shared
         with the cluster simulator's ``NodeState``, so admission stays
         O(P k log) per request instead of re-summing every plan at every
         probe.  A plan holds through its final boundary inclusive (the
-        paper's Eq. 1 domain [0, r_e]) and releases just after, hence the
-        ``nextafter`` release times."""
+        paper's Eq. 1 domain [0, r_e]) and releases at the key just after."""
         if self._prof is None:
-            plans = list(self.active.values())
-            bnd, val = pack_step_allocations([p.alloc for p in plans])
-            starts = np.asarray([p.admitted_at for p in plans])
-            releases = np.asarray(
-                [np.nextafter(p.admitted_at + float(p.alloc.boundaries[-1]), np.inf) for p in plans]
-            )
+            steps = list(self._steps.values())
+            bnd, val = pack_steps([(b, v) for b, v, _ in steps])
+            starts = np.asarray([s for _, _, s in steps], dtype=np.int64)
+            releases = np.asarray([s + b[-1] + 1 for b, _, s in steps], dtype=np.int64)
             self._prof = step_demand_profile(bnd, val, starts, releases)
         return self._prof
 
@@ -154,7 +180,8 @@ class AdmissionController(_AdmissionBase):
         newcomer's cheap early segments with a leader's remaining window.
         (Requests that outlive r_e are the retry/preemption path.)"""
         times, cum = self._profile()
-        return cum[np.searchsorted(times, np.asarray(horizon), side="right")]
+        keys = time_keys(np.asarray(horizon))
+        return cum[np.searchsorted(times, keys, side="right")]
 
     def try_admit(self, request_id: str, prompt_len: int, now: float) -> RequestPlan | None:
         """Admit if the segment-wise demand fits the budget at every point
@@ -170,14 +197,17 @@ class AdmissionController(_AdmissionBase):
             alloc = self._default_alloc()
         else:
             alloc = self.model.predict(float(prompt_len))
+        b, v = quantize_steps(alloc.boundaries, alloc.values)
+        start = int(time_keys(now))
+        end, _ = _windows(start, b)
         times, cum = self._profile()
-        end = now + float(alloc.boundaries[-1])
         # inclusive end: a plan holds through its final boundary (Eq. 1
         # domain [0, r_e]), unlike a cluster reservation's right-open window.
-        if demand_exceeds(times, cum, alloc, now, end, self.budget, inclusive_end=True):
+        if demand_exceeds(times, cum, b, v, start, int(end), self._units, inclusive_end=True):
             return None
         plan = RequestPlan(request_id, now, alloc)
         self.active[request_id] = plan
+        self._steps[request_id] = (b, v, start)
         self._static_reserved += float(alloc.values[-1])
         self._prof = None
         return plan
@@ -185,6 +215,7 @@ class AdmissionController(_AdmissionBase):
     def release(self, request_id: str) -> None:
         plan = self.active.pop(request_id, None)
         if plan is not None:
+            del self._steps[request_id]
             self._static_reserved -= float(plan.alloc.values[-1])
             self._prof = None
 
@@ -214,6 +245,7 @@ class BatchedAdmissionController(_AdmissionBase):
         device_min_batch: int = 32,
     ):
         super().__init__(hbm_budget_mib, k, interval_s)
+        self._units = int(budget_units(self.budget))
         self._prof = IncrementalDemandProfile()
         # Below this batch size the per-call device dispatch outweighs the
         # batched probe; the host path runs the same ``demand_exceeds``
@@ -224,19 +256,9 @@ class BatchedAdmissionController(_AdmissionBase):
     # -- admission ----------------------------------------------------------
 
     def try_admit(self, request_id: str, prompt_len: int, now: float) -> RequestPlan | None:
-        """Single-candidate fast path: the oracle's exact probe expressions
-        against the incremental profile — no batch plumbing, no rebuild, so
-        a lone decision is strictly cheaper than the scalar controller's."""
-        if self.model.n_observations == 0:
-            alloc = self._default_alloc()
-        else:
-            alloc = self.model.predict(float(prompt_len))
-        self._prof.expire(float(now))
-        times, cum = self._prof.arrays()
-        end = now + float(alloc.boundaries[-1])
-        if demand_exceeds(times, cum, alloc, now, end, self.budget, inclusive_end=True):
-            return None
-        return self._commit(request_id, alloc, float(now), float(np.nextafter(end, np.inf)))
+        """The batch-of-one case: the oracle's exact probe expressions
+        against the incremental profile, no rebuild."""
+        return self.try_admit_many([request_id], [prompt_len], now)[0]
 
     def try_admit_many(
         self, request_ids: list[str], prompt_lens, now
@@ -244,107 +266,93 @@ class BatchedAdmissionController(_AdmissionBase):
         """Decide a batch of candidates in arrival order, one device program.
 
         ``now`` is a scalar (all candidates share the clock) or a
-        non-decreasing (C,) array of per-candidate arrival times.  Decisions
-        are sequential-equivalent: candidate i is probed against the active
-        profile plus every candidate j < i admitted in this same call."""
+        non-decreasing (C,) array of per-candidate arrival times (seconds).
+        Decisions are sequential-equivalent: candidate i is probed against
+        the active profile plus every candidate j < i admitted in this same
+        call."""
         C = len(request_ids)
         if C == 0:
             return []
-        if C == 1:
-            t = now if np.ndim(now) == 0 else float(np.asarray(now)[0])
-            return [self.try_admit(request_ids[0], prompt_lens[0], t)]
-        if self.model.n_observations == 0:
-            d = self._default_alloc()
-            bnd = np.tile(d.boundaries, (C, 1))
-            val = np.tile(d.values, (C, 1))
-        else:
-            bnd, val = self.model.predict_batch(np.asarray(prompt_lens, dtype=np.float64))
-        starts = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,)).astype(np.float64)
-        ends = starts + bnd[:, -1]
-        rels = np.nextafter(ends, np.inf)  # a plan holds through r_e inclusive
-        self._prof.expire(float(starts[0]))
+        bnd_s, val_s = self._predict_many(prompt_lens, C)
+        bnd, val = quantize_steps(bnd_s, val_s)
+        now_s = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,))
+        starts = time_keys(now_s)
+        ends, rels = _windows(starts, bnd)
+        self._prof.expire(int(starts[0]))
         if C < self.device_min_batch:
-            return self._admit_host(request_ids, bnd, val, starts, ends, rels)
-        return self._admit_device(request_ids, bnd, val, starts, ends, rels)
-
-    def _admit_host(self, request_ids, bnd, val, starts, ends, rels):
-        """Small-batch path: the oracle's probe against the incremental
-        profile, committing admitted plans as it goes (so within-batch
-        sequencing matches the device scan exactly)."""
-        plans: list[RequestPlan | None] = []
-        for i, rid in enumerate(request_ids):
-            alloc = StepAllocation(bnd[i], val[i])
-            times, cum = self._prof.arrays()
-            if demand_exceeds(
-                times, cum, alloc, float(starts[i]), float(ends[i]), self.budget, inclusive_end=True
-            ):
-                plans.append(None)
-                continue
-            plans.append(self._commit(rid, alloc, float(starts[i]), float(rels[i])))
-        return plans
-
-    def _commit(self, rid: str, alloc: StepAllocation, start: float, release: float) -> RequestPlan:
-        # profile first: add() validates the owner before touching anything,
-        # so re-admitting a live id raises with controller state clean
-        self._prof.add(rid, alloc.boundaries, alloc.values, start, release)
-        plan = RequestPlan(rid, start, alloc)
-        self.active[rid] = plan
-        self._static_reserved += float(alloc.values[-1])
-        return plan
-
-    def _admit_device(self, request_ids, bnd, val, starts, ends, rels):
-        from repro.sim.batch_engine import bucket_size, pad_rows
-        from repro.sim.device_timeline import _x64_ctx, admission_program
-
-        C = len(request_ids)
-        sw = np.nextafter(starts[:, None] + bnd, np.inf)  # switch instants (right-open steps)
-        live = np.isfinite(bnd) & (starts[:, None] + bnd < rels[:, None])
-        valext = np.concatenate([val, val[:, -1:]], axis=1)  # hold-last (C, k+1)
-        times, cum = self._prof.arrays()
-
-        # Shared probe set: all profile events + every candidate's start and
-        # switch instants, deduped (overlapping candidate boundaries repeat
-        # heavily and would inflate the padded probe bucket) and padded to a
-        # bucket so compiled shapes are bounded.
-        P = shared_probe_set(times, starts, sw.ravel())
-        Pp = bucket_size(len(P))
-        prof_at_p = self._prof.demand_at(P)
-        P = np.concatenate([P, np.full(Pp - len(P), np.inf)])
-        prof_at_p = np.concatenate([prof_at_p, np.full(Pp - len(prof_at_p), 0.0)])
-        Cp = bucket_size(C)
-        args = (
-            P,
-            prof_at_p,
-            pad_rows(starts, Cp, np.inf),
-            pad_rows(ends, Cp, -np.inf),
-            pad_rows(rels, Cp, -np.inf),
-            pad_rows(bnd, Cp, np.inf),
-            pad_rows(val, Cp, 0.0),
-            pad_rows(valext, Cp, 0.0),
-            pad_rows(sw, Cp, np.inf),
-            pad_rows(live, Cp, False),
-            pad_rows(np.ones(C, dtype=bool), Cp, False),
-        )
-        with _x64_ctx():
-            admits = np.asarray(admission_program()(*args, self.budget))[:C]
-
-        adm = np.flatnonzero(admits)
-        if len(adm):
-            # profile first: add_many validates owners before touching
-            # anything, so a duplicate id aborts with controller state clean
-            self._prof.add_many(
-                [request_ids[i] for i in adm], bnd[adm], val[adm], starts[adm], rels[adm]
-            )
+            admits = self._admit_host(request_ids, bnd, val, starts, ends, rels)
+        else:
+            admits = self._admit_device(request_ids, bnd, val, starts, ends, rels)
         plans: list[RequestPlan | None] = []
         for i, rid in enumerate(request_ids):
             if admits[i]:
-                plan = RequestPlan(rid, float(starts[i]), StepAllocation(bnd[i], val[i]))
+                plan = RequestPlan(rid, float(now_s[i]), StepAllocation(bnd_s[i], val_s[i]))
                 self.active[rid] = plan
-                self._static_reserved += float(val[i, -1])
+                self._static_reserved += float(val_s[i, -1])
                 plans.append(plan)
             else:
                 plans.append(None)
         return plans
+
+    def _admit_host(self, request_ids, bnd, val, starts, ends, rels) -> list[bool]:
+        """Small-batch path: the oracle's probe against the incremental
+        profile, committing admitted plans as it goes (so within-batch
+        sequencing matches the device scan exactly)."""
+        admits = []
+        for i, rid in enumerate(request_ids):
+            times, cum = self._prof.arrays()
+            over = demand_exceeds(
+                times, cum, bnd[i], val[i], int(starts[i]), int(ends[i]), self._units, inclusive_end=True
+            )
+            if not over:
+                self._prof.add(rid, bnd[i], val[i], int(starts[i]), int(rels[i]))
+            admits.append(not over)
+        return admits
+
+    def _admit_device(self, request_ids, bnd, val, starts, ends, rels) -> list[bool]:
+        from repro.sim.batch_engine import bucket_size, pad_rows
+        from repro.sim.device_timeline import admission_program
+
+        C = len(request_ids)
+        live = bnd < (rels - starts)[:, None]
+        sw = np.where(live, starts[:, None] + np.where(live, bnd, 0) + 1, NEVER)  # switch keys
+        valext = np.concatenate([val, val[:, -1:]], axis=1)  # hold-last (C, k+1)
+        times, _ = self._prof.arrays()
+
+        # Shared probe set: all profile events + every candidate's start and
+        # live switch keys, deduped (overlapping candidate boundaries repeat
+        # heavily and would inflate the padded probe bucket) and padded to a
+        # bucket so compiled shapes are bounded.
+        P = shared_probe_set(times, starts, sw[live])
+        Pp = bucket_size(len(P))
+        prof_at_p = self._prof.demand_at(P)
+        i32 = np.int32
+        P = np.concatenate([P, np.full(Pp - len(P), NEVER)]).astype(i32)
+        prof_at_p = np.concatenate([prof_at_p, np.zeros(Pp - len(prof_at_p), np.int64)]).astype(i32)
+        Cp = bucket_size(C)
+        args = (
+            P,
+            prof_at_p,
+            pad_rows(starts.astype(i32), Cp, NEVER),
+            pad_rows(ends.astype(i32), Cp, -1),
+            pad_rows(rels.astype(i32), Cp, -1),
+            pad_rows(bnd.astype(i32), Cp, NEVER),
+            pad_rows(val.astype(i32), Cp, 0),
+            pad_rows(valext.astype(i32), Cp, 0),
+            pad_rows(sw.astype(i32), Cp, NEVER),
+            pad_rows(live, Cp, False),
+            pad_rows(np.ones(C, dtype=bool), Cp, False),
+        )
+        admits = np.asarray(admission_program()(*args, i32(self._units)))[:C]
+
+        adm = np.flatnonzero(admits)
+        if len(adm):
+            # add_many validates owners before touching anything, so a
+            # duplicate id aborts with controller state clean
+            self._prof.add_many(
+                [request_ids[i] for i in adm], bnd[adm], val[adm], starts[adm], rels[adm]
+            )
+        return admits.tolist()
 
     def release(self, request_id: str) -> None:
         plan = self.active.pop(request_id, None)
@@ -450,6 +458,7 @@ class ShardedAdmissionController(_AdmissionBase):
 
         self.n_shards = int(n_shards)
         self.shard_budget = self.budget / self.n_shards
+        self._units = int(budget_units(self.shard_budget))
         if use_shard_map is None:
             use_shard_map = jax.device_count() > 1
         # the mesh wants equal per-device shard slices: the largest divisor
@@ -466,12 +475,13 @@ class ShardedAdmissionController(_AdmissionBase):
         self._next_slot = [0] * self.n_shards
         self._pending_rel: list[list[int]] = [[] for _ in range(self.n_shards)]
         self._code: dict[str, tuple[int, int]] = {}  # rid -> (shard, code)
-        self._evtimes: dict[str, np.ndarray] = {}  # rid -> event-time row (nan padded)
+        self._steps: dict[str, tuple[np.ndarray, np.ndarray, int]] = {}  # rid -> (b, v, start)
+        self._evtimes: dict[str, np.ndarray] = {}  # rid -> event-key row (-1 padded)
         # event-time rows of queued releases: counted (vectorized) at the
         # next batch, against the clock they were released under
         self._pend_times: list[list[np.ndarray]] = [[] for _ in range(self.n_shards)]
         self._n_live = np.zeros(self.n_shards, dtype=np.int64)
-        self._clock = -np.inf
+        self._clock = -1
         self.reseeds = 0  # overflow-recovery reseeds (0 on healthy streams)
 
     # -- policy -------------------------------------------------------------
@@ -487,57 +497,55 @@ class ShardedAdmissionController(_AdmissionBase):
 
     # -- device-state plumbing ----------------------------------------------
 
+    def _place(self, *arrays):
+        """Device arrays of the carried state: with a mesh, sharded over its
+        devices along the leading shard axis (each device holds its own
+        shards' timelines); otherwise on the default device."""
+        import jax
+
+        if self.n_dev == 1:
+            return tuple(jax.numpy.asarray(a) for a in arrays)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.compat import device_mesh
+
+        sharding = NamedSharding(device_mesh(self.n_dev), PartitionSpec("shards"))
+        return tuple(jax.device_put(a, sharding) for a in arrays)
+
     def _ensure_state(self):
         if self._state is not None:
             return
-        import jax.numpy as jnp
-
-        from repro.sim.device_timeline import _x64_ctx
-
         S, L, Smax = self.n_shards, self._L, self._Smax
-        with _x64_ctx():
-            self._state = (
-                jnp.zeros((S,)),
-                jnp.full((S, L), jnp.inf),
-                jnp.zeros((S, L)),
-                jnp.full((S, L), -1, jnp.int32),
-                jnp.zeros((S, Smax)),
-            )
+        self._state = self._place(
+            np.zeros((S,), np.int32),
+            np.full((S, L), NEVER, np.int32),
+            np.zeros((S, L), np.int32),
+            np.full((S, L), -1, np.int32),
+            np.zeros((S, Smax), np.int32),
+        )
 
     def _grow_L(self, new_L: int):
-        import jax.numpy as jnp
-
-        from repro.sim.device_timeline import _x64_ctx
-
-        base0, tl_t, tl_d, tl_c, slot_fold = self._state
+        base0, tl_t, tl_d, tl_c, slot_fold = (np.asarray(a) for a in self._state)
         S, pad = self.n_shards, new_L - self._L
-        with _x64_ctx():
-            self._state = (
-                base0,
-                jnp.concatenate([tl_t, jnp.full((S, pad), jnp.inf, tl_t.dtype)], axis=1),
-                jnp.concatenate([tl_d, jnp.zeros((S, pad), tl_d.dtype)], axis=1),
-                jnp.concatenate([tl_c, jnp.full((S, pad), -1, tl_c.dtype)], axis=1),
-                slot_fold,
-            )
+        self._state = self._place(
+            base0,
+            np.concatenate([tl_t, np.full((S, pad), NEVER, np.int32)], axis=1),
+            np.concatenate([tl_d, np.zeros((S, pad), np.int32)], axis=1),
+            np.concatenate([tl_c, np.full((S, pad), -1, np.int32)], axis=1),
+            slot_fold,
+        )
         self._L = new_L
 
     def _grow_smax(self, new_smax: int):
-        import jax.numpy as jnp
-
-        from repro.sim.device_timeline import _x64_ctx
-
-        base0, tl_t, tl_d, tl_c, slot_fold = self._state
+        base0, tl_t, tl_d, tl_c, slot_fold = (np.asarray(a) for a in self._state)
         pad = new_smax - self._Smax
-        with _x64_ctx():
-            self._state = (
-                base0,
-                tl_t,
-                tl_d,
-                tl_c,
-                jnp.concatenate(
-                    [slot_fold, jnp.zeros((self.n_shards, pad), slot_fold.dtype)], axis=1
-                ),
-            )
+        self._state = self._place(
+            base0,
+            tl_t,
+            tl_d,
+            tl_c,
+            np.concatenate([slot_fold, np.zeros((self.n_shards, pad), np.int32)], axis=1),
+        )
         self._Smax = new_smax
 
     def _alloc_code(self, s: int) -> int:
@@ -552,29 +560,23 @@ class ShardedAdmissionController(_AdmissionBase):
         self._next_slot[s] += 1
         return code
 
-    def _reseed(self, t0: float):
-        """Rebuild the carried device state from the host plan set at ``t0``
-        — the recovery path for in-program overflow (and the correctness
-        anchor: the rebuilt state is exactly what the incremental splices
-        maintain, modulo float fold grouping)."""
-        import jax.numpy as jnp
-
-        from repro.sim.device_timeline import _x64_ctx
-
+    def _reseed(self, t0: int):
+        """Rebuild the carried device state from the host plan set at key
+        ``t0`` — the recovery path for in-program overflow (and the
+        correctness anchor: the rebuilt state is exactly what the
+        incremental splices maintain)."""
         S, L, Smax = self.n_shards, self._L, self._Smax
-        base0 = np.zeros(S)
-        tl_t = np.full((S, L), np.inf)
-        tl_d = np.zeros((S, L))
+        base0 = np.zeros(S, np.int64)
+        tl_t = np.full((S, L), NEVER, np.int32)
+        tl_d = np.zeros((S, L), np.int32)
         tl_c = np.full((S, L), -1, np.int32)
-        slot_fold = np.zeros((S, Smax))
+        slot_fold = np.zeros((S, Smax), np.int64)
         counts = np.zeros(S, dtype=np.int64)
         per: list[list] = [[] for _ in range(S)]
-        for rid, plan in self.active.items():
+        for rid in self.active:
             s, code = self._code[rid]
-            rel = float(np.nextafter(plan.admitted_at + float(plan.alloc.boundaries[-1]), np.inf))
-            t, d = plan_profile_events(
-                plan.alloc.boundaries, plan.alloc.values, plan.admitted_at, rel
-            )
+            b, v, start = self._steps[rid]
+            t, d = plan_profile_events(b, v, start, start + b[-1] + 1)
             per[s].append((t, d, np.full(len(t), code, dtype=np.int32)))
         for s in range(S):
             if not per[s]:
@@ -586,20 +588,16 @@ class ShardedAdmissionController(_AdmissionBase):
             t, d, c = t[order], d[order], c[order]
             cut = int(np.searchsorted(t, t0, side="right"))
             if cut:
-                base0[s] = np.cumsum(d[:cut])[-1]
+                base0[s] = d[:cut].sum()
                 np.add.at(slot_fold[s], c[:cut], d[:cut])
             nf = len(t) - cut
-            assert nf <= L, "reseed must be preceded by sufficient _grow_L"
+            if nf > L:
+                raise RuntimeError("reseed needs a larger timeline axis (grow L first)")
             tl_t[s, :nf], tl_d[s, :nf], tl_c[s, :nf] = t[cut:], d[cut:], c[cut:]
             counts[s] = nf
-        with _x64_ctx():
-            self._state = (
-                jnp.asarray(base0),
-                jnp.asarray(tl_t),
-                jnp.asarray(tl_d),
-                jnp.asarray(tl_c),
-                jnp.asarray(slot_fold),
-            )
+        self._state = self._place(
+            base0.astype(np.int32), tl_t, tl_d, tl_c, slot_fold.astype(np.int32)
+        )
         self._n_live = counts
         # pending releases are already reflected (released rids left
         # ``active`` before this rebuild): their codes free immediately
@@ -615,34 +613,30 @@ class ShardedAdmissionController(_AdmissionBase):
         return self.try_admit_many([request_id], [prompt_len], now)[0]
 
     def try_admit_many(self, request_ids, prompt_lens, now) -> list[RequestPlan | None]:
-        from repro.sim.device_timeline import _x64_ctx, admission_epoch
+        from repro.sim.device_timeline import admission_epoch
         from repro.sim.traces import bucket_size, fine_bucket
 
         C = len(request_ids)
         if C == 0:
             return []
-        if self.model.n_observations == 0:
-            d = self._default_alloc()
-            bnd = np.tile(d.boundaries, (C, 1))
-            val = np.tile(d.values, (C, 1))
-        else:
-            bnd, val = self.model.predict_batch(np.asarray(prompt_lens, dtype=np.float64))
-        starts = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,)).astype(np.float64)
-        t0 = float(starts[0])
+        bnd_s, val_s = self._predict_many(prompt_lens, C)
+        bnd, val = quantize_steps(bnd_s, val_s)
+        now_s = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,))
+        starts = time_keys(now_s)
+        t0 = int(starts[0])
         if t0 < self._clock:
             raise ValueError(
                 f"batch clock regressed: {t0} < {self._clock} (folded events never return)"
             )
-        ends = starts + bnd[:, -1]
-        rels = np.nextafter(ends, np.inf)  # a plan holds through r_e inclusive
-        # the finite events a plan splices in (start + live switches +
-        # release): one row per candidate, nan where a switch never fires —
-        # at release, the entries still above the clock (the unfolded ones)
-        # tighten the Lp prefix of the following batches
-        sw_all = np.nextafter(starts[:, None] + bnd, np.inf)
-        live_all = np.isfinite(bnd) & (starts[:, None] + bnd < rels[:, None])
+        ends, rels = _windows(starts, bnd)
+        # the events a plan splices in (start + live switches + release): one
+        # row per candidate, -1 where a switch never fires — at release, the
+        # entries still above the clock (the unfolded ones) tighten the Lp
+        # prefix of the following batches
+        live_all = bnd < (rels - starts)[:, None]
         times_all = np.concatenate(
-            [starts[:, None], np.where(live_all, sw_all, np.nan), rels[:, None]], axis=1
+            [starts[:, None], np.where(live_all, starts[:, None] + np.where(live_all, bnd, 0) + 1, -1), rels[:, None]],
+            axis=1,
         )
         S, k = self.n_shards, bnd.shape[1]
         shards = [self.shard_of(r) for r in request_ids]
@@ -652,8 +646,8 @@ class ShardedAdmissionController(_AdmissionBase):
         self._ensure_state()
         codes = [self._alloc_code(s) for s in shards]
         # capacity: worst case ignores the batch's own releases/folds, so
-        # growth (pure +inf padding — the sorted tail) runs strictly ahead of
-        # any possible in-program overflow
+        # growth (pure NEVER padding — the sorted tail) runs strictly ahead
+        # of any possible in-program overflow
         need = max(
             int(self._n_live[s]) + (k + 2) * len(per[s]) for s in range(S)
         )
@@ -666,7 +660,7 @@ class ShardedAdmissionController(_AdmissionBase):
         # decision tensors (fine_bucket: the prefix is the hot axis).  A
         # released plan's events still in the timeline are the ones above
         # the clock (everything at or under it was folded at a prior t0);
-        # nan pads (dead switches) compare False and drop out
+        # -1 pads (dead switches) drop out
         pend_ev = [
             int((np.stack(rows) > self._clock).sum()) if rows else 0
             for rows in self._pend_times
@@ -675,11 +669,11 @@ class ShardedAdmissionController(_AdmissionBase):
         Lp = min(self._L, fine_bucket(max(Lp_need, 1), floor=64))
         Cb = fine_bucket(max(len(p) for p in per), floor=8)
         Rb = bucket_size(max(max(len(q) for q in self._pending_rel), 1), floor=8)
-        st_p = np.full((S, Cb), np.inf)
-        en_p = np.full((S, Cb), -np.inf)
-        rl_p = np.full((S, Cb), -np.inf)
-        bnd_p = np.full((S, Cb, k), np.inf)
-        val_p = np.zeros((S, Cb, k))
+        st_p = np.full((S, Cb), NEVER, dtype=np.int32)
+        en_p = np.full((S, Cb), -1, dtype=np.int32)
+        rl_p = np.full((S, Cb), -1, dtype=np.int32)
+        bnd_p = np.full((S, Cb, k), NEVER, dtype=np.int32)
+        val_p = np.zeros((S, Cb, k), dtype=np.int32)
         code_p = np.full((S, Cb), -1, dtype=np.int32)
         valid_p = np.zeros((S, Cb), dtype=bool)
         codes_np = np.asarray(codes, dtype=np.int32)
@@ -696,10 +690,8 @@ class ShardedAdmissionController(_AdmissionBase):
             rel_p[s, : len(rel_lists[s])] = rel_lists[s]
         prog = admission_epoch(self.n_dev, Lp)
         batch = (st_p, en_p, rl_p, bnd_p, val_p, code_p, valid_p)
-        with _x64_ctx():
-            admits, overflow, n_live, *state = prog(
-                *self._state, rel_p, *batch, np.float64(t0), np.float64(self.shard_budget)
-            )
+        scalars = (np.int32(t0), np.int32(self._units))
+        admits, overflow, n_live, *state = prog(*self._state, rel_p, *batch, *scalars)
         if bool(np.asarray(overflow).any()):
             # can't-happen guard (growth pre-sizes L): rebuild from the host
             # plan set — the queued releases are already reflected there —
@@ -708,15 +700,11 @@ class ShardedAdmissionController(_AdmissionBase):
             self._reseed(t0)
             rel_lists = [[] for _ in range(S)]
             prog = admission_epoch(self.n_dev)  # replay probes the full axis
-            with _x64_ctx():
-                admits, overflow, n_live, *state = prog(
-                    *self._state,
-                    np.full((S, Rb), -1, dtype=np.int32),
-                    *batch,
-                    np.float64(t0),
-                    np.float64(self.shard_budget),
-                )
-            assert not bool(np.asarray(overflow).any()), "overflow after reseed"
+            admits, overflow, n_live, *state = prog(
+                *self._state, np.full((S, Rb), -1, dtype=np.int32), *batch, *scalars
+            )
+            if bool(np.asarray(overflow).any()):
+                raise RuntimeError("carried timeline overflowed after a reseed")
         self._state = tuple(state)
         self._n_live = np.asarray(n_live, dtype=np.int64)
         self._clock = t0
@@ -730,10 +718,11 @@ class ShardedAdmissionController(_AdmissionBase):
             j = pos[s]
             pos[s] += 1
             if bool(admits[s, j]):
-                plan = RequestPlan(rid, float(starts[i]), StepAllocation(bnd[i], val[i]))
+                plan = RequestPlan(rid, float(now_s[i]), StepAllocation(bnd_s[i], val_s[i]))
                 self.active[rid] = plan
-                self._static_reserved += float(val[i, -1])
+                self._static_reserved += float(val_s[i, -1])
                 self._code[rid] = (s, codes[i])
+                self._steps[rid] = (bnd[i], val[i], int(starts[i]))
                 self._evtimes[rid] = times_all[i]
                 plans.append(plan)
             else:
@@ -747,6 +736,7 @@ class ShardedAdmissionController(_AdmissionBase):
             return
         self._static_reserved -= float(plan.alloc.values[-1])
         s, code = self._code.pop(request_id)
+        del self._steps[request_id]
         # the code stays reserved until the release is applied on device —
         # recycling it earlier would let a newcomer's events alias a plan
         # still spliced into the carried timeline

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_compile_cache
 from repro.core.allocation import AttemptLadder
 from repro.core.ksegments import KSegmentsConfig
 
@@ -62,11 +61,6 @@ from repro.sim.device_timeline import (  # noqa: F401  (re-exports)
 from repro.sim.jax_sim import MAX_RETRIES, ENGINE_METHODS, simulate_task_ladders, simulate_task_methods
 from repro.sim.simulator import SimConfig, TaskResult
 from repro.sim.traces import TaskTrace, WorkflowTrace, bucket_size, pack_traces
-
-# Opt-in persistent compilation cache (REPRO_COMPILE_CACHE=dir): the engines
-# below compile ~a dozen bucket shapes; caching them across processes turns
-# the ~20 s CPU cold start into deserialization (see repro.compat).
-enable_compile_cache()
 
 GRID_METHODS = tuple(m for m in ENGINE_METHODS if m != "witt-lr-max")
 
@@ -316,14 +310,12 @@ def compute_cluster_ladders(
     ``_engine_error_mode``); cross-checks must run the sequential oracle with
     the same mode and window.
 
-    ``x64=True`` runs the ladder scan in float64 (~1.5x ladder cost): on rare
-    corpora a float32 prediction lands within an ulp of a capacity comparison
-    and end-to-end placement parity with the float64 numpy oracle flips; the
-    f64 variant closes that gap (tests/test_cluster_placement.py pins the
-    known boundary seed).
+    ``x64=True`` runs the ladder scan in float64 under ``jax.enable_x64``
+    (~1.5x ladder cost): a CPU reference for the rare corpus where a float32
+    prediction lands within an ulp of a comparison against the float64 numpy
+    oracle (tests/test_cluster_placement.py pins the known boundary seed).
+    No TPU path enters it: the device ladder is float32.
     """
-    from repro.sim.device_timeline import _x64_ctx
-
     kcfg = kcfg or KSegmentsConfig()
     methods = _check_methods(methods)
     for t in tasks:
@@ -340,14 +332,10 @@ def compute_cluster_ladders(
     dt = jnp.float64 if x64 else jnp.float32
 
     def _run(batch):
-        # The x64 context is held open for BOTH ladder dtypes: the attempt
-        # scorer accumulates wastage in float64 whenever x64 is live
-        # (``jax_sim._acc_dtype``), which the f32 ladder wants too — its
-        # *decisions* stay f32, only the reported sums gain the oracle's
-        # precision.  Inputs are therefore cast to the working dtype on the
-        # host (under the context ``jnp.asarray`` would silently promote the
-        # float64 trace arrays and change the f32 path's rounding).
-        with _x64_ctx():
+        # Inputs are cast to the working dtype on the host (under the x64
+        # context ``jnp.asarray`` would silently keep the float64 trace
+        # arrays).
+        with jax.enable_x64(x64):
             tbl = fn(
                 jnp.asarray(batch.x.astype(dt)),
                 jnp.asarray(batch.y.astype(dt)),

@@ -57,73 +57,90 @@ import numpy as np
 from repro.core.allocation import StepAllocation, score_attempt_np
 from repro.core.ksegments import KSegmentsConfig
 from repro.core.predictor import AllocationMethod, make_method
-from repro.core.timeline import Timeline, demand_exceeds_many
+from repro.core.timeline import (
+    Timeline,
+    budget_units,
+    check_horizon,
+    key_seconds,
+    quantize_steps,
+    time_keys,
+)
 from repro.sim.traces import TaskTrace, WorkflowTrace
 
 # Historical alias: NodeState's backing store predates the shared timeline.
 IncrementalDemandProfile = Timeline
 
+_ONE_SECOND = int(time_keys(1.0))  # the oracle's last-resort clock step
+
 
 @dataclasses.dataclass
 class NodeState:
+    """One node's reservations, in timeline units (``core.timeline``):
+    instants and durations are int keys, allocations ``(boundary keys,
+    value units)`` step pairs, and the capacity rounds down to units."""
+
     capacity_mib: float
-    # active reservations: (end_time, alloc, start_time)
-    active: list[tuple[float, StepAllocation, float]] = dataclasses.field(default_factory=list)
+    # active reservations: (end key, boundary keys, value units, start key)
+    active: list[tuple[int, np.ndarray, np.ndarray, int]] = dataclasses.field(default_factory=list)
     # The node's combined demand profile, maintained incrementally under
-    # add()/expire() by the serving path's IncrementalDemandProfile (O(E + k)
-    # per placement instead of a packed-view re-sort).  Direct external
-    # mutation of ``active`` (append, rebind, element replacement) is
-    # detected via the row-identity key — a mutating row must coexist with
-    # the row it replaces, so the key change is deterministic — and triggers
-    # a full profile rebuild on the next read.
-    _prof: IncrementalDemandProfile = dataclasses.field(
-        default_factory=IncrementalDemandProfile, init=False, repr=False, compare=False
-    )
+    # add()/expire() by the shared Timeline (O(E + k) per placement instead
+    # of a packed-view re-sort).  Direct external mutation of ``active``
+    # (append, rebind, element replacement) is detected via the row-identity
+    # key — a mutating row must coexist with the row it replaces, so the key
+    # change is deterministic — and triggers a full profile rebuild on the
+    # next read.
+    _prof: Timeline = dataclasses.field(default_factory=Timeline, init=False, repr=False, compare=False)
     _synced: tuple = dataclasses.field(default=(), init=False, repr=False, compare=False)
     _seq: int = dataclasses.field(default=0, init=False, repr=False, compare=False)
+
+    @property
+    def budget(self) -> int:
+        """The fits budget: the capacity in whole demand units."""
+        return int(budget_units(self.capacity_mib))
 
     def _key(self) -> tuple[int, ...]:
         return tuple(map(id, self.active))
 
-    def _sync(self) -> IncrementalDemandProfile:
+    def _sync(self) -> Timeline:
         key = self._key()
         if key != self._synced:
-            prof = IncrementalDemandProfile()
-            for end, alloc, start in self.active:
-                prof.add(self._seq, alloc.boundaries, alloc.values, start, end)
+            prof = Timeline()
+            for end, b, v, start in self.active:
+                prof.add(self._seq, b, v, start, end)
                 self._seq += 1
             self._prof = prof
             self._synced = key
         return self._prof
 
     def profile_arrays(self) -> tuple[np.ndarray, np.ndarray]:
-        """The node's total reserved-demand step profile as (event times,
-        cumulative demand): ``cum[searchsorted(times, t, "right")]`` is the
-        reservation sum at ``t`` (a reservation end is its release time —
-        exclusive).  The same arrays back ``fits``, ``reserved_at`` and the
-        batched placement program's probe reads, so every consumer sees one
-        source of truth."""
+        """The node's total reserved-demand step profile as (event keys,
+        cumulative demand): ``cum[searchsorted(times, p, "right")]`` is the
+        reservation sum at key ``p`` (a reservation end is its release
+        instant — exclusive).  The same arrays back ``fits``, ``reserved_at``
+        and the batched placement program's probe reads, so every consumer
+        sees one source of truth."""
         return self._sync().arrays()
 
-    def reserved_at(self, t: float) -> float:
-        """Total reserved MiB at time ``t`` (one profile probe — same source
+    def reserved_at(self, p: int) -> int:
+        """Total reserved units at key ``p`` (one profile probe — same source
         of truth as fits())."""
-        return float(self._sync().demand_at(t))
+        return int(self._sync().demand_at(p))
 
-    def add(self, end: float, alloc: StepAllocation, start: float) -> None:
-        """Reserve ``alloc`` over [start, end) — one O(E + k) event splice."""
+    def add(self, end: int, b: np.ndarray, v: np.ndarray, start: int) -> None:
+        """Reserve steps ``(b, v)`` over [start, end) — one O(E + k) splice."""
         prof = self._sync()
-        prof.add(self._seq, alloc.boundaries, alloc.values, start, end)
+        prof.add(self._seq, b, v, start, end)
         self._seq += 1
-        self.active.append((end, alloc, start))
+        self.active.append((end, b, v, start))
         self._synced = self._key()
 
-    def expire(self, t: float) -> None:
-        """Drop reservations that ended at or before ``t`` (released events
-        telescope to zero at probes >= t, so this only bounds event counts)."""
+    def expire(self, t: int) -> None:
+        """Drop reservations that ended at or before key ``t`` (released
+        events telescope to zero at probes >= t, so this only bounds event
+        counts)."""
         if not self.active:
             return
-        keep = [e > t for e, _, _ in self.active]
+        keep = [row[0] > t for row in self.active]
         if all(keep):
             return
         prof = self._sync()
@@ -131,16 +148,12 @@ class NodeState:
         self.active = [row for row, k_ in zip(self.active, keep) if k_]
         self._synced = self._key()
 
-    def fits(self, alloc: StepAllocation, start: float, duration: float) -> bool:
-        """Can the candidate's reservation be placed over [start,
-        start + duration) without the combined step profile exceeding
-        capacity?  One ``Timeline.demand_exceeds`` probe pass against the
-        node's cached cumulative profile — this is the scheduler's placement
-        inner loop, and per-checkpoint scalar probes dominated whole cluster
-        runs."""
-        return not self._sync().demand_exceeds(
-            alloc, start, start + duration, self.capacity_mib + 1e-6
-        )
+    def fits(self, b: np.ndarray, v: np.ndarray, start: int, duration: int) -> bool:
+        """Can the candidate's steps be reserved over [start, start +
+        duration) without the combined step profile exceeding capacity?  One
+        ``Timeline.demand_exceeds`` probe pass against the node's cached
+        cumulative profile — the scheduler's placement inner loop."""
+        return not self._sync().demand_exceeds(b, v, start, start + duration, self.budget)
 
 
 @dataclasses.dataclass
@@ -154,7 +167,7 @@ class TaskRecord:
     task: str
     exec_index: int
     attempts: int  # retries + 1
-    placements: list[tuple[int, float, float]]  # (node, start, end) per attempt
+    placements: list[tuple[int, float, float]]  # (node, start s, end s) per attempt
     wastage_gib_s: float
 
     @property
@@ -198,22 +211,111 @@ def _gc(nodes: list[NodeState], t: float) -> None:
 
 def _find_slot(
     nodes: list[NodeState],
-    events: list[tuple[float, int]],
-    now: float,
-    alloc: StepAllocation,
-    duration: float,
-) -> tuple[int, float]:
+    events: list[tuple[int, int]],
+    now: int,
+    b: np.ndarray,
+    v: np.ndarray,
+    duration: int,
+) -> tuple[int, int]:
     """First-fit placement against the future reservation profiles; waits on
-    the completion heap when no node fits.  Returns (node index, time)."""
+    the completion heap when no node fits.  Returns (node index, start key)."""
     while True:
         _gc(nodes, now)
         for ni, nd in enumerate(nodes):
-            if nd.fits(alloc, now, duration):
+            if nd.fits(b, v, now, duration):
                 return ni, now
         if events:
             now = max(now, heapq.heappop(events)[0])  # wait for a slot
         else:
-            now += 1.0
+            now += _ONE_SECOND  # last resort: walk the clock
+
+
+def oracle_rows(
+    workflows: list[WorkflowTrace],
+    policy: str,
+    node_mib: float = 128 * 1024.0,
+    train_frac: float = 0.5,
+    max_tasks_per_type: int = 40,
+    min_executions: int = 10,
+    ksegments_config: KSegmentsConfig | None = None,
+):
+    """The sequential oracle's attempt rows, from the float64 numpy
+    predictors: per queued execution, predict, score, ``on_failure`` until
+    an attempt succeeds, then ``observe``.  A prediction never depends on
+    where earlier attempts were placed, so the ladders come first and
+    ``place_rows`` places them.
+
+    Returns ``(queue, rows)`` in ``_policy_rows``' layout, except that the
+    boundary-key and value-unit rows are ragged (each row has its method's
+    k): (boundary keys, value units, run keys, probe keys, attempts per
+    task, wastage per task)."""
+    queue, traces = _eligible_queue(workflows, train_frac, max_tasks_per_type, min_executions)
+    # keyed by (workflow, task name): task names can collide across workflows
+    methods: dict[tuple[str, str], AllocationMethod] = {}
+    for trace, n_train in traces:
+        m = make_method(policy, trace.default_mib, node_mib, ksegments_config)
+        for e in trace.executions[:n_train]:
+            m.observe(e.input_size, e.series)
+        methods[(trace.workflow, trace.name)] = m
+
+    bnds, vals, runs, probes, counts, waste = [], [], [], [], [], []
+    for trace, i in queue:
+        e = trace.executions[i]
+        method = methods[(trace.workflow, trace.name)]
+        duration = int(time_keys(len(e.series) * trace.interval_s))
+        alloc = method.predict(e.input_size)
+        attempts = 0
+        task_waste = 0.0
+        while True:  # retry loop: each attempt is a fresh placement row
+            attempts += 1
+            alloc = StepAllocation(alloc.boundaries, np.minimum(alloc.values, node_mib))
+            b, v = quantize_steps(alloc.boundaries, alloc.values)
+            out = score_attempt_np(e.series, trace.interval_s, alloc)
+            bnds.append(b)
+            vals.append(v)
+            probes.append(duration)
+            runs.append(int(time_keys((out.failure_index + 1) * trace.interval_s)) if out.failed else duration)
+            task_waste += out.wastage_gib_s
+            if not out.failed:
+                break
+            if attempts > 64:
+                raise RuntimeError("unschedulable task")
+            seg = alloc.segment_of((out.failure_index + 0.5) * trace.interval_s)
+            alloc = method.on_failure(alloc, seg, node_mib)
+        method.observe(e.input_size, e.series)
+        counts.append(attempts)
+        waste.append(task_waste)
+    return queue, (
+        bnds,
+        vals,
+        np.asarray(runs, dtype=np.int64),
+        np.asarray(probes, dtype=np.int64),
+        np.asarray(counts, dtype=np.int64),
+        np.asarray(waste, dtype=np.float64),
+    )
+
+
+def place_rows(bnd_rows, val_rows, run_rows, probe_rows, n_nodes: int, node_mib: float):
+    """The sequential oracle's placement of flat attempt rows (timeline
+    units), one ``_find_slot`` per row: fit-check the full predicted
+    duration (the scheduler cannot know an attempt will die early),
+    first-fit across nodes, waiting on the completion heap, then occupy the
+    node for the row's real run.  Returns per-row (node, start key, end
+    key) int64 arrays."""
+    nodes = [NodeState(node_mib) for _ in range(n_nodes)]
+    # event heap of (end key, node_idx) completions to garbage-collect reservations
+    events: list[tuple[int, int]] = []
+    now = 0
+    out = np.empty((3, len(run_rows)), dtype=np.int64)
+    for r in range(len(run_rows)):
+        b, v, probe = bnd_rows[r], val_rows[r], int(probe_rows[r])
+        placed, now = _find_slot(nodes, events, now, b, v, probe)
+        check_horizon(now + probe)
+        end = now + int(run_rows[r])
+        nodes[placed].add(end, b, v, now)
+        heapq.heappush(events, (end, placed))
+        out[:, r] = placed, now, end
+    return out[0], out[1], out[2]
 
 
 def run_cluster(
@@ -231,86 +333,31 @@ def run_cluster(
 
     Tasks arrive in trace order; each waits until some node fits its
     reservation.  Per-method online learning happens as tasks finish.  This
-    is the sequential oracle; ``run_cluster_batched`` is the device-backed
-    twin (pass ``ksegments_config=KSegmentsConfig(error_mode="progressive")``
-    here to compare them cell by cell).
+    is the sequential oracle (``oracle_rows`` then ``place_rows``);
+    ``run_cluster_batched`` is the device-backed twin (pass
+    ``ksegments_config=KSegmentsConfig(error_mode="progressive")`` here to
+    compare them cell by cell).
     """
-    queue, traces = _eligible_queue(workflows, train_frac, max_tasks_per_type, min_executions)
-    # keyed by (workflow, task name): task names can collide across workflows
-    methods: dict[tuple[str, str], AllocationMethod] = {}
-    for trace, n_train in traces:
-        m = make_method(policy, trace.default_mib, node_mib, ksegments_config)
-        for e in trace.executions[:n_train]:
-            m.observe(e.input_size, e.series)
-        methods[(trace.workflow, trace.name)] = m
-
-    nodes = [NodeState(node_mib) for _ in range(n_nodes)]
-    # event heap of (time, node_idx) completions to garbage-collect reservations
-    events: list[tuple[float, int]] = []
-    now = 0.0
-    total_waste = 0.0
-    total_retries = 0
-    # The completion heap is consumed while waiting for slots and _gc() drops
-    # expired reservations, so the makespan is tracked explicitly as the max
-    # over every placed attempt's end instead of being reconstructed from
-    # whatever survives both (which undercounts).
-    makespan = 0.0
-    records: list[TaskRecord] = []
-
-    for trace, i in queue:
-        e = trace.executions[i]
-        method = methods[(trace.workflow, trace.name)]
-        series = e.series
-        duration = len(series) * trace.interval_s
-        # retry loop: each attempt is a fresh placement
-        alloc = method.predict(e.input_size)
-        attempts = 0
-        task_waste = 0.0
-        placements: list[tuple[int, float, float]] = []
-        while True:
-            attempts += 1
-            alloc = StepAllocation(alloc.boundaries, np.minimum(alloc.values, node_mib))
-            placed, now = _find_slot(nodes, events, now, alloc, duration)
-            out = score_attempt_np(series, trace.interval_s, alloc)
-            run_time = (out.failure_index + 1) * trace.interval_s if out.failed else duration
-            end = now + run_time
-            nodes[placed].add(end, alloc, now)
-            heapq.heappush(events, (end, placed))
-            placements.append((placed, now, end))
-            makespan = max(makespan, end)
-            total_waste += out.wastage_gib_s
-            task_waste += out.wastage_gib_s
-            if not out.failed:
-                break
-            total_retries += 1
-            if attempts > 64:
-                raise RuntimeError("unschedulable task")
-            seg = alloc.segment_of((out.failure_index + 0.5) * trace.interval_s)
-            alloc = method.on_failure(alloc, seg, node_mib)
-        method.observe(e.input_size, e.series)
-        records.append(TaskRecord(trace.workflow, trace.name, i, attempts, placements, task_waste))
-        # arrival pacing: next task arrives as soon as submitted (batch queue)
-
-    return ClusterResult(
-        policy=policy,
-        makespan_s=float(makespan),
-        wastage_gib_s=float(total_waste),
-        retries=int(total_retries),
-        tasks_run=len(queue),
-        records=records,
+    queue, rows = oracle_rows(
+        workflows, policy, node_mib, train_frac, max_tasks_per_type, min_executions, ksegments_config
     )
+    bnd, val, run, probe, counts, waste = rows
+    return _policy_result(policy, queue, counts, waste, *place_rows(bnd, val, run, probe, n_nodes, node_mib))
 
 
 def _policy_rows(ladders, queue, policy: str):
     """Flatten one policy's retry ladders into placement rows (queue x
-    attempt order): (boundaries (R, k), values (R, k), run times (R,),
-    probe durations (R,), attempts per task (Q,), wastage per task (Q,)).
+    attempt order), in timeline units: (boundary keys (R, k), value units
+    (R, k), run keys (R,), probe keys (R,), attempts per task (Q,), wastage
+    per task (Q,)).
 
-    ``run times`` are each attempt's node *occupancy* (up to and including
-    the kill sample on failure); ``probe durations`` are the execution's
-    full duration — the window the scheduler fit-checks, since it cannot
-    know an attempt will die early (``run_cluster`` probes ``_find_slot``
-    with the full duration and only occupies the truncated window).
+    ``run`` keys are each attempt's node *occupancy* (up to and including
+    the kill sample on failure); ``probe`` keys are the execution's full
+    duration — the window the scheduler fit-checks, since it cannot know an
+    attempt will die early (``run_cluster`` probes ``_find_slot`` with the
+    full duration and only occupies the truncated window).  Seconds and MiB
+    are quantized by the same ``core.timeline`` functions ``run_cluster``
+    applies per attempt.
 
     Works trace-block-wise straight off the ``TaskLadders`` tensors
     (``_eligible_queue`` emits each trace's executions contiguously) — the
@@ -346,14 +393,51 @@ def _policy_rows(ladders, queue, policy: str):
         counts_all.append(counts)
         waste.append(np.sum(tl.wastage_gib_s[mi, execs] * mask, axis=1))
         i0 = i1
+    bnd, val = quantize_steps(np.concatenate(bnds), np.concatenate(vals))
     return (
-        np.concatenate(bnds),
-        np.concatenate(vals),
-        np.concatenate(runs).astype(np.float64),
-        np.concatenate(probes).astype(np.float64),
+        bnd,
+        val,
+        time_keys(np.concatenate(runs)),
+        time_keys(np.concatenate(probes)),
         np.concatenate(counts_all),
         np.concatenate(waste),
     )
+
+
+def batched_rows(
+    workflows: list[WorkflowTrace],
+    policies: tuple[str, ...],
+    node_mib: float = 128 * 1024.0,
+    train_frac: float = 0.5,
+    max_tasks_per_type: int = 40,
+    min_executions: int = 10,
+    ksegments_config: KSegmentsConfig | None = None,
+    max_attempts: int = 32,
+    ladder_x64: bool = False,
+):
+    """The device engine's attempt rows: every policy's retry ladders from
+    one batched ladder pass (``compute_cluster_ladders``), flattened by
+    ``_policy_rows``.  Returns ``(queue, {policy: rows})`` — the rows both
+    placement engines consume, and ``oracle_rows``' twin."""
+    from repro.sim.batch_engine import compute_cluster_ladders  # deferred: keeps the oracle jax-free
+
+    kcfg = ksegments_config or KSegmentsConfig(error_mode="progressive")
+    if kcfg.error_mode == "insample" and kcfg.insample_window is None:
+        raise ValueError(
+            "the batched cluster engines support progressive or bounded-history insample "
+            "offsets; set KSegmentsConfig(insample_window=W) for insample"
+        )
+    queue, traces = _eligible_queue(workflows, train_frac, max_tasks_per_type, min_executions)
+    # The ladder scan is forward-only (an execution's prediction sees only
+    # earlier executions), so executions past the last one the queue can
+    # reach are dead weight — truncating them shrinks the biggest buckets
+    # without changing any consumed row.
+    trunc = [
+        dataclasses.replace(t, executions=t.executions[: n_train + max_tasks_per_type])
+        for t, n_train in traces
+    ]
+    ladders = compute_cluster_ladders(trunc, tuple(policies), node_mib, kcfg, max_attempts, x64=ladder_x64)
+    return queue, {p: _policy_rows(ladders, queue, p) for p in policies}
 
 
 def _place_rows_batched(
@@ -366,9 +450,9 @@ def _place_rows_batched(
     window: int,
     stats: dict | None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Place all of one policy's attempt rows with the device timeline
-    programs.  Returns per-row (node, start, end) arrays with the sequential
-    oracle's exact placement semantics.
+    """Place all of one policy's attempt rows (``_policy_rows`` units) with
+    the device timeline programs.  Returns per-row (node, start key, end key)
+    arrays with the sequential oracle's exact placement semantics.
 
     Two device regimes, zero host-resolved waits:
 
@@ -386,24 +470,24 @@ def _place_rows_batched(
       waiting.
 
     Between dispatches the host mirrors the commits into the per-node
-    ``Timeline``s (one ``add_many`` splice per node, bit-identical event
-    order) and drops the consumed completions, so the next epoch is seeded
-    from the same profiles the oracle probes.  The only remaining host
-    placement is the oracle's last-resort +1.0 clock walk when the
-    completion heap drains with a row still unplaced — unreachable for
-    node-capped allocations, counted in ``waits_host``."""
+    ``Timeline``s (one ``add_many`` splice per node, identical event order)
+    and drops the consumed completions, so the next epoch is seeded from the
+    same profiles the oracle probes.  The only remaining host placement is
+    the oracle's last-resort one-second clock walk when the completion heap
+    drains with a row still unplaced — unreachable for node-capped
+    allocations, counted in ``waits_host``."""
     # deferred import keeps the oracle path (run_cluster) jax-free
-    from repro.sim.device_timeline import _x64_ctx, first_fit_window, schedule_epoch
+    from repro.sim.device_timeline import first_fit_window, schedule_epoch
 
     R = len(run_rows)
     profs = [Timeline() for _ in range(n_nodes)]
-    pending: list[float] = []  # completion instants not yet consumed by a wait
-    budget = node_mib + 1e-6  # NodeState.fits budget
+    pending: list[int] = []  # completion keys not yet consumed by a wait
+    budget = int(budget_units(node_mib))  # NodeState.fits budget
     row_node = np.empty(R, dtype=np.int64)
-    row_start = np.empty(R, dtype=np.float64)
-    row_end = np.empty(R, dtype=np.float64)
+    row_start = np.empty(R, dtype=np.int64)
+    row_end = np.empty(R, dtype=np.int64)
     owner = 0
-    now = 0.0
+    now = 0
     r = 0
     congested = False
 
@@ -413,8 +497,11 @@ def _place_rows_batched(
         if stats is not None:
             stats["program_calls"] += 1
             stats["program_wall_s"] += time.perf_counter() - t0
+        # the device keys are int32: a fit window past the horizon would
+        # have wrapped, so every placement is checked before it is kept
+        check_horizon(starts[:npl] + probe_rows[r : r + npl])
         ends = starts[:npl] + run_rows[r : r + npl]
-        # committing per node in row order splices time-tied events in
+        # committing per node in row order splices key-tied events in
         # exactly the order the oracle's one-at-a-time add() would
         for n in np.unique(nidx[:npl]):
             m = np.flatnonzero(nidx[:npl] == n)
@@ -430,88 +517,88 @@ def _place_rows_batched(
         row_start[r : r + npl] = starts[:npl]
         row_end[r : r + npl] = ends
         r += npl
-        return [float(e) for e in ends]
+        return [int(e) for e in ends]
 
-    expired_at = -np.inf
-    with _x64_ctx():  # one context across all epoch dispatches
-        while r < R:
-            if now > expired_at:
-                # the clock only moves when a row waits, so most windows skip
-                # the N-node expiry sweep entirely
-                for prof in profs:
-                    prof.expire(now)
-                expired_at = now
-            w = min(window, R - r)
-            if not congested:
-                t0 = time.perf_counter()
-                placed, nidx = first_fit_window(
-                    now,
-                    bnd_rows[r : r + w],
-                    val_rows[r : r + w],
-                    run_rows[r : r + w],
-                    probe_rows[r : r + w],
-                    [prof.arrays() for prof in profs],
-                    budget,
-                    window,
-                )
-                npl = w if placed.all() else int(np.argmin(placed))
-                pending += _commit(npl, nidx, np.full(npl, now), t0)
-                if r < R and npl < w:
-                    congested = True  # row r must wait: epoch program takes over
-                continue
-            # small wait windows: every row-step of the epoch program pays
-            # for its carried clock/heap machinery, so congested dispatches
-            # place a handful of rows per call and hand back to streaming
-            # as soon as a window resolves without waiting
-            w = min(w, 8)
+    expired_at = -1
+    while r < R:
+        if now > expired_at:
+            # the clock only moves when a row waits, so most windows skip
+            # the N-node expiry sweep entirely
+            for prof in profs:
+                prof.expire(now)
+            expired_at = now
+        w = min(window, R - r)
+        if not congested:
             t0 = time.perf_counter()
-            placed, nidx, starts, now, n_pops, n_waited, dead = schedule_epoch(
+            placed, nidx = first_fit_window(
                 now,
                 bnd_rows[r : r + w],
                 val_rows[r : r + w],
                 run_rows[r : r + w],
-                [prof.events() for prof in profs],
-                np.asarray(pending),
+                probe_rows[r : r + w],
+                [prof.arrays() for prof in profs],
                 budget,
-                min(window, 8),
-                probe_times=probe_rows[r : r + w],
+                window,
             )
-            if stats is not None:
-                stats["waits_program"] += n_waited
             npl = w if placed.all() else int(np.argmin(placed))
-            ends = _commit(npl, nidx, starts, t0)
-            # the program consumed the n_pops earliest completions of the
-            # merged heap (pop order among time-ties is unobservable)
-            pending = sorted(pending + ends)[n_pops:]
-            congested = n_waited > 0  # stream again once a window stops waiting
-            if r < R and npl < w and not dead:
-                # a full per-node commit buffer aborted the epoch; nothing of
-                # row r was consumed — re-dispatch from fresh timelines
-                congested = True
-                continue
+            pending += _commit(npl, nidx, np.full(npl, now, dtype=np.int64), t0)
             if r < R and npl < w:
-                # heap drained with row r unplaced: the oracle's last-resort
-                # +1.0 clock walk (unreachable for node-capped allocations —
-                # an empty node always fits once everything released)
-                if stats is not None:
-                    stats["waits_host"] += 1
-                alloc = StepAllocation(bnd_rows[r], val_rows[r])
-                pdur = float(probe_rows[r])  # fit-check the full duration ...
-                ni = None
-                while ni is None:
-                    now += 1.0
-                    for prof in profs:
-                        prof.expire(now)
-                    for i, prof in enumerate(profs):
-                        if not prof.demand_exceeds(alloc, now, now + pdur, budget):
-                            ni = i
-                            break
-                end = now + float(run_rows[r])  # ... but occupy the real run
-                profs[ni].add(owner, bnd_rows[r], val_rows[r], now, end)
-                owner += 1
-                pending = sorted(pending + [end])
-                row_node[r], row_start[r], row_end[r] = ni, now, end
-                r += 1
+                congested = True  # row r must wait: epoch program takes over
+            continue
+        # small wait windows: every row-step of the epoch program pays
+        # for its carried clock/heap machinery, so congested dispatches
+        # place a handful of rows per call and hand back to streaming
+        # as soon as a window resolves without waiting
+        w = min(w, 8)
+        t0 = time.perf_counter()
+        placed, nidx, starts, now, n_pops, n_waited, dead = schedule_epoch(
+            now,
+            bnd_rows[r : r + w],
+            val_rows[r : r + w],
+            run_rows[r : r + w],
+            [prof.events() for prof in profs],
+            np.asarray(pending, dtype=np.int64),
+            budget,
+            min(window, 8),
+            probe_times=probe_rows[r : r + w],
+        )
+        if stats is not None:
+            stats["waits_program"] += n_waited
+        npl = w if placed.all() else int(np.argmin(placed))
+        ends = _commit(npl, nidx, starts, t0)
+        # the program consumed the n_pops earliest completions of the
+        # merged heap (pop order among key-ties is unobservable)
+        pending = sorted(pending + ends)[n_pops:]
+        congested = n_waited > 0  # stream again once a window stops waiting
+        if r < R and npl < w and not dead:
+            # a full per-node commit buffer aborted the epoch; nothing of
+            # row r was consumed — re-dispatch from fresh timelines
+            congested = True
+            continue
+        if r < R and npl < w:
+            # heap drained with row r unplaced: the oracle's last-resort
+            # clock walk (unreachable for node-capped allocations — an
+            # empty node always fits once everything released)
+            if stats is not None:
+                stats["waits_host"] += 1
+            b, v = bnd_rows[r], val_rows[r]
+            pdur = int(probe_rows[r])  # fit-check the full duration ...
+            ni = None
+            while ni is None:
+                now += _ONE_SECOND
+                for prof in profs:
+                    prof.expire(now)
+                for i, prof in enumerate(profs):
+                    if not prof.demand_exceeds(b, v, now, now + pdur, budget):
+                        ni = i
+                        break
+            check_horizon(now + pdur)
+            end = now + int(run_rows[r])  # ... but occupy the real run
+            profs[ni].add(owner, b, v, now, end)
+            owner += 1
+            pending = sorted(pending + [end])
+            row_node[r], row_start[r], row_end[r] = ni, now, end
+            r += 1
     return row_node, row_start, row_end
 
 
@@ -525,8 +612,10 @@ def _policy_result(
     row_end: np.ndarray,
 ) -> ClusterResult:
     """Assemble one policy's ``ClusterResult`` from its placed attempt rows
-    (shared by the windows and sweep placement engines)."""
+    (start/end keys; shared by the windows and sweep placement engines)."""
     offsets = np.concatenate([[0], np.cumsum(counts)])
+    start_s = key_seconds(row_start).tolist()
+    end_s = key_seconds(row_end).tolist()
     records = [
         TaskRecord(
             trace.workflow,
@@ -534,7 +623,7 @@ def _policy_result(
             i,
             int(counts[q]),
             [
-                (int(row_node[j]), float(row_start[j]), float(row_end[j]))
+                (int(row_node[j]), start_s[j], end_s[j])
                 for j in range(offsets[q], offsets[q + 1])
             ],
             float(waste[q]),
@@ -543,7 +632,7 @@ def _policy_result(
     ]
     return ClusterResult(
         policy=policy,
-        makespan_s=float(row_end.max()) if len(row_end) else 0.0,
+        makespan_s=float(key_seconds(row_end.max())) if len(row_end) else 0.0,
         wastage_gib_s=float(waste.sum()),
         retries=int((counts - 1).sum()),
         tasks_run=len(queue),
@@ -641,18 +730,20 @@ def run_cluster_batched(
     per attempt; tests/test_cluster_congested.py stresses the wait path).
 
     ``placement_stats``, when passed, accumulates ``{"program_calls",
-    "program_wall_s", "waits_program", "waits_host", "rows"}`` for the bench
-    (``waits_program`` = rows whose wait was resolved inside the device
-    program; ``waits_host`` = last-resort host clock walks, 0 in practice).
+    "program_wall_s", "waits_program", "waits_host", "rows",
+    "lanes_replayed"}`` for the bench (``waits_program`` = rows whose wait
+    was resolved inside the device program; ``waits_host`` = last-resort
+    host clock walks, 0 in practice; ``lanes_replayed`` = sweep lanes that
+    overflowed and were replayed through the windows engine).
 
     k-Segments policies run with progressive error offsets (the device
     engine's bounded-carry mode) by default; ``error_mode="insample"`` is
     accepted when ``insample_window`` is an explicit bound (the ladder
     engine's ring-buffer mode — the sequential oracle with the same window
     is the parity twin), and rejected unbounded to keep results honest.
-    ``ladder_x64`` runs
-    the ladder scan in float64, closing the rare f32 ulp-boundary parity gap
-    against the float64 numpy predictors at ~1.5x ladder cost.
+    ``ladder_x64`` runs the ladder scan in float64 — a CPU reference that
+    closes the rare f32 ulp-boundary parity gap against the float64 numpy
+    predictors at ~1.5x ladder cost; no TPU path enters it.
 
     ``placement`` picks the placement engine: ``"windows"`` runs the
     per-policy streaming/epoch windows loop above; ``"sweep"`` schedules
@@ -670,30 +761,21 @@ def run_cluster_batched(
     loop.  A sweep lane that overflows the program's bounded timeline axis
     falls back to the windows engine for that policy alone.
     """
-    from repro.sim.batch_engine import compute_cluster_ladders  # deferred: keeps the oracle jax-free
-
     if placement not in ("auto", "sweep", "windows"):
         raise ValueError(f"unknown placement engine: {placement!r}")
-    kcfg = ksegments_config or KSegmentsConfig(error_mode="progressive")
-    if kcfg.error_mode == "insample" and kcfg.insample_window is None:
-        raise ValueError(
-            "run_cluster_batched supports progressive or bounded-history insample "
-            "offsets; set KSegmentsConfig(insample_window=W) for insample"
-        )
     policies = tuple(policies)
-    queue, traces = _eligible_queue(workflows, train_frac, max_tasks_per_type, min_executions)
-    # The ladder scan is forward-only (an execution's prediction sees only
-    # earlier executions), so executions past the last one the queue can
-    # reach are dead weight — truncating them shrinks the biggest buckets
-    # without changing any consumed row.
-    trunc = [
-        dataclasses.replace(t, executions=t.executions[: n_train + max_tasks_per_type])
-        for t, n_train in traces
-    ]
-    ladders = compute_cluster_ladders(trunc, policies, node_mib, kcfg, max_attempts, x64=ladder_x64)
-
-    rows = {p: _policy_rows(ladders, queue, p) for p in policies}
-    stats = {"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0}
+    queue, rows = batched_rows(
+        workflows,
+        policies,
+        node_mib,
+        train_frac,
+        max_tasks_per_type,
+        min_executions,
+        ksegments_config,
+        max_attempts,
+        ladder_x64,
+    )
+    stats ={"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
     placed: dict[str, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
     if placement == "sweep" or (
         placement == "auto" and _auto_sweep(rows, policies, n_nodes, placement_window)
@@ -703,9 +785,10 @@ def run_cluster_batched(
         node_s, start_s, _, _, dead = sweep_schedule(
             [rows[p][:4] for p in policies],
             [n_nodes] * len(policies),
-            [node_mib + 1e-6] * len(policies),
+            [int(budget_units(node_mib))] * len(policies),
             stats=stats,
         )
+        stats["lanes_replayed"] += int(np.sum(dead))
         for s, p in enumerate(policies):
             if not dead[s]:
                 run_rows = rows[p][2]
@@ -764,29 +847,26 @@ def run_cluster_sweep(
     ClusterResult}`` — feed ``(makespan_s, wastage_gib_s)`` pairs per corpus
     to ``pareto_frontier`` for the capacity-planning frontier.
     """
-    from repro.sim.batch_engine import compute_cluster_ladders  # deferred: keeps the oracle jax-free
     from repro.sim.device_timeline import sweep_schedule
 
     if not isinstance(corpora, dict):
         corpora = {"": corpora}
-    kcfg = ksegments_config or KSegmentsConfig(error_mode="progressive")
-    if kcfg.error_mode == "insample" and kcfg.insample_window is None:
-        raise ValueError(
-            "run_cluster_sweep supports progressive or bounded-history insample "
-            "offsets; set KSegmentsConfig(insample_window=W) for insample"
-        )
     policies = tuple(policies)
-    stats = {"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0}
+    stats = {"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
     lane_rows, lane_nodes, lane_keys = [], [], []
     meta: dict[str, tuple[list, dict]] = {}
     for cname, wfs in corpora.items():
-        queue, traces = _eligible_queue(wfs, train_frac, max_tasks_per_type, min_executions)
-        trunc = [
-            dataclasses.replace(t, executions=t.executions[: n_train + max_tasks_per_type])
-            for t, n_train in traces
-        ]
-        ladders = compute_cluster_ladders(trunc, policies, node_mib, kcfg, max_attempts, x64=ladder_x64)
-        rows = {p: _policy_rows(ladders, queue, p) for p in policies}
+        queue, rows = batched_rows(
+            wfs,
+            policies,
+            node_mib,
+            train_frac,
+            max_tasks_per_type,
+            min_executions,
+            ksegments_config,
+            max_attempts,
+            ladder_x64,
+        )
         meta[cname] = (queue, rows)
         for p in policies:
             for nn in node_counts:
@@ -794,8 +874,9 @@ def run_cluster_sweep(
                 lane_nodes.append(int(nn))
                 lane_keys.append((cname, p, int(nn)))
     node_s, start_s, _, _, dead = sweep_schedule(
-        lane_rows, lane_nodes, [node_mib + 1e-6] * len(lane_rows), stats=stats
+        lane_rows, lane_nodes, [int(budget_units(node_mib))] * len(lane_rows), stats=stats
     )
+    stats["lanes_replayed"] += int(np.sum(dead))
     results: dict[tuple[str, str, int], ClusterResult] = {}
     for s, (cname, p, nn) in enumerate(lane_keys):
         queue, rows = meta[cname]

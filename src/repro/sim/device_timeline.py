@@ -21,16 +21,20 @@ jitted programs so the boundary semantics live in exactly one place:
   ``Timeline`` uses, so the carry stays bit-identical to the profiles the
   sequential oracle probes.
 
-All programs run in float64 (``nextafter`` switch events are below float32
-resolution at cluster/serving timestamps): callers hold one
-``jax.experimental.enable_x64`` context open across a hot loop; the host
-wrappers only enter one themselves when none is active.
+All programs run in int32, in ``core.timeline``'s units: time keys (the
+instant ``t`` ticks is ``2t``, "just after ``t``" is ``2t + 1`` — the
+right-open step's tie rank) and whole demand units.  ``NEVER`` (int32 max)
+pads every time axis and stands for "never"; ``_NEG`` (int32 min) is the
+max identity of masked running sums.  Integer sums are exact in any order,
+so the device's parallel prefix sums equal the host's sequential ones bit
+for bit.  A key that would pass the horizon can wrap inside a program, so
+the host wrappers check every placement they return
+(``core.timeline.check_horizon``).
 """
 
 from __future__ import annotations
 
 import collections
-import contextlib
 import functools
 import time
 
@@ -38,11 +42,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_compile_cache
+from repro.core.timeline import NEVER, check_horizon
 from repro.kernels.ops import compact_events
 from repro.sim.traces import bucket_size, fine_bucket
 
-enable_compile_cache()
+_NEG = -(2**31)  # int32 min: the max identity of masked demand sums
 
 
 def pad_rows(a: np.ndarray, n: int, fill: float) -> np.ndarray:
@@ -54,13 +58,6 @@ def pad_rows(a: np.ndarray, n: int, fill: float) -> np.ndarray:
     return np.concatenate([a, pad], axis=0)
 
 
-def _x64_ctx():
-    """An ``enable_x64`` context, or a no-op when one is already active."""
-    from jax.experimental import enable_x64
-
-    return contextlib.nullcontext() if jax.config.jax_enable_x64 else enable_x64()
-
-
 # ---------------------------------------------------------------------------
 # Shared per-(candidate, probe) demand pieces.
 # ---------------------------------------------------------------------------
@@ -69,13 +66,13 @@ def _x64_ctx():
 def candidate_probe_parts(P, starts, ends, rels, bnd, val, valext, sw, live, *, inclusive_end: bool):
     """Per-candidate demand pieces at a shared probe set.
 
-    Args (C candidates, Pp probes, k segments; all float64 on device):
-      P: (Pp,) absolute probe instants, +inf padded.
-      starts/ends/rels: (C,) window starts, window ends, release instants.
-      bnd/val: (C, k) each candidate's boundaries / values.
+    Args (C candidates, Pp probes, k segments; int32 keys and units):
+      P: (Pp,) probe keys, ``NEVER`` padded.
+      starts/ends/rels: (C,) window starts, window ends, release keys.
+      bnd/val: (C, k) each candidate's boundary keys / value units.
       valext: (C, k + 1) hold-last values.
-      sw/live: (C, k) absolute switch instants (``nextafter`` past each
-        boundary) and the fired-before-release mask.
+      sw/live: (C, k) switch keys (``start + b + 1``) and the
+        fired-before-release mask.
       inclusive_end: True probes the closed window [start, end] (admission's
         Eq. 1 domain), False the right-open [start, end) (a cluster
         reservation's occupancy window).
@@ -92,11 +89,11 @@ def candidate_probe_parts(P, starts, ends, rels, bnd, val, valext, sw, live, *, 
     idx = jnp.minimum(jnp.sum(bnd[:, None, :] < offs, axis=-1), k - 1)
     A = jnp.take_along_axis(val, idx, axis=1)  # alloc.at(P - start)
     below = (P[None, :] <= ends[:, None]) if inclusive_end else (P[None, :] < ends[:, None])
-    M = (P[None, :] >= starts[:, None]) & below & jnp.isfinite(P)[None, :]
+    M = (P[None, :] >= starts[:, None]) & below & (P != NEVER)[None, :]
     # value after the switches that fired by P, live on [start, release)
     nst = jnp.sum(live[:, None, :] & (sw[:, None, :] <= P[None, :, None]), axis=-1)
     inwin = (P[None, :] >= starts[:, None]) & (P[None, :] < rels[:, None])
-    D = jnp.where(inwin, jnp.take_along_axis(valext, nst, axis=1), 0.0)
+    D = jnp.where(inwin, jnp.take_along_axis(valext, nst, axis=1), 0)
     return A, M, D
 
 
@@ -109,11 +106,11 @@ def candidate_probe_parts(P, starts, ends, rels, bnd, val, valext, sw, live, *, 
 def _count_sorted(tl_t, pred, q_shape):
     """Per-row counts of the prefix satisfying a monotone predicate.
 
-    ``tl_t`` is (N, L), each row ascending (+inf padded); ``pred`` maps
-    gathered time values of shape ``q_shape = (N, Q)`` to a boolean mask and
+    ``tl_t`` is (N, L), each row ascending (``NEVER`` padded); ``pred`` maps
+    gathered keys of shape ``q_shape = (N, Q)`` to a boolean mask and
     must be True on a prefix of every sorted row (e.g. ``t <= p``,
-    ``t < end``, ``(t - c) <= b`` — IEEE subtraction is monotone, so
-    offset predicates bisect exactly like the dense compare-counts).
+    ``t < end``, ``(t - c) <= b`` — offset predicates bisect exactly like
+    the dense compare-counts).
     Returns int32 counts in [0, L]: O(log L) gathers instead of the dense
     O(L) compare-and-sum, with identical values.
     """
@@ -140,7 +137,7 @@ def _range_max_query(tbl, log2_tbl, l, r):
 
     ``tbl`` is (N, P, L) (``kernels.ops.range_max_table`` layout); ``l``/``r``
     are (N, Q) int32 index bounds.  Two overlapping span lookups per query —
-    the classic sparse-table read; -inf for empty windows.
+    the classic sparse-table read; ``_NEG`` for empty windows.
     """
     N, P, L = tbl.shape
     length = jnp.maximum(r - l, 0)
@@ -149,38 +146,37 @@ def _range_max_query(tbl, log2_tbl, l, r):
     flat = tbl.reshape(N, P * L)
     lo = jnp.take_along_axis(flat, p * L + jnp.minimum(l, L - 1), axis=1)
     hi = jnp.take_along_axis(flat, p * L + jnp.maximum(r - span, 0), axis=1)
-    return jnp.where(length > 0, jnp.maximum(lo, hi), -jnp.inf)
+    return jnp.where(length > 0, jnp.maximum(lo, hi), _NEG)
 
 
 def _tie_last(tl_t):
     """(N, L) mask of tie-group-final positions: the sum after event i is a
     settled profile value only when no later event shares its instant (a
     partial mid-tie sum can overshoot and fabricate an overflow)."""
-    return jnp.concatenate(
-        [tl_t[:, :-1] != tl_t[:, 1:], jnp.isfinite(tl_t[:, -1:])], axis=1
-    )
+    return jnp.concatenate([tl_t[:, :-1] != tl_t[:, 1:], tl_t[:, -1:] != NEVER], axis=1)
 
 
 def _plan_events(t_start, b, v, release):
     """One reservation's ~k+2 timeline events on device — the jnp twin of
     ``core.timeline.plan_profile_events``: +v_0 at the start, each step delta
-    at ``nextafter`` past a boundary that fires before ``release`` (Eq. 1
-    steps are right-open), and -v_end at the release, where v_end counts only
-    the switches that actually fired.  Unfired switches park at +inf with a
-    zero delta; the stable time sort keeps the host's event order on ties.
+    at key ``start + b + 1`` for a boundary that fires before ``release``
+    (Eq. 1 steps are right-open), and -v_end at the release, where v_end
+    counts only the switches that actually fired.  Unfired switches park at
+    ``NEVER`` with a zero delta (``b < release - start`` is the overflow-free
+    form of the fired test); the stable key sort keeps the host's event
+    order on ties.
 
     Returns ``(t_new (k+2,), d_new (k+2,), live (k,))``.  Shared by every
     program that commits a placement into a carried timeline
     (``_schedule_program``, ``_sweep_lane``, ``_admission_shard``), so the
     event construction cannot drift from the host ``Timeline``'s.
     """
-    sw = jnp.nextafter(t_start + b, jnp.inf)
-    live = jnp.isfinite(b) & (t_start + b < release)
+    live = b < release - t_start
     steps = jnp.concatenate([jnp.diff(v), jnp.zeros((1,), v.dtype)])
     vext = jnp.concatenate([v, v[-1:]])
     v_end = vext[jnp.sum(live)]
-    t_new = jnp.concatenate([t_start[None], jnp.where(live, sw, jnp.inf), release[None]])
-    d_new = jnp.concatenate([v[:1], jnp.where(live, steps, 0.0), -v_end[None]])
+    t_new = jnp.concatenate([t_start[None], jnp.where(live, t_start + b + 1, NEVER), release[None]])
+    d_new = jnp.concatenate([v[:1], jnp.where(live, steps, 0), -v_end[None]])
     order = jnp.argsort(t_new, stable=True)
     return t_new[order], d_new[order], live
 
@@ -188,8 +184,8 @@ def _plan_events(t_start, b, v, release):
 def _splice_row(tn, t_new, channels):
     """Splice time-sorted new events into one sorted (L,) timeline row,
     ``side="right"``: time-tied newcomers land after existing events, exactly
-    the host ``Timeline._splice`` order.  Dead (+inf) slots pushed past the
-    axis are dropped (compare-counts instead of searchsorted: its scan
+    the host ``Timeline._splice`` order.  Dead (``NEVER``) slots pushed past
+    the axis are dropped (compare-counts instead of searchsorted: its scan
     lowering is a sequential loop, the counts are one vectorized op).
 
     ``channels`` is a list of ``(old (L,), new (n,), fill)`` payload arrays
@@ -201,7 +197,7 @@ def _splice_row(tn, t_new, channels):
     pos_new = jnp.sum(tn[None, :] <= t_new[:, None], axis=1) + jnp.arange(n)
     old_tgt = jnp.arange(L) + jnp.sum(t_new[None, :] < tn[:, None], axis=1)
     t2 = (
-        jnp.full((L,), jnp.inf, tn.dtype)
+        jnp.full((L,), NEVER, tn.dtype)
         .at[old_tgt].set(tn, mode="drop")
         .at[pos_new].set(t_new, mode="drop")
     )
@@ -220,14 +216,14 @@ def _fit_tables(tl_t, tl_d, base0):
     range-max table over the tie-group-final cumulative demand.
 
     Returns ``(csm, tbl)``: ``csm`` (N, L) is the demand after event i
-    (``base0`` included) with non-tie-last positions masked to -inf, and
+    (``base0`` included) with non-tie-last positions masked to ``_NEG``, and
     ``tbl`` (N, P, L) its doubling range-max levels
-    (``kernels.ops.range_max_table``, the Pallas-backed kernel).
+    (``kernels.ops.range_max_table``, the Pallas kernel).
     """
     from repro.kernels.ops import range_max_table
 
     cs = base0[:, None] + jnp.cumsum(tl_d, axis=1)
-    csm = jnp.where(_tie_last(tl_t), cs, -jnp.inf)
+    csm = jnp.where(_tie_last(tl_t), cs, _NEG)
     return csm, range_max_table(csm)
 
 
@@ -239,8 +235,8 @@ def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
     * own probes (the clock + the row's switch instants): profile reads at
       ``#(t <= p)`` via binary search instead of dense compare-counts —
       identical counts, identical gathered sums.  ``csm`` is the running
-      demand sum with non-tie-last positions masked to -inf; a count always
-      lands after a full tie group (every event at an instant <= p is <= p),
+      demand sum with non-tie-last positions masked to ``_NEG``; a count
+      always lands after a full tie group (every event at a key <= p is <= p),
       so the gathers only ever read settled profile values.
     * profile events inside the window: for segment j the dense pass tests
       events with offset > b[j-1] (a *suffix* of the in-window events, since
@@ -252,26 +248,25 @@ def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
       re-probe), the sweep program through a masked reverse running max of
       the carried sums (no (N, P, L) table in its scan carry).  Identical
       maxima either way, and ``max(csm) + v_j > budget`` equals
-      ``any(cs + v_j > budget)`` exactly: float addition of a shared addend
-      is monotone, so the max element alone decides.
+      ``any(cs + v_j > budget)`` exactly: the max element alone decides.
 
     Every count the probe needs — own-probe positions, window ends, window
     starts and per-segment suffix starts — runs through ONE binary-lifting
     pass with per-query (offset, threshold, strictness) parameters: the
-    counts are bit-identical to four separate ``_count_sorted`` calls (same
-    bisection, same predicate values at every step), but on CPU the fused
-    pass costs one O(log L) op chain instead of four.
+    counts are identical to four separate ``_count_sorted`` calls (same
+    bisection, same predicate values at every step), but the fused pass
+    costs one O(log L) op chain instead of four.
     """
     N, L = tl_t.shape
     k = b.shape[0]
     C = cc.shape[0]
     end = cc + pd  # (C,)
-    dur_eff = end - cc  # the scalar's ``end - start`` (not ``pd``)
-    p_sw = jnp.nextafter(cc[:, None] + b[None, :], jnp.inf)  # (C, k)
+    # own switch probes inside the window; an out-of-window (or NEVER-padded)
+    # boundary's key may wrap, and is masked by ``own_ok``
+    p_sw = cc[:, None] + b[None, :] + 1  # (C, k)
     own_p = jnp.concatenate([cc[:, None], p_sw], axis=1)  # (C, k+1)
     own_ok = jnp.concatenate(
-        [jnp.ones((C, 1), bool), (b[None, :] < dur_eff[:, None]) & (p_sw < end[:, None])],
-        axis=1,
+        [jnp.ones((C, 1), bool), jnp.broadcast_to(b[None, :] < pd, (C, k))], axis=1
     )
     offs = own_p - cc[:, None]
     oidx = jnp.minimum(jnp.sum(b[None, None, :] < offs[:, :, None], axis=2), k - 1)
@@ -279,7 +274,7 @@ def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
     # one lifting pass for all counts: queries are "#(t - off <= thr)"
     # (strict ``<`` for the right-open window ends) — the offset-then-compare
     # form every original predicate already had (off = 0 where it subtracted
-    # nothing; IEEE ``t - 0.0`` is exact)
+    # nothing)
     n_own, n_lj = C * (k + 1), C * (k - 1) if k > 1 else 0
     zero_c = jnp.zeros((C,), cc.dtype)
     thr = [own_p.reshape(-1), end, cc]
@@ -323,29 +318,28 @@ def _suffix_max_query(csm, ls, r):
     """The table-free ``qmax`` backend: suffix maxima of ``csm`` over the
     windows [l_j, r) from one masked reverse running max per clock.
 
-    ``rm[i] = max(csm[i:r])`` (elements at or past ``r`` masked to -inf), so
-    the window max is a single gather at ``l_j`` — identical maxima to the
-    sparse-table read over the same index range (max is associative with
-    -inf identity), with O(N C L) streamed data and no carried table.
+    ``rm[i] = max(csm[i:r])`` (elements at or past ``r`` masked to
+    ``_NEG``), so the window max is a single gather at ``l_j`` — identical
+    maxima to the sparse-table read over the same index range (max is
+    associative with a ``_NEG`` identity), with O(N C L) streamed data and
+    no carried table.
     """
     N, L = csm.shape
     C = r.shape[1]
     inwin = jnp.arange(L)[None, None, :] < r[:, :, None]  # (N, C, L)
-    rm = jax.lax.cummax(
-        jnp.where(inwin, csm[:, None, :], -jnp.inf), axis=2, reverse=True
-    )
+    rm = jax.lax.cummax(jnp.where(inwin, csm[:, None, :], _NEG), axis=2, reverse=True)
     g = jnp.take_along_axis(rm, jnp.minimum(ls, L - 1), axis=2)
-    return jnp.where(ls < r[:, :, None], g, -jnp.inf)
+    return jnp.where(ls < r[:, :, None], g, _NEG)
 
 
 @functools.lru_cache(maxsize=None)
 def admission_program():
     """The jitted batch-admission program (compiled per padded shape bucket).
 
-    Shapes: P/prof (Pp,) shared probe set and profile reads; per-candidate
+    Shapes: P/prof (Pp,) shared probe keys and profile reads; per-candidate
     starts/ends/rels/valid (Cp,); bnd/val/sw/live (Cp, k); valext (Cp, k+1).
-    Padding: P with +inf (masked by isfinite), candidates with
-    valid=False / start=+inf (their window and member masks are empty).
+    Padding: P with ``NEVER`` (masked), candidates with valid=False /
+    start=``NEVER`` (their window and member masks are empty).
 
     Per candidate the fit check is the scalar ``demand_exceeds`` with
     ``inclusive_end=True``: max over every probe point in [start, end] of
@@ -369,7 +363,7 @@ def admission_program():
         def step(extra, row):
             a, d, m, ok = row
             admit = ok & ~jnp.any(m & (prof + extra + a > budget))
-            return extra + jnp.where(admit, d, 0.0), admit
+            return extra + jnp.where(admit, d, 0), admit
 
         _, admits = jax.lax.scan(step, jnp.zeros_like(P), (A, D, M, valid))
         return admits
@@ -399,13 +393,13 @@ def _window_program_shared(n_nodes: int):
 
     def run(P, prof, now, ends, rels, bnd, val, valid, cap):
         # Derive the per-row pieces on device (fewer host arrays per call):
-        # all candidates share the epoch clock, switch instants are the same
-        # ``nextafter`` the host used building P, and a cluster reservation
+        # all candidates share the epoch clock, switch keys are the same
+        # ``now + b + 1`` the host used building P, and a cluster reservation
         # releases at its occupancy end (``rels``) while the fit window runs
         # to the full predicted duration (``ends``).
-        starts = jnp.where(valid, now, jnp.inf)
-        sw = jnp.nextafter(now + bnd, jnp.inf)
-        live = jnp.isfinite(bnd) & (now + bnd < rels[:, None])
+        starts = jnp.where(valid, now, NEVER)
+        live = bnd < rels[:, None] - now
+        sw = jnp.where(live, now + bnd + 1, NEVER)
         valext = jnp.concatenate([val, val[:, -1:]], axis=1)
         A, M, D = candidate_probe_parts(
             P, starts, ends, rels, bnd, val, valext, sw, live, inclusive_end=False
@@ -419,7 +413,7 @@ def _window_program_shared(n_nodes: int):
             fit = ~over
             can = ok & ~blocked & jnp.any(fit)
             node = jnp.argmax(fit)  # first-fit: lowest fitting node index
-            extra = extra + jnp.where((can & (node_ids == node))[:, None], d[None, :], 0.0)
+            extra = extra + jnp.where((can & (node_ids == node))[:, None], d[None, :], 0)
             return (extra, blocked | (ok & ~can)), (can, node)
 
         init = (jnp.zeros_like(prof), jnp.asarray(False))
@@ -452,11 +446,10 @@ def _window_program_pernode(n_nodes: int):
     the padded probe axis is sized by one node's events, not the union
     across the cluster.  Candidate values and committed demand at the probes
     unroll into k fused passes over (N, Pp): for values via the monotone
-    comparison trick (exists j <= #(b < off) with demand + v_j > cap —
-    rounding is monotone in the addend, so the decision is bit-equal to
-    reading v[#(b < off)]); for committed demand via the step-delta sum
-    (v_0 + fired step deltas — the same deltas the host ``Timeline``
-    accumulates).
+    comparison trick (exists j <= #(b < off) with demand + v_j > cap — values
+    are non-decreasing, so the decision equals reading v[#(b < off)]); for
+    committed demand via the step-delta sum (v_0 + fired step deltas — the
+    same deltas the host ``Timeline`` accumulates).
     """
 
     def run(P, prof, now, ends, rels, bnd, val, valid, cap):
@@ -465,9 +458,9 @@ def _window_program_pernode(n_nodes: int):
         # strictly-future node events), so window membership per row is just
         # "before this row's end"
         off = P - now  # (N, Pp) candidate-relative offsets
-        fin = jnp.isfinite(P)
-        sw = jnp.nextafter(now + bnd, jnp.inf)  # (W, k)
-        live = jnp.isfinite(bnd) & (now + bnd < rels[:, None])
+        fin = P != NEVER
+        live = bnd < rels[:, None] - now
+        sw = jnp.where(live, now + bnd + 1, NEVER)  # (W, k)
         steps = jnp.concatenate([jnp.diff(val, axis=1), jnp.zeros_like(val[:, :1])], axis=1)
         k = bnd.shape[1]
 
@@ -484,11 +477,11 @@ def _window_program_pernode(n_nodes: int):
             # committed demand at the placed node's probes only (1, Pp): the
             # value after the fired switches, live on [now, release)
             Pn = P[node]
-            inwin = jnp.isfinite(Pn) & (Pn < rel)
-            d = inwin * v[0]
+            inwin = (Pn != NEVER) & (Pn < rel)
+            d = jnp.where(inwin, v[0], 0)
             for j in range(k):
-                d = d + jnp.where(inwin & live_r[j] & (sw_r[j] <= Pn), st_r[j], 0.0)
-            S = S.at[node].add(jnp.where(can, d, 0.0))
+                d = d + jnp.where(inwin & live_r[j] & (sw_r[j] <= Pn), st_r[j], 0)
+            S = S.at[node].add(jnp.where(can, d, 0))
             return (S, blocked | (ok & ~can)), (can, node)
 
         init = (prof, jnp.asarray(False))
@@ -503,33 +496,32 @@ def _window_program_pernode(n_nodes: int):
 
 
 def first_fit_window(
-    now: float,
+    now: int,
     bnd: np.ndarray,
     val: np.ndarray,
     run_times: np.ndarray,
     probe_times: np.ndarray,
     profiles: list[tuple[np.ndarray, np.ndarray]],
-    capacity_budget: float,
+    capacity_budget: int,
     window_bucket: int = 32,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Decide first-fit placements for one window of rows at a fixed clock.
 
-    Args:
+    Args (``core.timeline`` units: keys and demand units):
       now: the epoch clock — every candidate's start.
-      bnd/val: (w, k) the rows' allocation schedules (already node-capped).
-      run_times: (w,) occupancy durations (release instants); probe_times:
+      bnd/val: (w, k) the rows' allocation steps (already node-capped).
+      run_times: (w,) occupancy durations (release keys); probe_times:
         (w,) fit-window durations (the full predicted duration).
-      profiles: per node, the cached ``(event times, cumulative demand)``
+      profiles: per node, the cached ``(event keys, cumulative demand)``
         arrays of its ``Timeline`` (``NodeState.profile_arrays``).
-      capacity_budget: the fits budget (capacity + eps, as ``NodeState.fits``).
+      capacity_budget: the fits budget (``NodeState.budget``).
       window_bucket: rows are padded to this static size.
 
-    Probes are the instants where combined step demand can rise: the clock,
-    every candidate's switch instants, and profile events inside the widest
-    fit window, always deduped (``core.timeline.shared_probe_set`` — switch
-    instants and dyadic completion times repeat heavily, so the sorted
-    unique union often drops the padded probe bucket a power of two).  Two
-    exact, decision-identical program variants share the work differently:
+    Probes are the keys where combined step demand can rise: the clock,
+    every candidate's switch keys inside its window, and profile events
+    inside the widest fit window, always deduped
+    (``core.timeline.shared_probe_set``).  Two exact, decision-identical
+    program variants share the work differently:
 
     * **shared** — one probe union across nodes; per-candidate pieces
       precomputed once per call (cheap when the union stays small).
@@ -549,9 +541,10 @@ def first_fit_window(
     N = len(profiles)
     ends = now + probe_times
     rels = now + run_times
-    sw = np.nextafter(now + bnd, np.inf)  # switch instants (right-open steps)
-    tmax = float(ends.max())
-    csw = shared_probe_set(np.asarray([now]), sw[np.isfinite(sw)])
+    check_horizon(ends)
+    inwin = bnd < probe_times[:, None]  # switch keys below the window end
+    csw = shared_probe_set(np.asarray([now]), now + bnd[inwin] + 1)
+    tmax = int(ends.max())
     evs = [t[(t > now) & (t < tmax)] for t, _ in profiles]
     Wb = int(window_bucket)
     n_shared = len(csw) + sum(len(e) for e in evs)  # upper bound pre-dedup
@@ -561,32 +554,32 @@ def first_fit_window(
     if use_shared:
         P = shared_probe_set(csw, *evs)
         Pp = fine_bucket(len(P), floor=128)
-        prof = np.zeros((N, Pp))
+        prof = np.zeros((N, Pp), dtype=np.int32)
         for n, (t, c) in enumerate(profiles):
             prof[n, : len(P)] = c[np.searchsorted(t, P, side="right")]
-        P = np.concatenate([P, np.full(Pp - len(P), np.inf)])
+        P = np.concatenate([P, np.full(Pp - len(P), NEVER)]).astype(np.int32)
         program = _window_program_shared(N)
     else:
         pns = [shared_probe_set(csw, e) for e in evs]
         Pp = fine_bucket(max(len(p) for p in pns), floor=128)
-        P = np.full((N, Pp), np.inf)
-        prof = np.zeros((N, Pp))
+        P = np.full((N, Pp), NEVER, dtype=np.int32)
+        prof = np.zeros((N, Pp), dtype=np.int32)
         for n, ((t, c), pn) in enumerate(zip(profiles, pns)):
             P[n, : len(pn)] = pn
             prof[n, : len(pn)] = c[np.searchsorted(t, pn, side="right")]
         program = _window_program_pernode(N)
+    i32 = np.int32
     args = (
         P,
         prof,
-        float(now),
-        pad_rows(ends, Wb, -np.inf),
-        pad_rows(rels, Wb, -np.inf),
-        pad_rows(bnd, Wb, np.inf),
-        pad_rows(val, Wb, 0.0),
+        i32(now),
+        pad_rows(ends.astype(i32), Wb, -1),
+        pad_rows(rels.astype(i32), Wb, -1),
+        pad_rows(bnd.astype(i32), Wb, NEVER),
+        pad_rows(val.astype(i32), Wb, 0),
         pad_rows(np.ones(w, dtype=bool), Wb, False),
     )
-    with _x64_ctx():
-        placed, node = program(*args, np.float64(capacity_budget))
+    placed, node = program(*args, i32(capacity_budget))
     return np.asarray(placed)[:w], np.asarray(node)[:w]
 
 
@@ -600,23 +593,23 @@ def first_fit_window(
 def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, valid, budget):
     """One scheduling epoch on device (shapes fix the compiled variant).
 
-    Args:
-      tl_t/tl_d: (N, L) per-node event times (sorted, +inf padded) and
+    Args (int32 keys and demand units):
+      tl_t/tl_d: (N, L) per-node event keys (sorted, ``NEVER`` padded) and
         demand deltas (0 padded) — ``Timeline.events()`` seeded.  Only
         events after the epoch clock are carried; ``base0`` (N,) is each
         node's cumulative demand at the clock (the folded prefix — every
         probe is at or after the clock, so earlier events only ever enter
         through this sum).
-      ev: (H,) pending completion instants (+inf padded, +inf = free slot).
+      ev: (H,) pending completion keys (``NEVER`` = free slot).
       h0: number of real entries in ``ev`` (placements push at ``h0 + row``).
       now0: the epoch's starting clock.
-      bnd/val: (W, k) candidate allocation schedules (inf-padded rows are
+      bnd/val: (W, k) candidate allocation steps (``NEVER``-padded rows are
         the k = 1 baselines, which hold their value anyway).
       run: (W,) occupancy durations (a failed attempt holds its node only
         up to the kill); pdur: (W,) fit-check window durations (the
         scheduler probes the full predicted duration — it cannot know an
         attempt will die early); valid: (W,) real-row mask.
-      budget: the fits budget (capacity + eps, as ``NodeState.fits``).
+      budget: the fits budget (``NodeState.budget``).
 
     A ``lax.scan`` walks the rows in queue order.  Per row, a bounded
     ``while_loop`` mirrors the sequential oracle's ``_find_slot``: probe
@@ -680,16 +673,16 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
             # oracle pops one event, re-probes, pops the next ... — the
             # chunk evaluates those same probes (each at max(now, t_i))
             # together and consumes exactly the events the oracle would
-            neg, idx = jax.lax.top_k(-ev_, CH)  # CH smallest times, ascending
+            neg, idx = jax.lax.top_k(-ev_, CH)  # CH smallest keys, ascending
             tt = -neg
-            fin = jnp.isfinite(tt)
+            fin = tt != NEVER
             cc = jnp.maximum(t, tt)
             F = fit_many(jnp.where(fin, cc, t)) & fin[:, None]  # (CH, N)
             anyfit = jnp.any(F, axis=1)
             hit = jnp.any(anyfit)
             i = jnp.argmax(anyfit)
             npop = jnp.where(hit, i + 1, jnp.sum(fin)).astype(jnp.int32)
-            ev2 = ev_.at[idx].set(jnp.where(jnp.arange(CH) < npop, jnp.inf, tt))
+            ev2 = ev_.at[idx].set(jnp.where(jnp.arange(CH) < npop, NEVER, tt))
             last = jnp.maximum(npop - 1, 0)
             t2 = jnp.where(hit, cc[i], jnp.where(npop > 0, cc[last], t))
             node2 = jnp.argmax(F[i]).astype(jnp.int32)
@@ -708,7 +701,7 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
             # the row's ~k+2 timeline events (exactly plan_profile_events'),
             # spliced into the node's sorted timeline side="right"
             t_new, d_new, _ = _plan_events(t_f, b, v, end)
-            t2, d2 = _splice_row(tl_t[node], t_new, [(tl_d[node], d_new, 0.0)])
+            t2, d2 = _splice_row(tl_t[node], t_new, [(tl_d[node], d_new, 0)])
             return tl_t.at[node].set(t2), tl_d.at[node].set(d2), ev_.at[h0 + ridx].set(end)
 
         tl_t2, tl_d2, ev2 = jax.lax.cond(placed, commit, lambda a: a, (tl_t, tl_d, ev_f))
@@ -748,26 +741,26 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
 
 
 def schedule_epoch(
-    now: float,
+    now: int,
     bnd: np.ndarray,
     val: np.ndarray,
     run_times: np.ndarray,
     node_events: list[tuple[np.ndarray, np.ndarray]],
     pending: np.ndarray,
-    capacity_budget: float,
+    capacity_budget: int,
     window_bucket: int = 32,
     probe_times: np.ndarray | None = None,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, float, int, int, bool]:
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int, int, int, bool]:
     """Place up to one window of attempt rows, resolving waits in-program.
 
-    Args:
+    Args (``core.timeline`` units: keys and demand units):
       now: the scheduling clock at epoch start.
-      bnd/val: (w, k) the rows' allocation schedules (already node-capped).
+      bnd/val: (w, k) the rows' allocation steps (already node-capped).
       run_times: (w,) each row's occupancy duration.
-      node_events: per node, ``Timeline.events()`` — the sorted event times
+      node_events: per node, ``Timeline.events()`` — the sorted event keys
         and demand deltas of its reservation profile.
-      pending: (E,) completion instants still in the scheduler's wait heap.
-      capacity_budget: the fits budget (capacity + eps, as ``NodeState.fits``).
+      pending: (E,) completion keys still in the scheduler's wait heap.
+      capacity_budget: the fits budget (``NodeState.budget``).
       window_bucket: rows are padded to this static size; timeline/heap axes
         are bucketed so compiled shapes stay bounded.
       probe_times: (w,) fit-check window durations — the full predicted
@@ -779,10 +772,10 @@ def schedule_epoch(
     that aborted on a full per-node commit buffer (the caller re-dispatches;
     nothing about the row was consumed) or, with ``dead`` True, past a row
     that drained the heap with no fit (unreachable for capped allocations;
-    the caller falls back to the oracle's +1.0 clock walk).  ``start`` is
-    each placed row's clock; ``n_pops`` pending events were consumed (the
+    the caller falls back to the oracle's one-second clock walk).  ``start``
+    is each placed row's clock; ``n_pops`` pending events were consumed (the
     n_pops smallest of ``pending`` + this epoch's own completions — pop
-    order among time-ties is unobservable); ``n_waited`` rows waited
+    order among key-ties is unobservable); ``n_waited`` rows waited
     in-program.
     """
     w, k = bnd.shape
@@ -790,55 +783,50 @@ def schedule_epoch(
     N = len(node_events)
     # Fold each node's events at or before the clock into a scalar base
     # demand: every probe the program evaluates is at or after ``now``, so
-    # the prefix only ever enters as its cumulative sum — carrying it as a
-    # scalar keeps the padded timeline axis sized by *future* events.  The
-    # base is the sequential ``np.cumsum`` prefix, the same value the host
-    # profile's ``arrays()`` reads at the clock (``np.sum`` would not do:
-    # its pairwise accumulation rounds differently past ~128 elements).
+    # the prefix only ever enters as its sum — carrying it as a scalar keeps
+    # the padded timeline axis sized by *future* events.
     cuts = [np.searchsorted(t, now, side="right") for t, _ in node_events]
-    base0 = np.asarray(
-        [np.cumsum(d[:c])[-1] if c else 0.0 for (_, d), c in zip(node_events, cuts)]
-    )
+    base0 = np.asarray([d[:c].sum() for (_, d), c in zip(node_events, cuts)], dtype=np.int32)
     e0 = max((len(t) - c for (t, _), c in zip(node_events, cuts)), default=0)
     # capacity for one node's in-epoch commits (the program's CAP; beyond it
     # the epoch aborts and the host re-dispatches with fresh timelines)
     L = fine_bucket(e0 + max(2, min(Wb, 8)) * (k + 2), floor=64)
-    tl_t = np.full((N, L), np.inf)
-    tl_d = np.zeros((N, L))
+    tl_t = np.full((N, L), NEVER, dtype=np.int32)
+    tl_d = np.zeros((N, L), dtype=np.int32)
     for n, ((t, d), c) in enumerate(zip(node_events, cuts)):
         tl_t[n, : len(t) - c] = t[c:]
         tl_d[n, : len(d) - c] = d[c:]
     h0 = len(pending)
     H = bucket_size(h0 + Wb, floor=32)
-    ev = np.full(H, np.inf)
-    ev[:h0] = np.sort(np.asarray(pending, dtype=np.float64))
+    ev = np.full(H, NEVER, dtype=np.int32)
+    ev[:h0] = np.sort(np.asarray(pending, dtype=np.int64))
     if probe_times is None:
         probe_times = run_times
+    i32 = np.int32
     args = (
         tl_t,
         tl_d,
         base0,
         ev,
-        np.int32(h0),
-        np.float64(now),
-        pad_rows(np.asarray(bnd, dtype=np.float64), Wb, np.inf),
-        pad_rows(np.asarray(val, dtype=np.float64), Wb, 0.0),
-        pad_rows(np.asarray(run_times, dtype=np.float64), Wb, 0.0),
-        pad_rows(np.asarray(probe_times, dtype=np.float64), Wb, 0.0),
+        i32(h0),
+        i32(now),
+        pad_rows(np.asarray(bnd, dtype=i32), Wb, NEVER),
+        pad_rows(np.asarray(val, dtype=i32), Wb, 0),
+        pad_rows(np.asarray(run_times, dtype=i32), Wb, 0),
+        pad_rows(np.asarray(probe_times, dtype=i32), Wb, 0),
         pad_rows(np.ones(w, dtype=bool), Wb, False),
-        np.float64(capacity_budget),
+        i32(capacity_budget),
     )
-    with _x64_ctx():
-        placed, node, start, now_f, pops, waited, dead = _schedule_program(*args)
-        return (
-            np.asarray(placed)[:w],
-            np.asarray(node, dtype=np.int64)[:w],
-            np.asarray(start, dtype=np.float64)[:w],
-            float(now_f),
-            int(pops),
-            int(waited),
-            bool(dead),
-        )
+    placed, node, start, now_f, pops, waited, dead = _schedule_program(*args)
+    return (
+        np.asarray(placed)[:w],
+        np.asarray(node, dtype=np.int64)[:w],
+        np.asarray(start, dtype=np.int64)[:w],
+        int(now_f),
+        int(pops),
+        int(waited),
+        bool(dead),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -861,8 +849,7 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
     * outer scan (chunks of ``_SWEEP_W`` rows) — folds events at or before
       the clock into each node's base demand (the in-program twin of the
       host fold ``schedule_epoch`` does between epochs), then compacts the
-      survivors by dominance: every event whose delta leaves the running
-      sum's bits unchanged is scatter-compacted away
+      survivors: every zero-delta event is scatter-compacted away
       (``kernels.ops.compact_events``), so the carried axis stays sized by
       demand-shape-changing breakpoints — O(live breakpoints), not O(all
       events ever) — and the running demand sums are rebuilt over the
@@ -887,7 +874,7 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
       on CPU the scan bookkeeping dominates an un-unrolled body.
 
     Per-lane node counts are handled by ``nmask`` (invalid nodes never fit);
-    rows are +inf/False padded to the lane grid's shared shape.  ``overflow``
+    rows are ``NEVER``/False padded to the lane grid's shared shape.  ``overflow``
     reports a node timeline outgrowing L — the commits' ``mode="drop"``
     splices silently lose events past it, so the host re-dispatches with a
     doubled axis.  ``dead`` is a drained heap with no fit (unreachable for
@@ -912,37 +899,31 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
         # axis sized by *future* events.
         nowq = jnp.broadcast_to(now, (N, 1))
         cnt = _count_sorted(tl_t, lambda t: t <= nowq, (N, 1))
-        gain = jnp.take_along_axis(jnp.cumsum(tl_d, axis=1), jnp.maximum(cnt - 1, 0), axis=1)
-        base = base + jnp.where(cnt > 0, gain, 0.0)[:, 0]
+        gain = jnp.sum(jnp.where(jnp.arange(L)[None, :] < cnt, tl_d, 0), axis=1)
+        base = base + gain
         idx = jnp.arange(L)[None, :] + cnt
         ahead = idx < L
         idxc = jnp.minimum(idx, L - 1)
-        tl_t = jnp.where(ahead, jnp.take_along_axis(tl_t, idxc, axis=1), jnp.inf)
-        tl_d = jnp.where(ahead, jnp.take_along_axis(tl_d, idxc, axis=1), 0.0)
+        tl_t = jnp.where(ahead, jnp.take_along_axis(tl_t, idxc, axis=1), NEVER)
+        tl_d = jnp.where(ahead, jnp.take_along_axis(tl_d, idxc, axis=1), 0)
         # Dominance compaction (the epoch re-fold of this lane's carry): the
         # clock fold above removes almost nothing under generous node memory
         # because reservations release late, but most surviving events do not
         # change the shape of future demand — zero steps from capped flat
         # profiles, coincident +/- cancellations, telescoped release groups,
-        # equal-value runs.  Drop every event whose delta leaves the running
-        # sum's BITS unchanged: the recomputed prefix sum then passes through
-        # exactly the same accumulator values at every kept position, every
-        # probe count still lands at a tie-group boundary, and a dropped
-        # breakpoint's settled value is always re-read at its surviving
-        # predecessor (or the own probe at the window start) under the same
-        # segment demand — so placements stay bit-exact against the windows
-        # engine while the carried axis stays sized by live breakpoints
-        # instead of every event the run ever placed (the reason the deep
-        # congested lanes previously outgrew the axis ~4x).
-        cs = base[:, None] + jnp.cumsum(tl_d, axis=1)
-        keep = jnp.isfinite(tl_t) & (
-            cs != jnp.concatenate([base[:, None], cs[:, :-1]], axis=1)
-        )
+        # equal-value runs.  Drop every zero-delta event: the recomputed
+        # prefix sum then passes through exactly the same values at every
+        # kept position, every probe count still lands at a tie-group
+        # boundary, and a dropped breakpoint's settled value is always
+        # re-read at its surviving predecessor (or the own probe at the
+        # window start) under the same segment demand — so placements stay
+        # exact against the windows engine while the carried axis stays
+        # sized by live breakpoints instead of every event the run ever
+        # placed.
+        keep = (tl_t != NEVER) & (tl_d != 0)
         tl_t, tl_d = compact_events(tl_t, tl_d, keep)
         hw = jnp.maximum(hw, jnp.max(jnp.sum(keep, axis=1)).astype(jnp.int32))
-        csm0 = jnp.where(
-            _tie_last(tl_t), base[:, None] + jnp.cumsum(tl_d, axis=1), -jnp.inf
-        )
+        csm0 = jnp.where(_tie_last(tl_t), base[:, None] + jnp.cumsum(tl_d, axis=1), _NEG)
 
         def row_step(icarry, x):
             now, tl_t, tl_d, csm, ev, pops, waited, dead_any, over_any = icarry
@@ -969,14 +950,14 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
                 # identical chunked-pop semantics to ``_schedule_program``
                 neg, hidx = jax.lax.top_k(-ev_, CH)
                 tt = -neg
-                fin = jnp.isfinite(tt)
+                fin = tt != NEVER
                 cc = jnp.maximum(t, tt)
                 F = fit_many(jnp.where(fin, cc, t)) & fin[:, None]  # (CH, N)
                 anyfit = jnp.any(F, axis=1)
                 hit = jnp.any(anyfit)
                 i = jnp.argmax(anyfit)
                 npop = jnp.where(hit, i + 1, jnp.sum(fin)).astype(jnp.int32)
-                ev2 = ev_.at[hidx].set(jnp.where(jnp.arange(CH) < npop, jnp.inf, tt))
+                ev2 = ev_.at[hidx].set(jnp.where(jnp.arange(CH) < npop, NEVER, tt))
                 last = jnp.maximum(npop - 1, 0)
                 t2 = jnp.where(hit, cc[i], jnp.where(npop > 0, cc[last], t))
                 node2 = jnp.argmax(F[i]).astype(jnp.int32)
@@ -996,14 +977,14 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
             # single-node writes keep the per-row carry traffic at O(k L)
             # and let XLA update the scan carry in place.
             t_new, d_new, live = _plan_events(t_f, b, v, end)
-            n_fin = jnp.sum(jnp.isfinite(tl_t[node]))
+            n_fin = jnp.sum(tl_t[node] != NEVER)
             over_loc = placed & (n_fin + 2 + jnp.sum(live) > L)
             tn, dn = tl_t[node], tl_d[node]
-            t2, d2 = _splice_row(tn, t_new, [(dn, d_new, 0.0)])
+            t2, d2 = _splice_row(tn, t_new, [(dn, d_new, 0)])
             # probe state refresh for the placed node only: one O(L) running
             # sum (tie-masked in place) instead of an all-nodes rebuild
-            tie_n = jnp.concatenate([t2[:-1] != t2[1:], jnp.isfinite(t2[-1:])])
-            csm_n = jnp.where(tie_n, base[node] + jnp.cumsum(d2), -jnp.inf)
+            tie_n = jnp.concatenate([t2[:-1] != t2[1:], t2[-1:] != NEVER])
+            csm_n = jnp.where(tie_n, base[node] + jnp.cumsum(d2), _NEG)
             tl_t2 = tl_t.at[node].set(jnp.where(placed, t2, tn))
             tl_d2 = tl_d.at[node].set(jnp.where(placed, d2, dn))
             csm2 = csm.at[node].set(jnp.where(placed, csm_n, csm[node]))
@@ -1042,9 +1023,9 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
     init = (
         jnp.zeros((), dt),  # the lane's cluster starts empty at clock 0
         jnp.zeros((N,), dt),
-        jnp.full((N, L), jnp.inf, dt),
+        jnp.full((N, L), NEVER, dt),
         jnp.zeros((N, L), dt),
-        jnp.full((R,), jnp.inf, dt),  # release heap: one slot per row
+        jnp.full((R,), NEVER, dt),  # release heap: one slot per row
         jnp.zeros((), jnp.int32),
         jnp.zeros((), jnp.int32),
         jnp.asarray(False),
@@ -1089,7 +1070,7 @@ def _admission_shard(
       base0: () folded demand — the cumulative sum of every event at or
         before the shard's clock (the in-carry twin of ``schedule_epoch``'s
         host-side fold).
-      tl_t/tl_d: (L,) sorted future event times (+inf padded) and deltas.
+      tl_t/tl_d: (L,) sorted future event keys (``NEVER`` padded) and deltas.
       tl_c: (L,) int32 owner codes per event (-1 = empty slot).
       slot_fold: (Smax,) per-owner sums of the deltas already folded into
         ``base0`` — what a release must subtract back out when its plan's
@@ -1118,7 +1099,7 @@ def _admission_shard(
     batch's returned ``n_live`` (releases and the fold only shrink the live
     prefix, so ``Lp >= n_live`` holds at decision time).  The full L axis is
     touched only by the O(L) bookkeeping (releases, fold, final splice) —
-    that split is what keeps a long-lived timeline (large L, mostly +inf
+    that split is what keeps a long-lived timeline (large L, mostly ``NEVER``
     padding) from taxing every decision.
 
     Returns ``(admits (Cb,), overflow (), n_live (), *state)``; ``overflow``
@@ -1143,12 +1124,12 @@ def _admission_shard(
         jnp.zeros((Smax + 1,), bool).at[jnp.where(rv, rel_codes, Smax)].set(True, mode="drop")
     )
     gone = rel_mask[jnp.where(tl_c >= 0, tl_c, Smax)]
-    base0 = base0 - jnp.sum(jnp.where(rv, slot_fold[jnp.clip(rel_codes, 0)], 0.0))
-    slot_fold = slot_fold.at[jnp.where(rv, rel_codes, Smax)].set(0.0, mode="drop")
+    base0 = base0 - jnp.sum(jnp.where(rv, slot_fold[jnp.clip(rel_codes, 0)], 0))
+    slot_fold = slot_fold.at[jnp.where(rv, rel_codes, Smax)].set(0, mode="drop")
     keep = ~gone
     tgt = jnp.cumsum(keep) - 1
     dst = jnp.where(keep, tgt, L)
-    tl_t = jnp.full((L,), jnp.inf, tl_t.dtype).at[dst].set(tl_t, mode="drop")
+    tl_t = jnp.full((L,), NEVER, tl_t.dtype).at[dst].set(tl_t, mode="drop")
     tl_d = jnp.zeros((L,), tl_d.dtype).at[dst].set(tl_d, mode="drop")
     tl_c = jnp.full((L,), -1, tl_c.dtype).at[dst].set(tl_c, mode="drop")
 
@@ -1157,22 +1138,22 @@ def _admission_shard(
     # prefix only ever enters as its cumulative sum.
     fold = tl_t <= t0
     cnt = jnp.sum(fold).astype(jnp.int32)
-    dfold = jnp.where(fold, tl_d, 0.0)
-    base0 = base0 + jnp.cumsum(dfold)[-1]
+    dfold = jnp.where(fold, tl_d, 0)
+    base0 = base0 + jnp.sum(dfold)
     slot_fold = slot_fold.at[jnp.where(fold & (tl_c >= 0), tl_c, Smax)].add(
         dfold, mode="drop"
     )
     idx = jnp.arange(L) + cnt
     kept = idx < L
     idxc = jnp.minimum(idx, L - 1)
-    tl_t = jnp.where(kept, tl_t[idxc], jnp.inf)
-    tl_d = jnp.where(kept, tl_d[idxc], 0.0)
+    tl_t = jnp.where(kept, tl_t[idxc], NEVER)
+    tl_d = jnp.where(kept, tl_d[idxc], 0)
     tl_c = jnp.where(kept, tl_c[idxc], -1)
 
     # 3. fresh fold slots for this batch's candidate codes (the host only
     # recycles a code after its release has been applied here, so these are
     # already zero — the scatter is a cheap idempotent guard).
-    slot_fold = slot_fold.at[jnp.where(valid, codes, Smax)].set(0.0, mode="drop")
+    slot_fold = slot_fold.at[jnp.where(valid, codes, Smax)].set(0, mode="drop")
 
     # 4. probe parts, precomputed VECTORIZED over the whole batch — the
     # ``admission_program`` cost shape: the sequential scan below is down to
@@ -1197,16 +1178,15 @@ def _admission_shard(
     pd = tl_d[:Lp]
     # can't-happen guard: a live event beyond the decision prefix means the
     # host undersized Lp — flag it through the same reseed+replay overflow
-    prefix_over = jnp.isfinite(tl_t[Lp]) if Lp < L else jnp.asarray(False)
+    prefix_over = (tl_t[Lp] != NEVER) if Lp < L else jnp.asarray(False)
     cs = base0 + jnp.cumsum(pd)  # carried demand after event i
     cs0 = jnp.concatenate([base0[None], cs])
-    tie = jnp.concatenate([pt[:-1] != pt[1:], jnp.isfinite(pt[-1:])])
+    tie = jnp.concatenate([pt[:-1] != pt[1:], pt[-1:] != NEVER])
 
-    # candidate event tables: (Cb, k+2) times/deltas in host splice order
+    # candidate event tables: (Cb, k+2) keys/deltas in host splice order
     t_new, d_new, live = jax.vmap(_plan_events)(starts, bnd, val, rels)
-    sw = jnp.nextafter(starts[:, None] + bnd, jnp.inf)
     Q = jnp.concatenate(
-        [starts[:, None], jnp.where(live, sw, jnp.inf)], axis=1
+        [starts[:, None], jnp.where(live, starts[:, None] + bnd + 1, NEVER)], axis=1
     ).reshape(-1)  # shared probe family 2: (Cb * (k+1),)
 
     # carried profile at the Q points: all deltas at or before q
@@ -1249,8 +1229,8 @@ def _admission_shard(
         )
         admit = ok & ~over
         return (
-            extra_ev + jnp.where(admit, ec, 0.0),
-            extra_q + jnp.where(admit, qc, 0.0),
+            extra_ev + jnp.where(admit, ec, 0),
+            extra_q + jnp.where(admit, qc, 0),
         ), admit
 
     _, admits = jax.lax.scan(
@@ -1263,17 +1243,17 @@ def _admission_shard(
     # 5. one batched splice: every admitted candidate's events merge into
     # the carried timeline in a single stable sort (old events first on
     # ties, then candidates in admission order — the host splice order).
-    new_t = jnp.where(admits[:, None], t_new, jnp.inf).reshape(-1)
-    new_d = jnp.where(admits[:, None], d_new, 0.0).reshape(-1)
+    new_t = jnp.where(admits[:, None], t_new, NEVER).reshape(-1)
+    new_d = jnp.where(admits[:, None], d_new, 0).reshape(-1)
     new_c = (
         jnp.broadcast_to(jnp.where(admits, codes, -1)[:, None], t_new.shape)
         .astype(tl_c.dtype)
         .reshape(-1)
     )
-    # only the decision prefix can hold finite events (prefix_over guards
-    # the rest), so the sort runs over Lp + Cb*(k+2) lanes and the +inf tail
-    # rides along unsorted — concat keeps global order because both parts
-    # end in +inf padding
+    # only the decision prefix can hold live events (prefix_over guards
+    # the rest), so the sort runs over Lp + Cb*(k+2) lanes and the NEVER
+    # tail rides along unsorted — concat keeps global order because both
+    # parts end in NEVER padding
     head_t = jnp.concatenate([pt, new_t])
     head_d = jnp.concatenate([pd, new_d])
     head_c = jnp.concatenate([tl_c[:Lp], new_c])
@@ -1282,9 +1262,9 @@ def _admission_shard(
     comb_d = jnp.concatenate([head_d[order], tl_d[Lp:]])
     comb_c = jnp.concatenate([head_c[order], tl_c[Lp:]])
     # a real event falling off the axis, or a live prefix past Lp
-    overflow = jnp.isfinite(comb_t[L]) | prefix_over
+    overflow = (comb_t[L] != NEVER) | prefix_over
     tl_t, tl_d, tl_c = comb_t[:L], comb_d[:L], comb_c[:L]
-    n_live = jnp.sum(jnp.isfinite(tl_t)).astype(jnp.int32)
+    n_live = jnp.sum(tl_t != NEVER).astype(jnp.int32)
     return admits, overflow, n_live, base0, tl_t, tl_d, tl_c, slot_fold
 
 
@@ -1294,10 +1274,10 @@ def admission_epoch(n_dev: int = 1, Lp: int | None = None):
 
     ``_admission_shard`` vmapped over shards (state/batch inputs carry a
     leading S axis; ``t0``/``budget`` broadcast) and, for ``n_dev > 1``,
-    ``shard_map``-partitioned across that many devices via the
-    ``repro.compat`` shim — shards are independent (each owns its slice of
-    the budget), so the program needs no collectives and the mapped body is
-    embarrassingly parallel.  S must be divisible by ``n_dev``.
+    ``jax.shard_map``-partitioned across that many devices — shards are
+    independent (each owns its slice of the budget), so the program needs no
+    collectives and the mapped body is embarrassingly parallel.  S must be
+    divisible by ``n_dev``.
 
     ``Lp`` is the static decision-prefix length (see ``_admission_shard``);
     ``None`` probes the full timeline axis.
@@ -1311,10 +1291,10 @@ def admission_epoch(n_dev: int = 1, Lp: int | None = None):
     if n_dev > 1:
         from jax.sharding import PartitionSpec
 
-        from repro.compat import device_mesh, shard_map
+        from repro.compat import device_mesh
 
         sh, rep = PartitionSpec("shards"), PartitionSpec()
-        run = shard_map(
+        run = jax.shard_map(
             run,
             mesh=device_mesh(n_dev),
             in_specs=(sh,) * 13 + (rep, rep),
@@ -1407,13 +1387,13 @@ def sweep_schedule(
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Schedule every lane of a design space in one vmapped dispatch.
 
-    Args:
+    Args (``core.timeline`` units: keys and demand units):
       lane_rows: per lane, ``(bnd (r, k), val (r, k), run (r,), probe (r,))``
         attempt rows in queue order (``_policy_rows`` layout: values already
         node-capped, run = occupancy, probe = fit-check duration).
       lane_nodes: per lane, its cluster's node count (lanes may differ; the
         program masks nodes past each lane's count).
-      lane_budgets: per lane, the fits budget (capacity + eps).
+      lane_budgets: per lane, the fits budget (``NodeState.budget``).
       timeline_floor/timeline_cap: initial / maximal per-node timeline axis.
         A lane whose concurrent future events outgrow the axis flags
         overflow and the whole grid re-dispatches with the axis doubled
@@ -1425,22 +1405,23 @@ def sweep_schedule(
         carried-breakpoint high-water marks) and ``timeline_axis`` (the L
         the grid settled on).
 
-    Rows are padded to a shared ``(S, R, k)`` grid: row axes with +inf
+    Rows are padded to a shared ``(S, R, k)`` grid: row axes with ``NEVER``
     boundaries / False valid, segment axes hold-last (padded segments have
-    +inf boundaries, so they never fire a switch and their suffix windows
-    are empty).  Returns ``(node (S, R), start (S, R), pops (S,),
-    waited (S,), dead (S,))``; rows of a dead lane are undefined — the
-    caller replays that lane through the per-policy windows engine.
+    ``NEVER`` boundaries, so they never fire a switch and their suffix
+    windows are empty).  Returns ``(node (S, R), start keys (S, R), pops
+    (S,), waited (S,), dead (S,))``; rows of a dead lane are undefined — the
+    caller replays that lane through the per-policy windows engine.  Raises
+    ``OverflowError`` when a live lane's schedule passes the time horizon.
     """
     S = len(lane_rows)
     rmax = max((b.shape[0] for b, _, _, _ in lane_rows), default=1)
     R = _row_bucket(max(rmax, 1))
     kmax = max(b.shape[1] for b, _, _, _ in lane_rows)
     N = max(lane_nodes)
-    bnd = np.full((S, R, kmax), np.inf)
-    val = np.zeros((S, R, kmax))
-    run = np.zeros((S, R))
-    pdur = np.zeros((S, R))
+    bnd = np.full((S, R, kmax), NEVER, dtype=np.int32)
+    val = np.zeros((S, R, kmax), dtype=np.int32)
+    run = np.zeros((S, R), dtype=np.int32)
+    pdur = np.zeros((S, R), dtype=np.int32)
     valid = np.zeros((S, R), dtype=bool)
     nmask = np.zeros((S, N), dtype=bool)
     for s, ((b, v, rr, pr), nn) in enumerate(zip(lane_rows, lane_nodes)):
@@ -1453,31 +1434,35 @@ def sweep_schedule(
         pdur[s, :r] = pr
         valid[s, :r] = True
         nmask[s, :nn] = True
-    budget = np.asarray(lane_budgets, dtype=np.float64)
+    budget = np.asarray(lane_budgets, dtype=np.int32)
     hint_key = (S, R, kmax, N)
     L = max(
         bucket_size(_SWEEP_W * (kmax + 2), floor=timeline_floor),
         min(_hint_get(hint_key), timeline_cap),
     )
-    with _x64_ctx():
-        while True:
-            t0 = time.perf_counter()
-            placed, node, start, _, pops, waited, dead, over, hw = _sweep_program(
-                bnd, val, run, pdur, valid, nmask, budget, L=L
+    while True:
+        t0 = time.perf_counter()
+        placed, node, start, _, pops, waited, dead, over, hw = _sweep_program(
+            bnd, val, run, pdur, valid, nmask, budget, L=L
+        )
+        placed, dead, over = np.asarray(placed), np.asarray(dead), np.asarray(over)
+        if stats is not None:
+            stats["program_calls"] = stats.get("program_calls", 0) + 1
+            stats["program_wall_s"] = stats.get("program_wall_s", 0.0) + (
+                time.perf_counter() - t0
             )
-            placed, dead, over = np.asarray(placed), np.asarray(dead), np.asarray(over)
-            if stats is not None:
-                stats["program_calls"] = stats.get("program_calls", 0) + 1
-                stats["program_wall_s"] = stats.get("program_wall_s", 0.0) + (
-                    time.perf_counter() - t0
-                )
-            if not over.any() or L >= timeline_cap:
-                break
-            L *= 2
+        if not over.any() or L >= timeline_cap:
+            break
+        L *= 2
     _hint_put(hint_key, L)
     dead = dead | over  # still overflowing at the cap: replay on the fallback
+    start = np.asarray(start, dtype=np.int64)
     for s, (b, _, _, _) in enumerate(lane_rows):
-        assert dead[s] or placed[s, : b.shape[0]].all(), f"lane {s}: unplaced rows"
+        if dead[s]:
+            continue
+        if not placed[s, : b.shape[0]].all():
+            raise RuntimeError(f"sweep lane {s} left rows unplaced")
+        check_horizon(start[s, : b.shape[0]] + pdur[s, : b.shape[0]])
     if stats is not None:
         stats["waits_program"] = stats.get("waits_program", 0) + int(
             np.asarray(waited)[~dead].sum()
@@ -1490,7 +1475,7 @@ def sweep_schedule(
         stats["timeline_axis"] = L
     return (
         np.asarray(node, dtype=np.int64),
-        np.asarray(start, dtype=np.float64),
+        start,
         np.asarray(pops, dtype=np.int64),
         np.asarray(waited, dtype=np.int64),
         dead,

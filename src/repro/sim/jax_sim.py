@@ -108,16 +108,11 @@ def _predict_rel(rt_stats, rt_over_rel, seg_stats, seg_under_rel, u, k: int, k_e
 
 
 def _acc_dtype(dt):
-    """Wastage accumulation dtype: float64 whenever an x64 context is live,
-    regardless of the ladder's working dtype.
-
-    Outcome decisions (failure index, retries) stay in the working dtype —
-    they must keep matching the f32 predictions bit-for-bit — but wastage is
-    a *report*, summed over every sample of every attempt: accumulating the
-    f32 ladder's per-sample terms in f32 loses ~3 decimal digits over a
-    cluster corpus against the float64 numpy scorer (``score_attempt_np``
-    casts to float64 first).  Resolved at trace time, so the flag is part of
-    the jit cache key."""
+    """Wastage accumulation dtype: float64 whenever an x64 context is live
+    (the CPU reference ladder), else the working dtype — the device ladder
+    is float32 end to end.  Outcome decisions (failure index, retries) stay
+    in the working dtype either way.  Resolved at trace time, so the flag is
+    part of the jit cache key."""
     return jnp.float64 if jax.config.jax_enable_x64 else dt
 
 
@@ -427,7 +422,7 @@ def _simulate_methods(
     """Shared body of the multi-method engines (see the jitted entry points
     ``simulate_task_methods`` and ``simulate_task_ladders``).  ``dtype`` is
     the working precision: float32 (default), or float64 for the x64 ladder
-    variant (callers must hold an ``enable_x64`` context).
+    variant, a CPU reference (callers must hold a ``jax.enable_x64`` context).
 
     ``error_mode="insample"`` switches the k-Segments family (including KS+)
     to bounded-history insample offsets over the last ``insample_window``
@@ -725,10 +720,10 @@ def simulate_task_ladders(
     executions of the same task type — identical to the sequential
     ``run_cluster`` protocol).
 
-    ``x64=True`` runs the whole scan in float64 (the caller must hold an
-    ``jax.experimental.enable_x64`` context): closes the rare ulp-boundary
-    gap where a float32 prediction flips a capacity comparison against the
-    float64 numpy oracle, at ~1.5x ladder cost.
+    ``x64=True`` runs the whole scan in float64 (the caller must hold a
+    ``jax.enable_x64`` context): a CPU reference for the rare ulp-boundary
+    case where a float32 prediction flips a comparison against the float64
+    numpy oracle, at ~1.5x ladder cost.  The TPU path is float32.
     """
     _, _, bounds, vbuf, fbuf, wbuf, natt = _simulate_methods(
         x,

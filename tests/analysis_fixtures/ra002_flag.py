@@ -3,7 +3,7 @@
 Donated-buffer executables deserialized from the persistent compile
 cache crash jax 0.4.37's XLA:CPU (use-after-free on the donated input).
 Unconditional donation is therefore a latent crash on every CPU CI run
-with REPRO_COMPILE_CACHE set.
+with the persistent compilation cache on.
 """
 
 import jax

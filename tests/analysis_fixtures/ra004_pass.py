@@ -7,13 +7,13 @@ override it through the ladder) — both exempt.
 
 import jax.numpy as jnp
 
-from repro.sim.device_timeline import _x64_ctx
+from jax import enable_x64
 
 
 def ladder(y, *, x64=False):
     dt = jnp.float64 if x64 else jnp.float32
     acc = jnp.zeros((), dt)
-    with _x64_ctx():
+    with enable_x64(x64):
         return acc + y.sum().astype(dt)
 
 

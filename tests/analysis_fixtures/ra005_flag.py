@@ -1,9 +1,8 @@
-"""MUST-FLAG RA005: raw enable_x64 outside device_timeline.py.
+"""MUST-FLAG RA005: the removed ``jax.experimental.enable_x64``.
 
-This was the live finding in serve/admission.py and sim/cluster.py that
-this rule was written from: each module re-imported enable_x64 and
-re-entered the config context even when x64 was already the global
-default, forking the trace-context story across the jit caches.
+Every batched program once imported it through one helper; on the
+installed jax the import fails, and no device program may run in float64
+anyway (the TPU compiler refuses f64 ``nextafter`` and f64 bitcasts).
 """
 
 from jax.experimental import enable_x64

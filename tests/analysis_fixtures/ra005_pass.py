@@ -1,13 +1,10 @@
-"""MUST-PASS RA005: the shared ladder context from device_timeline.
-
-`_x64_ctx()` no-ops when jax_enable_x64 is already on, so warm dispatch
-keeps one trace context (and therefore one jit-cache entry) regardless
-of the global flag.
+"""MUST-PASS RA005: the ``jax.enable_x64`` context, as the CPU reference
+ladder uses it (``batch_engine.compute_cluster_ladders(x64=True)``).
 """
 
-from repro.sim.device_timeline import _x64_ctx
+import jax
 
 
 def dispatch(program, *args):
-    with _x64_ctx():
+    with jax.enable_x64(True):
         return program(*args)

@@ -72,10 +72,13 @@ def test_ra002_reproduces_pr6_bug():
     assert not [f for f in findings if f.rule == "RA002"]
 
 
-def test_ra005_allows_device_timeline_itself():
+def test_ra005_flags_every_module():
+    """No module is exempt: the experimental context is gone everywhere,
+    and the supported ``jax.enable_x64`` passes."""
     src = "from jax.experimental import enable_x64\n"
-    assert check_source(src, "src/repro/sim/device_timeline.py") == []
-    assert [f.rule for f in check_source(src, "src/repro/sim/cluster.py")] == ["RA005"]
+    for path in ("src/repro/sim/device_timeline.py", "src/repro/sim/cluster.py"):
+        assert [f.rule for f in check_source(src, path)] == ["RA005"]
+    assert check_source("import jax\nwith jax.enable_x64(True):\n    pass\n", "src/repro/x.py") == []
 
 
 # ---------------------------------------------------------------------------

@@ -5,19 +5,20 @@ import pytest
 
 from repro.sim import generate_eager
 from repro.sim.cluster import NodeState, run_cluster
-from repro.core.allocation import StepAllocation
+from repro.core.timeline import quantize_steps, time_keys
 
 
 def test_node_fits_profile():
     nd = NodeState(capacity_mib=1000.0)
-    a1 = StepAllocation(np.asarray([10.0, 20.0]), np.asarray([400.0, 800.0]))
-    assert nd.fits(a1, 0.0, 20.0)
-    nd.active.append((20.0, a1, 0.0))
+    k = lambda s: int(time_keys(s))  # noqa: E731  (seconds -> key)
+    a1 = quantize_steps([10.0, 20.0], [400.0, 800.0])
+    assert nd.fits(*a1, 0, k(20.0))
+    nd.active.append((k(20.0), *a1, 0))
     # second task peaking at 300 fits only while the first is in its 400-phase
-    a2 = StepAllocation(np.asarray([5.0]), np.asarray([300.0]))
-    assert nd.fits(a2, 0.0, 5.0)  # 400+300 <= 1000 in [0,5)
-    a3 = StepAllocation(np.asarray([15.0]), np.asarray([300.0]))
-    assert not nd.fits(a3, 0.0, 15.0)  # overlaps the 800-phase: 1100 > 1000
+    a2 = quantize_steps([5.0], [300.0])
+    assert nd.fits(*a2, 0, k(5.0))  # 400+300 <= 1000 in [0,5)
+    a3 = quantize_steps([15.0], [300.0])
+    assert not nd.fits(*a3, 0, k(15.0))  # overlaps the 800-phase: 1100 > 1000
 
 
 @pytest.fixture(scope="module")

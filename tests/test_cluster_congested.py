@@ -126,14 +126,17 @@ def test_schedule_epoch_waits_in_program():
     completion, consuming exactly one pending event."""
     from repro.sim.device_timeline import schedule_epoch
 
-    bnd = np.asarray([[5.0], [5.0]])
-    val = np.asarray([[700.0], [700.0]])  # 2 x 700 > 1000: row 1 must wait
-    run = np.asarray([10.0, 10.0])
+    from repro.core.timeline import quantize_steps, time_keys
+
+    bnd, val = quantize_steps([[5.0], [5.0]], [[700.0], [700.0]])  # 2 x 700 > 1000: row 1 must wait
+    run = time_keys([10.0, 10.0])
+    empty = np.empty(0, np.int64)
     placed, node, start, now_f, pops, waited, dead = schedule_epoch(
-        0.0, bnd, val, run, [(np.empty(0), np.empty(0))], np.asarray([]), 1000.0 + 1e-6, 8
+        0, bnd, val, run, [(empty, empty)], empty, 1000, 8
     )
+    t10 = int(time_keys(10.0))
     assert placed.tolist() == [True, True]
     assert node.tolist() == [0, 0]
-    assert start.tolist() == [0.0, 10.0]  # row 1 waits for row 0's release
-    assert now_f == 10.0
+    assert start.tolist() == [0, t10]  # row 1 waits for row 0's release
+    assert now_f == t10
     assert pops == 1 and waited == 1 and not dead

@@ -19,9 +19,9 @@ Three layers:
   boundary-free because both sides consume identical rows.)
 * **Boundary oracle** — ``NodeState.fits`` / ``reserved_at`` /
   ``demand_exceeds_many`` probed against a naive Eq. (1) evaluator at every
-  event instant and its one-ulp neighbours (mirroring
+  event key and its neighbouring keys (mirroring
   tests/test_demand_oracle.py), including reservations starting *exactly* at
-  another's release time — the case where an off-by-one-ulp disagreement
+  another's release instant — the case where an off-by-one disagreement
   between the profile's release events and the probe sides would show up.
 
 Each property runs as a seeded loop plus a hypothesis variant (skipped
@@ -36,14 +36,17 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.allocation import StepAllocation, demand_exceeds, demand_exceeds_many
+from repro.core.allocation import demand_exceeds, demand_exceeds_many
 from repro.core.ksegments import KSegmentsConfig
+from repro.core.timeline import key_seconds, quantize_steps, time_keys
 from repro.sim.cluster import (
     NodeState,
     _eligible_queue,
     _find_slot,
     _place_rows_batched,
     _policy_rows,
+    oracle_rows,
+    place_rows,
     run_cluster,
     run_cluster_batched,
 )
@@ -58,23 +61,38 @@ NODE_MIB = 128 * 1024.0
 # ---------------------------------------------------------------------------
 
 
+def _k(seconds) -> int:
+    """Seconds -> the key of that instant (timeline units)."""
+    return int(time_keys(seconds))
+
+
 def _scalar_place_rows(bnd_rows, val_rows, run_rows, probe_rows, n_nodes):
-    """Reference placement of flat attempt rows via the oracle's scalar
-    ``_find_slot`` + ``NodeState`` loop: fit-check the full predicted
-    duration (the scheduler cannot know an attempt will die early), occupy
-    the kill-truncated run time — exactly ``run_cluster``'s semantics."""
-    nodes = [NodeState(NODE_MIB) for _ in range(n_nodes)]
+    """Reference placement of flat attempt rows (timeline units) via the
+    oracle's scalar ``_find_slot`` + ``NodeState`` loop (``place_rows``,
+    the placement half of ``run_cluster``)."""
+    node, start, end = place_rows(bnd_rows, val_rows, run_rows, probe_rows, n_nodes, NODE_MIB)
+    return list(zip(node.tolist(), start.tolist(), end.tolist()))
+
+
+def test_oracle_rows_replace_run_cluster_placement():
+    """``run_cluster`` is ``oracle_rows`` placed by ``place_rows``; the
+    hand-rolled ``_find_slot`` loop over those rows gives the same records."""
+    wfs = [generate_workflow("eager", seed=3, scale=0.1)]
+    kw = dict(train_frac=0.5, max_tasks_per_type=10, min_executions=8)
+    queue, (bnd, val, run, probe, counts, _waste) = oracle_rows(wfs, "ksegments-selective", NODE_MIB, **kw)
+    nodes = [NodeState(NODE_MIB) for _ in range(2)]
     events: list = []
-    now = 0.0
-    out = []
-    for r in range(len(run_rows)):
-        alloc = StepAllocation(bnd_rows[r], val_rows[r])
-        placed, now = _find_slot(nodes, events, now, alloc, float(probe_rows[r]))
-        end = now + float(run_rows[r])
-        nodes[placed].add(end, alloc, now)
+    now = 0
+    ref = []
+    for r in range(len(run)):
+        placed, now = _find_slot(nodes, events, now, bnd[r], val[r], int(probe[r]))
+        end = now + int(run[r])
+        nodes[placed].add(end, bnd[r], val[r], now)
         heapq.heappush(events, (end, placed))
-        out.append((placed, now, end))
-    return out
+        ref.append((placed, float(key_seconds(now)), float(key_seconds(end))))
+    res = run_cluster(wfs, "ksegments-selective", n_nodes=2, **kw)
+    assert len(res.records) == len(queue) and sum(counts) == len(ref) >= len(queue) > 0
+    assert [p for rec in res.records for p in rec.placements] == ref
 
 
 @pytest.mark.parametrize(
@@ -101,7 +119,7 @@ def test_engine_parity_given_rows(seed, name, n_nodes, window):
         rn, rs, re = _place_rows_batched(
             bnd_rows, val_rows, run_rows, probe_rows, n_nodes, NODE_MIB, window, None
         )
-        got = [(int(rn[r]), float(rs[r]), float(re[r])) for r in range(len(run_rows))]
+        got = [(int(rn[r]), int(rs[r]), int(re[r])) for r in range(len(run_rows))]
         assert got == ref, policy
 
 
@@ -158,12 +176,11 @@ def test_placement_parity_across_fracs():
 
 
 def test_x64_ladders_exact_parity_on_f32_boundary_seed():
-    """The float64 ladder option on the corpus that historically flipped
-    end-to-end parity (sarek seed 11 at scale 0.06 — a prediction lands
-    within a float32 ulp of a capacity comparison; the probe-window fix in
-    this PR resolved the dominant divergence, and ``ladder_x64`` closes the
-    residual ulp-boundary class).  Exact (node, start, end) parity with the
-    float64 numpy oracle across all bench policies."""
+    """The float64 ladder option (a CPU reference) on the corpus that
+    historically flipped end-to-end parity (sarek seed 11 at scale 0.06 — a
+    prediction lands within a float32 ulp of a comparison; ``ladder_x64``
+    closes that ulp-boundary class).  Exact (node, start, end) parity with
+    the float64 numpy oracle across all bench policies."""
     wfs = [generate_workflow("sarek", seed=11, scale=0.06)]
     kw = dict(n_nodes=3, max_tasks_per_type=10, min_executions=8, train_frac=0.5)
     cfg = KSegmentsConfig(error_mode="progressive")
@@ -196,61 +213,56 @@ def test_property_placement_parity(seed, n_nodes):
 # ---------------------------------------------------------------------------
 
 
-def _oracle_value(alloc: StepAllocation, start: float, t: float) -> float:
-    """Naive Eq. (1): step s+1 applies from the first representable instant
-    after ``start + b_s`` (right-open steps)."""
-    idx = 0
-    for b in alloc.boundaries[:-1]:
-        if t >= np.nextafter(start + b, np.inf):
-            idx += 1
-    return float(alloc.values[idx])
+def _oracle_value(steps, start: int, t: int) -> int:
+    """Naive Eq. (1): step s+1 applies from the key just after the switch
+    instant ``start + b_s`` (right-open steps)."""
+    b, v = steps
+    return int(v[sum(1 for bb in b[:-1] if t >= start + bb + 1)])
 
 
-def _oracle_total(rows, t: float) -> float:
+def _oracle_total(rows, t: int) -> int:
     """Naive reserved total: a reservation holds on [start, end) — its end
     is the release instant, exclusive (unlike a serving plan's Eq. 1 domain,
     which holds through r_e)."""
-    return sum(_oracle_value(a, s, t) for e, a, s in rows if s <= t < e)
+    return sum(_oracle_value((b, v), s, t) for e, b, v, s in rows if s <= t < e)
 
 
-def _rand_alloc(rng, exact_ties: bool) -> StepAllocation:
+def _rand_alloc(rng, exact_ties: bool):
     k = int(rng.integers(1, 5))
     b = np.sort(rng.uniform(0.5, 40.0, k))
     if exact_ties:  # values that can sum exactly to the capacity
         v = np.maximum.accumulate(rng.choice([100.0, 200.0, 250.0, 500.0], k))
     else:
         v = np.maximum.accumulate(rng.uniform(10.0, 500.0, k))
-    return StepAllocation(b, v)
+    return quantize_steps(b, v)
 
 
 def _build_node(rng, exact_ties: bool):
     """A NodeState under add/expire churn; half the reservations start
-    exactly at the previous one's release time."""
+    exactly at the previous one's release instant."""
     nd = NodeState(capacity_mib=1000.0)
     rows = []
     for _ in range(int(rng.integers(2, 8))):
-        a = _rand_alloc(rng, exact_ties)
-        start = rows[-1][0] if rows and rng.random() < 0.5 else float(rng.uniform(0.0, 60.0))
-        end = start + float(rng.uniform(2.0, 50.0))
-        nd.add(end, a, start)
-        rows.append((end, a, start))
+        b, v = _rand_alloc(rng, exact_ties)
+        start = rows[-1][0] if rows and rng.random() < 0.5 else _k(rng.uniform(0.0, 60.0))
+        end = start + _k(rng.uniform(2.0, 50.0))
+        nd.add(end, b, v, start)
+        rows.append((end, b, v, start))
         if rng.random() < 0.3:
-            cut = float(rng.uniform(0.0, 80.0))
+            cut = _k(rng.uniform(0.0, 80.0))
             nd.expire(cut)
             rows = [r for r in rows if r[0] > cut]
     return nd, rows
 
 
 def _probe_grid(rows, rng):
-    """Every event instant, one ulp before, one ulp after, plus random times."""
-    ev = [0.0]
-    for end, a, start in rows:
+    """Every event key, one key before, one key after, plus random keys."""
+    ev = [0]
+    for end, b, _, start in rows:
         ev += [start, end]
-        ev += list(np.nextafter(start + a.boundaries, np.inf))
-    ev = np.asarray(ev)
-    return np.concatenate(
-        [ev, np.nextafter(ev, -np.inf), np.nextafter(ev, np.inf), rng.uniform(0.0, 120.0, 48)]
-    )
+        ev += list(start + b + 1)
+    ev = np.asarray(ev, dtype=np.int64)
+    return np.concatenate([ev, ev - 1, ev + 1, time_keys(rng.uniform(0.0, 120.0, 48))])
 
 
 def _check_node_matches_oracle(seed: int) -> None:
@@ -258,28 +270,26 @@ def _check_node_matches_oracle(seed: int) -> None:
     nd, rows = _build_node(rng, exact_ties=seed % 2 == 0)
     grid = _probe_grid(rows, rng)
     for t in grid:
-        got = nd.reserved_at(float(t))
-        want = _oracle_total(rows, float(t))
-        assert np.isclose(got, want, rtol=1e-9, atol=1e-6), (float(t), got, want)
+        assert nd.reserved_at(int(t)) == _oracle_total(rows, int(t)), int(t)
     for _ in range(6):
         cand = _rand_alloc(rng, seed % 2 == 0)
         # placement windows that start exactly at a release instant are the
         # regression case: the released row must not count at the start probe
-        start = float(rng.choice([r[0] for r in rows])) if rows and rng.random() < 0.6 else float(rng.uniform(0.0, 70.0))
-        dur = float(rng.uniform(1.0, 45.0))
+        start = int(rng.choice([r[0] for r in rows])) if rows and rng.random() < 0.6 else _k(rng.uniform(0.0, 70.0))
+        dur = _k(rng.uniform(1.0, 45.0))
         end = start + dur
-        pts = np.concatenate([[start], np.nextafter(start + cand.boundaries, np.inf), grid])
+        pts = np.concatenate([[start], start + cand[0] + 1, grid])
         pts = pts[(pts >= start) & (pts < end)]
-        peak = max(_oracle_total(rows, float(t)) + _oracle_value(cand, start, float(t)) for t in pts)
-        want = peak <= 1000.0 + 1e-6  # fits' budget expression
-        assert nd.fits(cand, start, dur) == want, (start, dur, peak)
+        peak = max(_oracle_total(rows, int(t)) + _oracle_value(cand, start, int(t)) for t in pts)
+        want = peak <= 1000  # fits' budget: the capacity in whole units
+        assert nd.fits(*cand, start, dur) == want, (start, dur, peak)
         # the vectorized multi-start probe must agree with the scalar one
         times, cum = nd.profile_arrays()
-        starts = np.asarray([start, start + 0.5, np.nextafter(start, np.inf)])
-        many = demand_exceeds_many(times, cum, cand, starts, dur, 1000.0 + 1e-6)
+        starts = np.asarray([start, start + _k(0.5), start + 1])
+        many = demand_exceeds_many(times, cum, *cand, starts, dur, 1000)
         for s, got in zip(starts, many):
-            scalar = demand_exceeds(times, cum, cand, float(s), float(s) + dur, 1000.0 + 1e-6)
-            assert bool(got) == scalar, (float(s), dur)
+            scalar = demand_exceeds(times, cum, *cand, int(s), int(s) + dur, 1000)
+            assert bool(got) == scalar, (int(s), dur)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 7, 19, 101])
@@ -294,26 +304,25 @@ def test_property_node_profile_matches_oracle(seed):
 
 
 def test_reservation_at_anothers_release_boundary_exact():
-    """Pinned semantics at the exact-collision instant: at A's release time
-    B (starting right there) is the only live reservation; one ulp earlier A
-    is the only one; and a candidate window starting at the collision packs
-    against B alone."""
+    """Pinned semantics at the exact-collision instant: at A's release
+    instant B (starting right there) is the only live reservation; one key
+    earlier A is the only one; and a candidate window starting at the
+    collision packs against B alone."""
     nd = NodeState(capacity_mib=1000.0)
-    a = StepAllocation(np.asarray([10.0]), np.asarray([700.0]))
-    b = StepAllocation(np.asarray([10.0]), np.asarray([600.0]))
-    nd.add(10.0, a, 0.0)
-    nd.add(20.0, b, 10.0)
-    assert nd.reserved_at(np.nextafter(10.0, -np.inf)) == 700.0
-    assert nd.reserved_at(10.0) == 600.0  # A released, B live
+    t10, t20 = _k(10.0), _k(20.0)
+    nd.add(t10, *quantize_steps([10.0], [700.0]), 0)
+    nd.add(t20, *quantize_steps([10.0], [600.0]), t10)
+    assert nd.reserved_at(t10 - 1) == 700
+    assert nd.reserved_at(t10) == 600  # A released, B live
     # 400 fits alongside B (600 + 400 <= 1000) but not alongside A + B
-    cand = StepAllocation(np.asarray([5.0]), np.asarray([400.0]))
-    assert nd.fits(cand, 10.0, 5.0)
-    assert not nd.fits(cand, np.nextafter(10.0, -np.inf), 5.0)
+    cand = quantize_steps([5.0], [400.0])
+    assert nd.fits(*cand, t10, _k(5.0))
+    assert not nd.fits(*cand, t10 - 1, _k(5.0))
 
 
 def test_profile_add_many_matches_sequential_adds():
     """One vectorized spliced commit must leave the profile arrays
-    bit-identical to one-at-a-time adds — this is what keeps the batched
+    identical to one-at-a-time adds — this is what keeps the batched
     scheduler's per-epoch commits (``profs[n].add_many`` in
     ``_place_rows_batched``) on the same profile as the oracle's sequential
     ``NodeState.add`` commits."""
@@ -322,12 +331,14 @@ def test_profile_add_many_matches_sequential_adds():
     rng = np.random.default_rng(5)
     one, many = IncrementalDemandProfile(), IncrementalDemandProfile()
     k = 3
-    bnd = np.sort(rng.uniform(0.5, 30.0, (6, k)), axis=1)
-    val = np.maximum.accumulate(rng.uniform(10.0, 400.0, (6, k)), axis=1)
-    ends = rng.uniform(40.0, 80.0, 6)
+    bnd, val = quantize_steps(
+        np.sort(rng.uniform(0.5, 30.0, (6, k)), axis=1),
+        np.maximum.accumulate(rng.uniform(10.0, 400.0, (6, k)), axis=1),
+    )
+    ends = time_keys(rng.uniform(40.0, 80.0, 6))
     for i in range(6):
-        one.add(i, bnd[i], val[i], 7.0, float(ends[i]))
-    many.add_many(range(6), bnd, val, np.full(6, 7.0), ends)
+        one.add(i, bnd[i], val[i], _k(7.0), int(ends[i]))
+    many.add_many(range(6), bnd, val, np.full(6, _k(7.0)), ends)
     t1, c1 = one.arrays()
     t2, c2 = many.arrays()
     np.testing.assert_array_equal(t1, t2)

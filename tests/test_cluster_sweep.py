@@ -194,11 +194,16 @@ def test_sweep_multi_corpus_keys_and_pareto():
 
 
 def _lane(r, seed, k=2):
-    """Synthetic attempt rows in sweep_schedule's lane layout."""
+    """Synthetic attempt rows in sweep_schedule's lane layout (timeline
+    units: boundary keys, value units, run keys)."""
+    from repro.core.timeline import quantize_steps, time_keys
+
     rng = np.random.default_rng(seed)
-    bnd = np.stack([rng.uniform(1.0, 2.0, r), np.full(r, np.inf)], axis=1)
-    val = rng.uniform(50.0, 200.0, (r, k))
-    run = rng.uniform(2.0, 4.0, r)
+    bnd, val = quantize_steps(
+        np.stack([rng.uniform(1.0, 2.0, r), np.full(r, np.inf)], axis=1),
+        rng.uniform(50.0, 200.0, (r, k)),
+    )
+    run = time_keys(rng.uniform(2.0, 4.0, r))
     return bnd, val, run, run
 
 
@@ -238,7 +243,7 @@ def test_sweep_overflow_doubling_and_dead_lane():
     # one node, generous budget: every row starts immediately, so the carry
     # holds ~all future completions at once — deeper than a tiny axis
     lanes = [_lane(60, 0), _lane(6, 1)]
-    nodes, budgets = [1, 1], [50_000.0, 50_000.0]
+    nodes, budgets = [1, 1], [50_000, 50_000]
     saved = dict(dt._SWEEP_L_HINT)
     try:
         dt._SWEEP_L_HINT.clear()

@@ -1,12 +1,12 @@
 """Persistent-compilation-cache smoke test (subprocesses, since the cache
 must be configured before the backend compiles anything).
 
-``REPRO_COMPILE_CACHE`` points jax's persistent cache at a directory
-(``repro.compat.enable_compile_cache``, hooked by ``repro.sim.batch_engine``
-on import); a first process populates it, a second process must get actual
-cache *hits* — asserted via jax's monitoring events, not just file reuse —
-so a warm process deserializes executables instead of recompiling (the
-batched engines' ~20 s CPU cold start)."""
+The entry points turn the cache on with ``repro.compat.enable_compile_cache``
+— never an import.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache
+lands there and nowhere else; a first process populates it and a second
+must get actual cache *hits*, asserted via jax's monitoring events, not
+just file reuse.  Without the variable the cache goes to one fixed,
+git-ignored directory inside the checkout."""
 
 import json
 import os
@@ -18,7 +18,8 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 _CHILD = r"""
 import os, sys, json
 sys.path.insert(0, sys.argv[1])
-import repro.sim.batch_engine  # calls compat.enable_compile_cache() on import
+from repro.compat import enable_compile_cache
+path = enable_compile_cache()
 import jax, jax.numpy as jnp
 from jax._src import monitoring
 
@@ -28,14 +29,20 @@ monitoring.register_event_listener(
 )
 f = jax.jit(lambda x: jnp.cumsum(jnp.sin(x)) * 2.0)
 f(jnp.ones((128,))).block_until_ready()
-print(json.dumps({"hits": len(hits)}))
+print(json.dumps({"hits": len(hits), "path": path, "config": jax.config.jax_compilation_cache_dir}))
 """
 
 
-def _run(cache_dir: str) -> dict:
-    env = dict(os.environ, REPRO_COMPILE_CACHE=cache_dir)
+def _env(**kw) -> dict:
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(kw)
+    return env
+
+
+def _run(code: str, env: dict) -> dict:
     res = subprocess.run(
-        [sys.executable, "-c", _CHILD, SRC], capture_output=True, text=True, timeout=300, env=env
+        [sys.executable, "-c", code, SRC], capture_output=True, text=True, timeout=300, env=env
     )
     assert res.returncode == 0, res.stderr[-2000:]
     return json.loads(res.stdout.strip().splitlines()[-1])
@@ -43,24 +50,35 @@ def _run(cache_dir: str) -> dict:
 
 def test_cache_populates_and_hits(tmp_path):
     cache = str(tmp_path / "xla_cache")
-    first = _run(cache)
+    env = _env(JAX_COMPILATION_CACHE_DIR=cache)
+    first = _run(_CHILD, env)
+    assert first["path"] == first["config"] == cache  # the variable's dir, no other
     entries = [f for f in os.listdir(cache) if f.endswith("-cache")]
     assert entries, "first process must write executables into the cache dir"
     assert first["hits"] == 0  # nothing to hit on a cold cache
-    second = _run(cache)
+    second = _run(_CHILD, env)
     assert second["hits"] >= 1, "second process must hit the persistent cache"
 
 
-def test_cache_disabled_without_env(tmp_path):
-    env = dict(os.environ)
-    env.pop("REPRO_COMPILE_CACHE", None)
+def test_cache_defaults_to_checkout_dir():
     probe = (
-        "import sys; sys.path.insert(0, sys.argv[1]);"
-        "from repro.compat import enable_compile_cache;"
-        "print(enable_compile_cache())"
+        "import sys, json; sys.path.insert(0, sys.argv[1]);"
+        "from repro.compat import CHECKOUT_CACHE_DIR, enable_compile_cache;"
+        "print(json.dumps({'path': enable_compile_cache(), 'fixed': str(CHECKOUT_CACHE_DIR)}))"
     )
-    res = subprocess.run(
-        [sys.executable, "-c", probe, SRC], capture_output=True, text=True, timeout=120, env=env
+    out = _run(probe, _env())
+    repo = os.path.realpath(os.path.join(os.path.dirname(__file__), ".."))
+    assert out["path"] == out["fixed"] == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_import_leaves_the_cache_off():
+    """Importing the engines must not configure the cache: only the entry
+    points decide where compiled programs go."""
+    probe = (
+        "import sys, json; sys.path.insert(0, sys.argv[1]);"
+        "import repro.sim.batch_engine, repro.sim.device_timeline, repro.serve.admission;"
+        "import jax; print(json.dumps({'dir': jax.config.jax_compilation_cache_dir}))"
     )
-    assert res.returncode == 0, res.stderr[-2000:]
-    assert res.stdout.strip().splitlines()[-1] == "None"
+    assert _run(probe, _env())["dir"] is None

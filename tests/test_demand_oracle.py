@@ -1,10 +1,11 @@
 """Oracle suite for step-demand semantics: the packed profile
 (``step_demand_profile``), its incremental twin
-(``IncrementalDemandProfile``), the window probe (``demand_exceeds``) and
-the batched admission program are all checked against a brute-force oracle
-that evaluates Eq. (1) naively — per plan, per probe time, no profiles, no
-cumulative sums.  Boundary-epsilon probes (at, just before, and just after
-every event instant) are always included.
+(``IncrementalDemandProfile``) and the window probe (``demand_exceeds``) are
+checked against a brute-force oracle that evaluates Eq. (1) naively — per
+plan, per probe key, no profiles, no cumulative sums.  Everything is in
+``core.timeline``'s integer units (time keys, demand units); boundary probes
+(at, one key before, and one key after every event key) are always
+included.
 
 Each property runs both ways: as a hypothesis ``@given`` test (random seeds,
 shrinking — skipped cleanly by the conftest shim when hypothesis is absent)
@@ -18,69 +19,58 @@ from hypothesis import strategies as st
 
 from repro.core.allocation import (
     IncrementalDemandProfile,
-    StepAllocation,
     demand_exceeds,
-    pack_step_allocations,
+    pack_steps,
+    quantize_steps,
     step_demand_profile,
 )
+from repro.core.timeline import time_keys
 
 SEEDS = [0, 1, 2, 7, 19, 101]
 
 
-def _random_plan(rng) -> tuple[StepAllocation, float, float]:
-    """(alloc, start, release) with admission-style release just past r_e."""
+def _random_plan(rng) -> tuple[tuple[np.ndarray, np.ndarray], int, int]:
+    """((boundary keys, value units), start key, release key) with the
+    admission-style release just after r_e."""
     k = int(rng.integers(1, 6))
-    bounds = np.sort(rng.uniform(0.5, 50.0, k))
-    values = np.maximum.accumulate(rng.uniform(10.0, 500.0, k))
-    start = float(rng.uniform(0.0, 100.0))
-    release = float(np.nextafter(start + bounds[-1], np.inf))
-    return StepAllocation(bounds, values), start, release
+    steps = quantize_steps(
+        np.sort(rng.uniform(0.5, 50.0, k)), np.maximum.accumulate(rng.uniform(10.0, 500.0, k))
+    )
+    start = int(time_keys(rng.uniform(0.0, 100.0)))
+    return steps, start, start + int(steps[0][-1]) + 1
 
 
-def _oracle_value(alloc: StepAllocation, start: float, t: float) -> float:
-    """Naive Eq. (1): the step to segment s+1 fires at the first representable
-    instant after the switch time ``start + b_s`` (right-open steps)."""
-    idx = 0
-    for b in alloc.boundaries[:-1]:
-        if t >= np.nextafter(start + b, np.inf):
-            idx += 1
-    return float(alloc.values[idx])
+def _oracle_value(steps, start: int, t: int) -> int:
+    """Naive Eq. (1): the step to segment s+1 applies from the key just
+    after the switch instant ``start + b_s`` (right-open steps)."""
+    b, v = steps
+    idx = sum(1 for bb in b[:-1] if t >= start + bb + 1)
+    return int(v[idx])
 
 
-def _oracle_total(plans, t: float) -> float:
+def _oracle_total(plans, t: int) -> int:
     """Naive total demand: sum the live plans' values, one at a time."""
-    tot = 0.0
-    for alloc, start, release in plans:
-        if start <= t < release:
-            tot += _oracle_value(alloc, start, t)
-    return tot
+    return sum(_oracle_value(steps, s, t) for steps, s, rel in plans if s <= t < rel)
 
 
 def _event_times(plans) -> np.ndarray:
     ev = []
-    for alloc, start, release in plans:
+    for (b, _), start, release in plans:
         ev.append(start)
-        ev.extend(np.nextafter(start + alloc.boundaries, np.inf))
+        ev.extend(start + b + 1)
         ev.append(release)
-    return np.asarray(ev)
+    return np.asarray(ev, dtype=np.int64)
 
 
 def _probe_times(plans, rng) -> np.ndarray:
-    """Random times plus every boundary-epsilon case: each event instant,
-    one ulp before, and one ulp after."""
+    """Random keys plus every boundary case: each event key, one key before,
+    and one key after."""
     ev = _event_times(plans)
-    return np.concatenate(
-        [
-            rng.uniform(-5.0, 160.0, 64),
-            ev,
-            np.nextafter(ev, -np.inf),
-            np.nextafter(ev, np.inf),
-        ]
-    )
+    return np.concatenate([time_keys(rng.uniform(0.0, 160.0, 64)), ev, ev - 1, ev + 1])
 
 
 def _profile_arrays(plans):
-    bnd, val = pack_step_allocations([a for a, _, _ in plans])
+    bnd, val = pack_steps([steps for steps, _, _ in plans])
     starts = np.asarray([s for _, s, _ in plans])
     releases = np.asarray([r for _, _, r in plans])
     return step_demand_profile(bnd, val, starts, releases)
@@ -92,8 +82,7 @@ def _check_profile_matches_oracle(seed: int) -> None:
     times, cum = _profile_arrays(plans)
     for t in _probe_times(plans, rng):
         got = cum[np.searchsorted(times, t, side="right")]
-        want = _oracle_total(plans, t)
-        assert np.isclose(got, want, rtol=1e-9, atol=1e-6), (t, got, want)
+        assert got == _oracle_total(plans, int(t)), t
 
 
 def _check_incremental_matches_oracle(seed: int) -> None:
@@ -103,50 +92,43 @@ def _check_incremental_matches_oracle(seed: int) -> None:
     prof = IncrementalDemandProfile()
     livemap = {}
     for i in range(int(rng.integers(4, 12))):
-        alloc, start, release = _random_plan(rng)
-        prof.add(i, alloc.boundaries, alloc.values, start, release)
-        livemap[i] = (alloc, start, release)
+        steps, start, release = _random_plan(rng)
+        prof.add(i, *steps, start, release)
+        livemap[i] = (steps, start, release)
     for i in list(livemap):
         if rng.random() < 0.4:
             prof.remove(i)
             del livemap[i]
     plans = list(livemap.values())
     times, cum = prof.arrays()
-    for t in _probe_times(plans, rng) if plans else np.linspace(0, 100, 16):
+    for t in _probe_times(plans, rng) if plans else time_keys(np.linspace(0, 100, 16)):
         got = cum[np.searchsorted(times, t, side="right")]
-        want = _oracle_total(plans, t)
-        assert np.isclose(got, want, rtol=1e-9, atol=1e-6), (t, got, want)
+        assert got == _oracle_total(plans, int(t)), t
     # expire at a random instant only drops fully-released plans: readings at
-    # t >= now are unchanged
+    # keys >= now are unchanged
     if plans:
-        now = float(rng.uniform(0.0, 200.0))
+        now = int(time_keys(rng.uniform(0.0, 200.0)))
         prof.expire(now)
         times2, cum2 = prof.arrays()
-        for t in np.concatenate([[now], rng.uniform(now, now + 100.0, 16)]):
+        for t in np.concatenate([[now], now + time_keys(rng.uniform(0.0, 100.0, 16))]):
             got = cum2[np.searchsorted(times2, t, side="right")]
-            assert np.isclose(got, _oracle_total(plans, t), rtol=1e-9, atol=1e-6)
+            assert got == _oracle_total(plans, int(t))
 
 
 def _check_demand_exceeds_matches_oracle(seed: int) -> None:
     """The probe's boolean must match the naive window max: the combined
     step function over [start, end] attains its max at some event/boundary
-    instant, so the oracle evaluates all of them plus epsilon neighbours."""
+    key, so the oracle evaluates all of them plus their neighbours."""
     rng = np.random.default_rng(seed)
     plans = [_random_plan(rng) for _ in range(int(rng.integers(1, 7)))]
     times, cum = _profile_arrays(plans)
     cand, start, _ = _random_plan(rng)
-    end = start + float(cand.boundaries[-1])
-    pts = np.concatenate(
-        [
-            [start, end],
-            _probe_times(plans, rng),
-            np.nextafter(start + cand.boundaries, np.inf),
-        ]
-    )
+    end = start + int(cand[0][-1])
+    pts = np.concatenate([[start, end], _probe_times(plans, rng), start + cand[0] + 1])
     pts = pts[(pts >= start) & (pts <= end)]
-    peak = max(_oracle_total(plans, t) + _oracle_value(cand, start, t) for t in pts)
-    for budget, want in [(peak * (1 + 1e-6), False), (peak * (1 - 1e-6), True)]:
-        got = demand_exceeds(times, cum, cand, start, end, budget, inclusive_end=True)
+    peak = max(_oracle_total(plans, int(t)) + _oracle_value(cand, start, int(t)) for t in pts)
+    for budget, want in [(peak, False), (peak - 1, True)]:
+        got = demand_exceeds(times, cum, *cand, start, end, budget, inclusive_end=True)
         assert got == want, (budget, peak, got)
 
 
@@ -166,21 +148,23 @@ def test_demand_exceeds_matches_oracle(seed):
 
 
 def test_profile_boundary_epsilon_exact():
-    """Pinned boundary semantics: AT a switch instant the profile reads the
-    stepped value; one ulp before, the pre-step value; at the release, zero."""
-    alloc = StepAllocation(np.asarray([10.0, 20.0]), np.asarray([100.0, 500.0]))
-    start, release = 5.0, float(np.nextafter(25.0, np.inf))
-    plans = [(alloc, start, release)]
+    """Pinned boundary semantics: AT the switch instant the profile still
+    reads the pre-step value; just after it (the odd key), the stepped
+    value; through r_e inclusive the plan holds; just after r_e, zero."""
+    steps = quantize_steps([10.0, 20.0], [100.0, 500.0])
+    start = int(time_keys(5.0))
+    end = int(time_keys(25.0))
+    plans = [(steps, start, end + 1)]
     times, cum = _profile_arrays(plans)
 
     def read(t):
         return cum[np.searchsorted(times, t, side="right")]
 
-    sw = np.nextafter(15.0, np.inf)  # start + first boundary, right-open
-    assert read(np.nextafter(sw, -np.inf)) == 100.0
-    assert read(sw) == 500.0
-    assert read(25.0) == 500.0  # holds through r_e inclusive
-    assert read(release) == 0.0
+    sw = int(time_keys(15.0))  # start + first boundary: right-open step
+    assert read(sw) == 100
+    assert read(sw + 1) == 500
+    assert read(end) == 500  # holds through r_e inclusive
+    assert read(end + 1) == 0
 
 
 def test_incremental_remove_is_exact_inverse():
@@ -188,10 +172,10 @@ def test_incremental_remove_is_exact_inverse():
     rng = np.random.default_rng(3)
     prof = IncrementalDemandProfile()
     a1, s1, r1 = _random_plan(rng)
-    prof.add("keep", a1.boundaries, a1.values, s1, r1)
+    prof.add("keep", *a1, s1, r1)
     t_before, c_before = (x.copy() for x in prof.arrays())
     a2, s2, r2 = _random_plan(rng)
-    prof.add("gone", a2.boundaries, a2.values, s2, r2)
+    prof.add("gone", *a2, s2, r2)
     prof.remove("gone")
     t_after, c_after = prof.arrays()
     np.testing.assert_array_equal(t_before, t_after)

@@ -62,7 +62,7 @@ def test_wastage_failure_state_machine_across_blocks():
 
 @pytest.mark.parametrize("B,L", [(1, 8), (3, 100), (8, 512), (17, 640), (5, 2048)])
 def test_compact_events_pallas_matches_jnp(B, L):
-    """The sweep's chunk-boundary compaction: the Pallas triangular-gather
+    """The sweep's chunk-boundary compaction: the Pallas roll-and-shift
     kernel vs the jnp rank-scatter twin, bit for bit — kept entries move to
     the front in order, (+inf, 0) identities fill the tail."""
     from repro.kernels import compaction
@@ -77,7 +77,9 @@ def test_compact_events_pallas_matches_jnp(B, L):
         if p:
             t[i, L - p :], d[i, L - p :], keep[i, L - p :] = np.inf, 0.0, False
     out_t, out_d = ops.compact_events(jnp.asarray(t), jnp.asarray(d), jnp.asarray(keep))
-    ref_t, ref_d = compaction.compact_events_jnp(jnp.asarray(t), jnp.asarray(d), jnp.asarray(keep))
+    ref_t, ref_d = compaction.compact_events_jnp(
+        jnp.asarray(t), jnp.asarray(d), jnp.asarray(keep), float("inf")
+    )
     np.testing.assert_array_equal(np.asarray(out_t), np.asarray(ref_t))
     np.testing.assert_array_equal(np.asarray(out_d), np.asarray(ref_d))
     # semantics against a python oracle: stable front-compaction

@@ -18,7 +18,6 @@ sys.path.insert(0, sys.argv[1])
 import dataclasses, json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.compat import use_mesh
 from repro.configs import get_config
 from repro.models.layers import init_moe, moe
 
@@ -31,7 +30,7 @@ for name, shard in [("qwen3-moe-235b-a22b", "ep"), ("grok-1-314b", "tp")]:
     p = init_moe(jax.random.PRNGKey(0), r)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, r.d_model), jnp.float32).astype(jnp.bfloat16)
     ref_out, _ = moe(p, x, r)  # no mesh -> reference path
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda p, x: moe(p, x, r),
                     in_shardings=(None, NamedSharding(mesh, P(("data",), None, None))))
         got_out, got_aux = f(p, x)

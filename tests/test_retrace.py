@@ -19,10 +19,9 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from repro.analysis.trace_audit import no_recompiles  # noqa: E402
-from repro.core.timeline import Timeline  # noqa: E402
+from repro.core.timeline import NEVER, Timeline, quantize_steps, time_keys  # noqa: E402
 from repro.sim.batch_engine import bucket_size, pad_rows  # noqa: E402
 from repro.sim.device_timeline import (  # noqa: E402
-    _x64_ctx,
     admission_epoch,
     admission_program,
     first_fit_window,
@@ -34,14 +33,17 @@ K = 2  # allocation-schedule width used throughout
 
 
 def _candidates(C: int, seed: int):
-    """Synthetic admission candidates in the controller's array layout."""
+    """Synthetic admission candidates in the controller's array layout
+    (time keys, demand units)."""
     rng = np.random.default_rng(seed)
-    starts = np.sort(rng.uniform(0.0, 10.0, C))
-    durs = rng.uniform(1.0, 3.0, C)
+    starts = time_keys(np.sort(rng.uniform(0.0, 10.0, C)))
+    durs = time_keys(rng.uniform(1.0, 3.0, C))
     ends = starts + durs
-    rels = ends + rng.uniform(0.5, 1.0, C)
-    bnd = np.stack([durs * 0.5, np.full(C, np.inf)], axis=1)
-    val = rng.uniform(10.0, 50.0, (C, K))
+    rels = ends + time_keys(rng.uniform(0.5, 1.0, C))
+    bnd, val = quantize_steps(
+        np.stack([rng.uniform(0.5, 1.5, C), np.full(C, np.inf)], axis=1),
+        rng.uniform(10.0, 50.0, (C, K)),
+    )
     return starts, ends, rels, bnd, val
 
 
@@ -49,47 +51,50 @@ def _admission_args(C: int, Cp: int, Pp: int, seed: int):
     """Bucket-padded argument tuple, mirroring _admit_device's packing."""
     starts, ends, rels, bnd, val = _candidates(C, seed)
     valext = np.concatenate([val, val[:, -1:]], axis=1)
-    sw = np.nextafter(starts[:, None] + bnd, np.inf)
-    live = np.isfinite(bnd) & (starts[:, None] + bnd < rels[:, None])
-    P = np.sort(np.unique(np.concatenate([starts, sw[np.isfinite(sw)]])))
+    live = bnd < (rels - starts)[:, None]
+    sw = np.where(live, starts[:, None] + np.where(live, bnd, 0) + 1, NEVER)
+    P = np.unique(np.concatenate([starts, sw[live]]))
     assert len(P) <= Pp and C <= Cp
-    prof_at_p = np.zeros(len(P))
-    P = np.concatenate([P, np.full(Pp - len(P), np.inf)])
-    prof_at_p = np.concatenate([prof_at_p, np.zeros(Pp - len(prof_at_p))])
+    i32 = np.int32
     return (
-        P,
-        prof_at_p,
-        pad_rows(starts, Cp, np.inf),
-        pad_rows(ends, Cp, -np.inf),
-        pad_rows(rels, Cp, -np.inf),
-        pad_rows(bnd, Cp, np.inf),
-        pad_rows(val, Cp, 0.0),
-        pad_rows(valext, Cp, 0.0),
-        pad_rows(sw, Cp, np.inf),
+        np.concatenate([P, np.full(Pp - len(P), NEVER)]).astype(i32),
+        np.zeros(Pp, i32),
+        pad_rows(starts.astype(i32), Cp, NEVER),
+        pad_rows(ends.astype(i32), Cp, -1),
+        pad_rows(rels.astype(i32), Cp, -1),
+        pad_rows(bnd.astype(i32), Cp, NEVER),
+        pad_rows(val.astype(i32), Cp, 0),
+        pad_rows(valext.astype(i32), Cp, 0),
+        pad_rows(sw.astype(i32), Cp, NEVER),
         pad_rows(live, Cp, False),
         pad_rows(np.ones(C, dtype=bool), Cp, False),
     )
 
 
 def _window_rows(w: int, seed: int):
+    """Attempt rows in timeline units: (boundary keys, value units, run keys)."""
     rng = np.random.default_rng(seed)
-    bnd = np.stack([rng.uniform(1.0, 2.0, w), np.full(w, np.inf)], axis=1)
-    val = rng.uniform(50.0, 200.0, (w, K))
-    run = rng.uniform(2.0, 4.0, w)
-    return bnd, val, run
+    bnd, val = quantize_steps(
+        np.stack([rng.uniform(1.0, 2.0, w), np.full(w, np.inf)], axis=1),
+        rng.uniform(50.0, 200.0, (w, K)),
+    )
+    return bnd, val, time_keys(rng.uniform(2.0, 4.0, w))
+
+
+def _k(seconds) -> int:
+    return int(time_keys(seconds))
 
 
 def test_admission_program_warm_zero_retrace():
     Cp, Pp = bucket_size(5), 16
-    budget = 1000.0
-    with _x64_ctx():
-        np.asarray(admission_program()(*_admission_args(5, Cp, Pp, seed=0), budget))
-        # warm: different values AND a different candidate count that pads
-        # into the SAME (Cp, Pp) buckets — zero new traces
-        for C, seed in ((5, 1), (6, 2), (7, 3)):
-            assert bucket_size(C) == Cp
-            with no_recompiles(f"admission C={C}"):
-                np.asarray(admission_program()(*_admission_args(C, Cp, Pp, seed), budget))
+    budget = np.int32(1000)
+    np.asarray(admission_program()(*_admission_args(5, Cp, Pp, seed=0), budget))
+    # warm: different values AND a different candidate count that pads
+    # into the SAME (Cp, Pp) buckets — zero new traces
+    for C, seed in ((5, 1), (6, 2), (7, 3)):
+        assert bucket_size(C) == Cp
+        with no_recompiles(f"admission C={C}"):
+            np.asarray(admission_program()(*_admission_args(C, Cp, Pp, seed), budget))
 
 
 def test_admission_epoch_warm_zero_retrace():
@@ -132,36 +137,36 @@ def test_admission_epoch_warm_zero_retrace():
 def test_first_fit_window_warm_zero_retrace():
     profiles = [Timeline().arrays() for _ in range(2)]
     bnd, val, run = _window_rows(5, seed=0)
-    first_fit_window(0.0, bnd, val, run, run, profiles, 10_000.0)
+    first_fit_window(0, bnd, val, run, run, profiles, 10_000)
     # same window bucket (32) and probe bucket despite w and values changing
     for w, seed in ((5, 1), (7, 2), (9, 3)):
         bnd, val, run = _window_rows(w, seed)
         with no_recompiles(f"first_fit_window w={w}"):
-            first_fit_window(float(seed), bnd, val, run, run, profiles, 10_000.0)
+            first_fit_window(_k(seed), bnd, val, run, run, profiles, 10_000)
 
 
 def test_schedule_epoch_warm_zero_retrace():
     node_events = [Timeline().events() for _ in range(2)]
-    pending = np.asarray([3.5, 7.25])
+    pending = time_keys([3.5, 7.25])
     bnd, val, run = _window_rows(5, seed=0)
-    schedule_epoch(0.0, bnd, val, run, node_events, pending, 10_000.0)
+    schedule_epoch(0, bnd, val, run, node_events, pending, 10_000)
     for w, seed in ((5, 1), (7, 2)):
         bnd, val, run = _window_rows(w, seed)
         with no_recompiles(f"schedule_epoch w={w}"):
-            schedule_epoch(float(seed), bnd, val, run, node_events, pending, 10_000.0)
+            schedule_epoch(_k(seed), bnd, val, run, node_events, pending, 10_000)
 
 
 def test_schedule_epoch_congested_budget_warm_zero_retrace():
     """A tight budget drives the in-program wait path (rows blocked until
     pending completions); warm re-dispatch must still be silent."""
     node_events = [Timeline().events() for _ in range(1)]
-    pending = np.asarray([1.0, 2.0, 3.0])
+    pending = time_keys([1.0, 2.0, 3.0])
     bnd, val, run = _window_rows(6, seed=0)
-    budget = float(np.sort(val.ravel())[len(val) // 2])  # ~half the rows fit
-    schedule_epoch(0.0, bnd, val, run, node_events, pending, budget)
+    budget = int(np.sort(val.ravel())[len(val) // 2])  # ~half the rows fit
+    schedule_epoch(0, bnd, val, run, node_events, pending, budget)
     bnd, val, run = _window_rows(6, seed=1)
     with no_recompiles("schedule_epoch congested"):
-        schedule_epoch(0.0, bnd, val, run, node_events, pending, budget)
+        schedule_epoch(0, bnd, val, run, node_events, pending, budget)
 
 
 def _lanes(rows_per_lane, seed):
@@ -173,7 +178,7 @@ def _lanes(rows_per_lane, seed):
 
 
 def test_sweep_schedule_warm_zero_retrace():
-    nodes, budgets = [2, 3], [500.0, 500.0]
+    nodes, budgets = [2, 3], [500, 500]
     sweep_schedule(_lanes([10, 11], seed=0), nodes, budgets)
     # warm: new values, row counts drift within the same _row_bucket
     for rows, seed in (([10, 11], 10), ([11, 12], 20), ([12, 9], 30)):
@@ -189,7 +194,7 @@ def test_sweep_schedule_congested_budget_warm_zero_retrace():
     nodes = [1, 2]
     # every row fits alone (max value < budget) but most pairs don't: the
     # single-node lane serializes through the wait path
-    budgets = [220.0, 220.0]
+    budgets = [220, 220]
     _, _, _, _, waited, dead = (None, *sweep_schedule(_lanes([40, 44], seed=0), nodes, budgets))
     assert not dead.any()
     assert waited.sum() >= 10

@@ -104,15 +104,29 @@ def test_try_admit_probes_active_switch_points():
 
 
 def test_try_admit_boundary_probe_at_large_timestamps():
-    """The switch-point probe must step past the boundary even when float64
-    resolution near ``now`` is coarser than any fixed epsilon (a long-lived
-    controller's clock): probing ON the boundary reads the pre-step value."""
-    now = 1.0e12  # ulp ~ 1.2e-4: coarser than any epsilon an implementation might add
+    """The switch-point probe must step past the boundary at any clock a
+    long-lived controller reaches: integer keys have the same resolution
+    near the end of the tick horizon as at zero, so probing ON the boundary
+    reads the pre-step value there too."""
+    from repro.core.timeline import HORIZON_TICKS, TICKS_PER_S
+
+    now = HORIZON_TICKS / TICKS_PER_S - 100.0  # ~12.4 days of controller clock
     ctl = AdmissionController(hbm_budget_mib=1000.0, k=2, interval_s=1.0)
     ctl.model = _FixedModel(StepAllocation(np.asarray([10.0, 30.0]), np.asarray([100.0, 900.0])))
     assert ctl.try_admit("leader", 100, now) is not None
     ctl.model = _FixedModel(StepAllocation(np.asarray([5.0, 40.0]), np.asarray([50.0, 200.0])))
     assert ctl.try_admit("newcomer", 100, now) is None
+
+
+def test_try_admit_past_the_horizon_raises():
+    """A plan that would end past the int32 tick horizon raises instead of
+    wrapping its keys."""
+    from repro.core.timeline import HORIZON_TICKS, TICKS_PER_S
+
+    ctl = AdmissionController(hbm_budget_mib=1000.0, k=2, interval_s=1.0)
+    ctl.model = _FixedModel(StepAllocation(np.asarray([10.0, 30.0]), np.asarray([100.0, 900.0])))
+    with pytest.raises(OverflowError):
+        ctl.try_admit("late", 100, HORIZON_TICKS / TICKS_PER_S - 10.0)
 
 
 def test_combined_demand_release_at_final_boundary():
@@ -123,9 +137,9 @@ def test_combined_demand_release_at_final_boundary():
     ctl.model = _FixedModel(plan_alloc)
     assert ctl.try_admit("r0", 100, 0.0) is not None
     at_end = ctl._combined_demand((20.0,))
-    just_past = ctl._combined_demand((20.0 + 1e-6,))
-    assert at_end[0] == 500.0
-    assert just_past[0] == 0.0
+    next_tick = ctl._combined_demand((20.001,))
+    assert at_end[0] == 500
+    assert next_tick[0] == 0
 
 
 def test_reservation_wastage_segmentwise_lower():

@@ -44,8 +44,7 @@ import dataclasses
 r = dataclasses.replace(get_config("llama3.2-3b").reduced(), vocab_size=512)
 from repro.models.model import init_params
 from repro.train.train_step import TrainConfig, init_train_state, make_train_step
-from repro.compat import use_mesh
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), r))
     state = jax.eval_shape(lambda p: init_train_state(p), params)
     p_sh = param_specs(params, r, mesh)

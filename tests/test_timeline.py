@@ -13,21 +13,28 @@ interleaving of fast-path hits, real expiries and batched adds.
 import numpy as np
 import pytest
 
-from repro.core.allocation import StepAllocation
 from repro.core.timeline import (
     IncrementalDemandProfile,
     Timeline,
     demand_exceeds,
     demand_exceeds_many,
+    quantize_steps,
     shared_probe_set,
+    time_keys,
 )
 from repro.sim.cluster import NodeState
 
 
+def _k(seconds) -> int:
+    """Seconds -> the key of that instant (timeline units)."""
+    return int(time_keys(seconds))
+
+
 def _rand_res(rng, k=3):
+    """Random steps in timeline units: (boundary keys, value units)."""
     b = np.sort(rng.uniform(0.5, 30.0, k))
     v = np.maximum.accumulate(rng.uniform(10.0, 400.0, k))
-    return b, v
+    return quantize_steps(b, v)
 
 
 def _rebuilt(tl: Timeline, rows) -> Timeline:
@@ -48,7 +55,7 @@ def test_expire_fast_path_keeps_caches_coherent():
     tl = Timeline()
     rows: dict = {}
     owner = 0
-    clock = 0.0
+    clock = 0
     for step in range(40):
         op = rng.random()
         ver = tl.version
@@ -59,8 +66,8 @@ def test_expire_fast_path_keeps_caches_coherent():
             names = []
             for _ in range(n):
                 b, v = _rand_res(rng)
-                s = clock + float(rng.uniform(0.0, 10.0))
-                e = s + float(rng.uniform(5.0, 40.0))
+                s = clock + _k(rng.uniform(0.0, 10.0))
+                e = s + _k(rng.uniform(5.0, 40.0))
                 rows[owner] = (b, v, s, e)
                 names.append(owner)
                 bs.append(b), vs.append(v), ss.append(s), es.append(e)
@@ -72,13 +79,13 @@ def test_expire_fast_path_keeps_caches_coherent():
             # hit and MUST leave arrays, caches and version untouched
             live = [e for o, (_, _, _, e) in rows.items() if o in tl]
             if live:
-                tl.expire(min(live) - 1.0)
+                tl.expire(min(live) - 1)
                 t, c = tl.arrays()
                 np.testing.assert_array_equal(t, t_before)
                 np.testing.assert_array_equal(c, c_before)
                 assert tl.version == ver
         else:
-            clock += float(rng.uniform(5.0, 25.0))
+            clock += _k(rng.uniform(5.0, 25.0))
             dropped = [o for o, (_, _, _, e) in rows.items() if o in tl and e <= clock]
             tl.expire(clock)
             for o in dropped:
@@ -92,10 +99,10 @@ def test_expire_fast_path_keeps_caches_coherent():
         np.testing.assert_array_equal(np.sort(t), np.sort(tf))
         # probe a grid: the maintained profile must read identically to the
         # rebuilt one at every instant (value-coherence of the cum cache)
-        grid = np.concatenate([tf, [clock, clock + 100.0]]) if len(tf) else np.asarray([clock])
+        grid = np.concatenate([tf, [clock, clock + _k(100.0)]]) if len(tf) else np.asarray([clock])
         got = c[np.searchsorted(t, grid, side="right")]
         want = cf[np.searchsorted(tf, grid, side="right")]
-        np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-9)
+        np.testing.assert_array_equal(got, want)
 
 
 def test_node_state_expire_interleaved_with_add_many():
@@ -104,38 +111,38 @@ def test_node_state_expire_interleaved_with_add_many():
     rng = np.random.default_rng(7)
     nd = NodeState(capacity_mib=5000.0)
     active = []
-    clock = 0.0
+    clock = 0
     for _ in range(25):
         b, v = _rand_res(rng)
-        s = clock + float(rng.uniform(0.0, 5.0))
-        e = s + float(rng.uniform(3.0, 30.0))
-        nd.add(e, StepAllocation(b, v), s)
-        active.append((e, StepAllocation(b, v), s))
+        s = clock + _k(rng.uniform(0.0, 5.0))
+        e = s + _k(rng.uniform(3.0, 30.0))
+        nd.add(e, b, v, s)
+        active.append((e, b, v, s))
         # expire at a time before every active end: fast path territory
-        nd.expire(min(a[0] for a in active) - 0.5)
+        nd.expire(min(a[0] for a in active) - _k(0.5))
         if rng.random() < 0.4:
-            clock += float(rng.uniform(5.0, 20.0))
+            clock += _k(rng.uniform(5.0, 20.0))
             nd.expire(clock)
             active = [a for a in active if a[0] > clock]
         # oracle read: rebuilt node with the same still-active rows
         fresh = NodeState(capacity_mib=5000.0)
-        for e2, a2, s2 in active:
-            fresh.add(e2, a2, s2)
-        for t in [clock, clock + 1.0, clock + 10.0, clock + 50.0]:
-            assert np.isclose(nd.reserved_at(t), fresh.reserved_at(t), rtol=1e-12, atol=1e-9)
+        for e2, b2, v2, s2 in active:
+            fresh.add(e2, b2, v2, s2)
+        for t in [clock, clock + _k(1.0), clock + _k(10.0), clock + _k(50.0)]:
+            assert nd.reserved_at(t) == fresh.reserved_at(t)
 
 
 def test_expired_rows_do_not_change_future_probes():
     """Dropping released reservations must not flip any fit decision at
     probes past the expiry clock."""
     tl = Timeline()
-    tl.add("a", np.asarray([5.0]), np.asarray([400.0]), 0.0, 10.0)
-    tl.add("b", np.asarray([5.0]), np.asarray([300.0]), 0.0, 30.0)
-    cand = StepAllocation(np.asarray([4.0]), np.asarray([500.0]))
-    before = tl.demand_exceeds(cand, 15.0, 25.0, 800.0)
-    tl.expire(12.0)
+    tl.add("a", *quantize_steps([5.0], [400.0]), 0, _k(10.0))
+    tl.add("b", *quantize_steps([5.0], [300.0]), 0, _k(30.0))
+    cand = quantize_steps([4.0], [500.0])
+    before = tl.demand_exceeds(*cand, _k(15.0), _k(25.0), 800)
+    tl.expire(_k(12.0))
     assert "a" not in tl and "b" in tl
-    assert tl.demand_exceeds(cand, 15.0, 25.0, 800.0) == before
+    assert tl.demand_exceeds(*cand, _k(15.0), _k(25.0), 800) == before
 
 
 # ---------------------------------------------------------------------------
@@ -144,17 +151,17 @@ def test_expired_rows_do_not_change_future_probes():
 
 
 def test_shared_probe_set_dedups_and_sorts():
-    a = np.asarray([3.0, 1.0, 2.0])
-    b = np.asarray([[2.0, 5.0], [1.0, 3.0]])  # raveled; overlaps a
+    a = np.asarray([3, 1, 2])
+    b = np.asarray([[2, 5], [1, 3]])  # raveled; overlaps a
     P = shared_probe_set(a, b)
-    np.testing.assert_array_equal(P, [1.0, 2.0, 3.0, 5.0])
+    np.testing.assert_array_equal(P, [1, 2, 3, 5])
 
 
 def test_shared_probe_set_inverse_maps_back():
-    a = np.asarray([4.0, 4.0, 1.0])
-    b = np.asarray([1.0, 9.0])
+    a = np.asarray([4, 4, 1])
+    b = np.asarray([1, 9])
     P, inv = shared_probe_set(a, b, return_inverse=True)
-    np.testing.assert_array_equal(P, [1.0, 4.0, 9.0])
+    np.testing.assert_array_equal(P, [1, 4, 9])
     cat = np.concatenate([a, b])
     np.testing.assert_array_equal(P[inv.ravel()], cat)
 
@@ -166,15 +173,15 @@ def test_probe_dedup_cannot_change_decisions():
     tl = Timeline()
     for i in range(6):
         b, v = _rand_res(rng)
-        s = float(rng.uniform(0.0, 20.0))
-        tl.add(i, b, v, s, s + float(rng.uniform(5.0, 30.0)))
+        s = _k(rng.uniform(0.0, 20.0))
+        tl.add(i, b, v, s, s + _k(rng.uniform(5.0, 30.0)))
     times, cum = tl.arrays()
     # duplicate-heavy probe grid vs its deduped version
-    grid = np.concatenate([times, times, np.repeat(times[:4], 3)]) if len(times) else np.zeros(1)
+    grid = np.concatenate([times, times, np.repeat(times[:4], 3)]) if len(times) else np.zeros(1, int)
     dedup = shared_probe_set(grid)
     got_dup = cum[np.searchsorted(times, grid, side="right")]
     got_ded = cum[np.searchsorted(times, dedup, side="right")]
-    assert set(np.round(got_dup, 9)) == set(np.round(got_ded, 9))
+    assert set(got_dup.tolist()) == set(got_ded.tolist())
 
 
 # ---------------------------------------------------------------------------
@@ -187,19 +194,19 @@ def test_timeline_methods_match_free_functions():
     tl = Timeline()
     for i in range(5):
         b, v = _rand_res(rng)
-        s = float(rng.uniform(0.0, 15.0))
-        tl.add(i, b, v, s, s + float(rng.uniform(5.0, 25.0)))
-    cand = StepAllocation(*_rand_res(rng))
+        s = _k(rng.uniform(0.0, 15.0))
+        tl.add(i, b, v, s, s + _k(rng.uniform(5.0, 25.0)))
+    cand = _rand_res(rng)
     times, cum = tl.arrays()
-    for s in (0.0, 3.0, 17.5):
+    for s in (_k(0.0), _k(3.0), _k(17.5)):
         for inc in (False, True):
-            assert tl.demand_exceeds(cand, s, s + 12.0, 900.0, inclusive_end=inc) == demand_exceeds(
-                times, cum, cand, s, s + 12.0, 900.0, inclusive_end=inc
+            assert tl.demand_exceeds(*cand, s, s + _k(12.0), 900, inclusive_end=inc) == demand_exceeds(
+                times, cum, *cand, s, s + _k(12.0), 900, inclusive_end=inc
             )
-    starts = np.asarray([0.0, 2.0, 9.0, 21.0])
+    starts = time_keys([0.0, 2.0, 9.0, 21.0])
     np.testing.assert_array_equal(
-        tl.demand_exceeds_many(cand, starts, 8.0, 900.0),
-        demand_exceeds_many(times, cum, cand, starts, 8.0, 900.0),
+        tl.demand_exceeds_many(*cand, starts, _k(8.0), 900),
+        demand_exceeds_many(times, cum, *cand, starts, _k(8.0), 900),
     )
 
 
@@ -210,7 +217,23 @@ def test_incremental_demand_profile_alias():
 
 def test_add_many_duplicate_owner_leaves_state_clean():
     tl = Timeline()
-    tl.add("x", np.asarray([2.0]), np.asarray([100.0]), 0.0, 5.0)
+    tl.add("x", np.asarray([4000]), np.asarray([100]), 0, 10000)
     with pytest.raises(ValueError):
-        tl.add_many(["y", "x"], np.full((2, 1), 2.0), np.full((2, 1), 50.0), [0.0, 0.0], [4.0, 4.0])
+        tl.add_many(["y", "x"], np.full((2, 1), 4000), np.full((2, 1), 50), [0, 0], [8000, 8000])
     assert "y" not in tl and tl.n_owners == 1
+
+
+def test_units_round_up_and_forgive_float_noise():
+    """Allocations round up to whole units, except that float noise of a few
+    ulps above a whole unit (a float32 prediction of a float64 integer)
+    stays on that unit; capacities round down; times floor to ticks, +inf
+    boundaries become NEVER and the horizon raises."""
+    from repro.core.timeline import HORIZON_TICKS, NEVER, TICKS_PER_S, budget_units, demand_units
+
+    noisy = float(np.nextafter(np.nextafter(np.float32(100.0), np.float32(np.inf)), np.float32(np.inf)))
+    assert demand_units([100.0, noisy, 100.01, 0.2]).tolist() == [100, 100, 101, 1]
+    assert int(budget_units(100.9)) == 100
+    assert time_keys([0.0, 1.2345, np.inf]).tolist() == [0, 2468, NEVER]
+    for bad in (-1.0, np.nan, HORIZON_TICKS / TICKS_PER_S):
+        with pytest.raises(OverflowError):
+            time_keys(bad)

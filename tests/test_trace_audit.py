@@ -29,7 +29,7 @@ def test_counter_sees_cold_trace_then_silent_warm():
     with CompileCounter() as cold:
         f(x).block_until_ready()
     assert cold.traces >= 1
-    # with REPRO_COMPILE_CACHE set the backend compile may be answered by
+    # with a persistent compilation cache on, the backend compile may be answered by
     # the persistent cache instead — either way the counter must see it
     assert cold.compiles >= 1 or cold.cache_hits >= 1
 
@@ -136,16 +136,14 @@ def test_carry_stability_flags_forbidden_dtype():
 
     xs = jnp.ones(4, jnp.float32)
     assert check_scan_carry_stability(run32, xs, forbid_dtypes=("float32",))
-    from repro.sim.device_timeline import _x64_ctx
-
-    with _x64_ctx():
+    with jax.enable_x64(True):
         xs64 = jnp.ones(4, jnp.float64)
         assert not check_scan_carry_stability(run64, xs64, forbid_dtypes=("float32",))
 
 
 def test_closure_constants_flags_only_giants():
-    big = np.ones((1 << 15,), np.float64)  # 256 KiB
-    small = np.ones((8,), np.float64)
+    big = np.ones((1 << 16,), np.float32)  # 256 KiB
+    small = np.ones((8,), np.float32)
 
     def with_big(x):
         return x + jnp.asarray(big)
@@ -153,14 +151,14 @@ def test_closure_constants_flags_only_giants():
     def with_small(x):
         return x * jnp.asarray(small)
 
-    giants = closure_constants(with_big, jnp.ones(1 << 15), min_bytes=1 << 17)
+    giants = closure_constants(with_big, jnp.ones(1 << 16), min_bytes=1 << 17)
     assert len(giants) == 1 and giants[0].nbytes == big.nbytes
 
     assert closure_constants(with_small, jnp.ones(8), min_bytes=1 << 17) == []
 
 
 def test_closure_constants_recurses_into_scan():
-    table = np.arange(1 << 14, dtype=np.float64)  # 128 KiB, captured in the body
+    table = np.arange(1 << 15, dtype=np.float32)  # 128 KiB, captured in the body
 
     def step(c, x):
         return c + jnp.asarray(table)[0] * x, None
