@@ -111,11 +111,22 @@ def score_attempt_np(series_mib: np.ndarray, interval_s: float, alloc: StepAlloc
     over = y > a
     if over.any():
         fi = int(np.argmax(over))
-        waste = float(np.sum(a[: fi + 1]) * interval_s)
-        return AttemptOutcome(True, fi, waste / MIB_PER_GIB, waste / MIB_PER_GIB)
+        waste = attempt_wastage_np(a, y, fi, interval_s)
+        return AttemptOutcome(True, fi, waste, waste)
     alloc_int = float(np.sum(a) * interval_s)
-    waste = float(np.sum(a - y) * interval_s)
-    return AttemptOutcome(False, -1, waste / MIB_PER_GIB, alloc_int / MIB_PER_GIB)
+    return AttemptOutcome(False, -1, attempt_wastage_np(a, y, -1, interval_s), alloc_int / MIB_PER_GIB)
+
+
+def attempt_wastage_np(a: np.ndarray, y: np.ndarray, failure_index: int, interval_s: float) -> float:
+    """Wastage (GiB*s) of one attempt whose outcome is known, in float64:
+    the allocation ``a`` (sampled at the sample midpoints) up to and
+    including the kill sample ``failure_index``, or ``a - y`` over the whole
+    run when it is -1.  ``score_attempt_np``'s accounting, shared with the
+    device ladder's host-side scoring (``batch_engine.compute_cluster_ladders``)
+    so that equal allocations give bit-equal wastage."""
+    if failure_index >= 0:
+        return float(np.sum(a[: failure_index + 1]) * interval_s) / MIB_PER_GIB
+    return float(np.sum(a - y) * interval_s) / MIB_PER_GIB
 
 
 @dataclasses.dataclass

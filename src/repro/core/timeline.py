@@ -16,8 +16,10 @@ for bit:
   ``2t + 1``.  Eq. (1) steps are right-open, so a step at boundary ``b`` takes
   effect at key ``2(start + b) + 1``; the odd keys are that explicit tie rank.
   Boundaries, durations and offsets are keys too (``2 x ticks``).
-  ``time_keys`` rounds seconds down to a tick (a step up never starts later
-  than the prediction says) and raises past ``HORIZON_TICKS``: keys fit int32
+  ``time_keys`` rounds instants and step boundaries down to a tick (a step
+  up never starts later than the prediction says); ``span_keys`` rounds run
+  lengths and release instants up (a reservation is never released before
+  the time it covers).  Both raise past ``HORIZON_TICKS``: keys fit int32
   with ``NEVER`` (int32 max) reserved for "never" — padding, unfired
   switches, +inf boundaries.  At 1 ms ticks the horizon is 12.4 days.
 * a demand *unit* is ``MIB_PER_UNIT`` MiB.  ``demand_units`` rounds
@@ -58,22 +60,37 @@ NEVER = 2**31 - 1  # the key of an instant that never comes (int32 max)
 HORIZON_TICKS = 2**30 - 1  # every instant is below this tick: 2t + 1 < NEVER
 MAX_UNITS = 2**24  # largest allocation or budget: 2**7 of int32 headroom for sums
 UNIT_SNAP = 2.0**-11  # float noise forgiven when rounding up: half a KiB, in units
+TICK_SNAP = 2.0**-10  # float noise forgiven when rounding to a tick: ~1 us, in ticks
 
 
-def time_keys(seconds) -> np.ndarray:
-    """Seconds -> int64 keys (``2 x floor(seconds x TICKS_PER_S)``).
-
-    Works for instants, durations and step boundaries alike; +inf (a padded
-    segment's boundary) maps to ``NEVER``.  Raises ``OverflowError`` for a
-    negative or NaN time or one at or past the horizon."""
+def _keys(seconds, round_ticks) -> np.ndarray:
     s = np.asarray(seconds, dtype=np.float64)
     fin = np.isfinite(s)
-    ticks = np.floor(np.where(fin, s, 0.0) * TICKS_PER_S)
+    ticks = round_ticks(np.where(fin, s, 0.0) * TICKS_PER_S)
     if np.any(np.isnan(s) | (s == -np.inf) | (ticks < 0) | (ticks >= HORIZON_TICKS)):
         raise OverflowError(
             f"time outside [0, {HORIZON_TICKS / TICKS_PER_S:.0f} s): the int32 tick horizon"
         )
     return np.where(fin, 2 * ticks.astype(np.int64), NEVER)
+
+
+def time_keys(seconds) -> np.ndarray:
+    """Seconds -> int64 keys, rounded down (``2 x floor(seconds x
+    TICKS_PER_S)``, forgiving ``TICK_SNAP`` of float noise, so 1.003 s is
+    tick 1003): instants and step boundaries.
+
+    +inf (a padded segment's boundary) maps to ``NEVER``.  Raises
+    ``OverflowError`` for a negative or NaN time or one at or past the
+    horizon."""
+    return _keys(seconds, lambda ticks: np.floor(ticks + TICK_SNAP))
+
+
+def span_keys(seconds) -> np.ndarray:
+    """Seconds -> int64 keys, rounded up (``2 x ceil(seconds x TICKS_PER_S)``,
+    forgiving ``TICK_SNAP`` of float noise): run lengths and release
+    instants, so a reservation is never released before the time it covers.
+    Same ``NEVER`` mapping and horizon checks as ``time_keys``."""
+    return _keys(seconds, lambda ticks: np.ceil(ticks - TICK_SNAP))
 
 
 def key_seconds(keys) -> np.ndarray:
@@ -114,6 +131,21 @@ def quantize_steps(boundaries, values) -> tuple[np.ndarray, np.ndarray]:
     """A step allocation (seconds, MiB; any leading shape) in timeline units:
     ``(boundary keys, value units)``."""
     return time_keys(boundaries), demand_units(values)
+
+
+def quantize_plan(boundaries, values, now) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """An admission plan started at ``now`` seconds (``boundaries``/``values``
+    of shape (..., k), ``now`` of the leading shape) in timeline units:
+    ``(boundary keys, value units, start keys)``.
+
+    The steps round as ``quantize_steps`` rounds them, except the final
+    boundary: it is the plan's end, where nothing steps, and the plan holds
+    through it inclusive.  Its instant ``now + r_k`` rounds up
+    (``span_keys``), so an admitted plan is never released early."""
+    b, v = quantize_steps(boundaries, values)
+    starts = time_keys(now)
+    b[..., -1] = span_keys(np.asarray(now) + np.asarray(boundaries, dtype=np.float64)[..., -1]) - starts
+    return b, v, starts
 
 
 def step_demand_profile(

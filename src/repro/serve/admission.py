@@ -63,7 +63,7 @@ from repro.core.timeline import (
     demand_exceeds,
     pack_steps,
     plan_profile_events,
-    quantize_steps,
+    quantize_plan,
     shared_probe_set,
     step_demand_profile,
     time_keys,
@@ -138,8 +138,9 @@ class _AdmissionBase:
 
 def _windows(starts: np.ndarray, b: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """Admission windows in keys: a plan from ``starts`` holds through its
-    final boundary inclusive and releases just after it.  Returns (end keys,
-    release keys), checked against the horizon."""
+    final boundary inclusive (rounded up by ``quantize_plan``) and releases
+    just after it.  Returns (end keys, release keys), checked against the
+    horizon."""
     ends = starts + b[..., -1]
     check_horizon(ends)
     return ends, ends + 1
@@ -197,8 +198,8 @@ class AdmissionController(_AdmissionBase):
             alloc = self._default_alloc()
         else:
             alloc = self.model.predict(float(prompt_len))
-        b, v = quantize_steps(alloc.boundaries, alloc.values)
-        start = int(time_keys(now))
+        b, v, start = quantize_plan(alloc.boundaries, alloc.values, now)
+        start = int(start)
         end, _ = _windows(start, b)
         times, cum = self._profile()
         # inclusive end: a plan holds through its final boundary (Eq. 1
@@ -274,9 +275,8 @@ class BatchedAdmissionController(_AdmissionBase):
         if C == 0:
             return []
         bnd_s, val_s = self._predict_many(prompt_lens, C)
-        bnd, val = quantize_steps(bnd_s, val_s)
         now_s = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,))
-        starts = time_keys(now_s)
+        bnd, val, starts = quantize_plan(bnd_s, val_s, now_s)
         ends, rels = _windows(starts, bnd)
         self._prof.expire(int(starts[0]))
         if C < self.device_min_batch:
@@ -620,9 +620,8 @@ class ShardedAdmissionController(_AdmissionBase):
         if C == 0:
             return []
         bnd_s, val_s = self._predict_many(prompt_lens, C)
-        bnd, val = quantize_steps(bnd_s, val_s)
         now_s = np.broadcast_to(np.asarray(now, dtype=np.float64), (C,))
-        starts = time_keys(now_s)
+        bnd, val, starts = quantize_plan(bnd_s, val_s, now_s)
         t0 = int(starts[0])
         if t0 < self._clock:
             raise ValueError(

@@ -16,7 +16,8 @@ a handful of device dispatches:
 
 The same packing serves the cluster scheduler: ``compute_cluster_ladders``
 records every queued execution's full retry ladder (attempt -> allocation,
-failure index, wastage) for all policies in one pass, so
+failure index) for all policies in one pass and scores each attempt's
+wastage on the host in float64, so
 ``repro.sim.cluster.run_cluster_batched``'s host loop only does placement
 (per-task parity with the sequential scheduler in tests/test_cluster_batch.py).
 
@@ -47,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.allocation import AttemptLadder
+from repro.core.allocation import AttemptLadder, attempt_wastage_np
 from repro.core.ksegments import KSegmentsConfig
 
 # The shared probe/packing device programs live in repro.sim.device_timeline
@@ -196,10 +197,14 @@ def simulate_grid(
     methods: tuple[str, ...] = GRID_METHODS,
     train_fracs: tuple[float, ...] = (0.25, 0.5, 0.75),
     cfg: SimConfig | None = None,
+    grid_stats: dict | None = None,
 ) -> list[TaskResult]:
     """Batched twin of ``simulator.simulate_suite``: same grid, same
     ``TaskResult`` rows (ordered workflow -> task -> fraction -> method), but
-    every (method x fraction) cell of a task comes from one scan pass."""
+    every (method x fraction) cell of a task comes from one scan pass.
+
+    ``grid_stats``, when given, receives ``program_calls``: the bucket
+    programs this call dispatched, counted as they are called."""
     cfg = cfg or SimConfig()
     methods = _check_methods(methods)
     kcfg = cfg.ksegments
@@ -211,6 +216,8 @@ def simulate_grid(
     per_task: dict[int, tuple[np.ndarray, np.ndarray]] = {}
     tasks = [t for wf in workflows for t in wf.eligible_tasks(cfg.min_executions)]
 
+    calls: list[int] = []  # appended per dispatch (thread-safe under the GIL)
+
     def _run(batch):
         waste, retries = fn(
             jnp.asarray(batch.x),
@@ -219,10 +226,14 @@ def simulate_grid(
             jnp.asarray(batch.default_mib, jnp.float32),
             jnp.asarray(kcfg.k, jnp.int32),
         )
+        calls.append(1)
         return np.asarray(waste, dtype=np.float64), np.asarray(retries)  # (L, M, B)
 
     batches = pack_traces(tasks)
-    for batch, (waste, retries) in zip(batches, _map_concurrent(_run, batches)):
+    outs = _map_concurrent(_run, batches)
+    if grid_stats is not None:
+        grid_stats["program_calls"] = len(calls)
+    for batch, (waste, retries) in zip(batches, outs):
         for li, trace in enumerate(batch.tasks):
             n = int(batch.n_execs[li])
             per_task[id(trace)] = (waste[li, :, :n], retries[li, :, :n])
@@ -349,14 +360,40 @@ def compute_cluster_ladders(
     for batch, tbl in zip(batches, _map_concurrent(_run, batches)):
         for li, trace in enumerate(batch.tasks):
             n = int(batch.n_execs[li])
+            bnd = tbl["boundaries"][li, :, :n].astype(np.float64)
+            vals = tbl["values"][li, :, :n].astype(np.float64)
+            fi = tbl["failure_index"][li, :, :n]
+            natt = tbl["n_attempts"][li, :, :n]
             out[(trace.workflow, trace.name)] = TaskLadders(
                 methods=methods,
-                boundaries=tbl["boundaries"][li, :, :n].astype(np.float64),
-                values=tbl["values"][li, :, :n].astype(np.float64),
-                failure_index=tbl["failure_index"][li, :, :n],
-                wastage_gib_s=tbl["wastage_gib_s"][li, :, :n].astype(np.float64),
-                n_attempts=tbl["n_attempts"][li, :, :n],
+                boundaries=bnd,
+                values=vals,
+                failure_index=fi,
+                wastage_gib_s=_host_wastage(trace, bnd, vals, fi, natt),
+                n_attempts=natt,
             )
+    return out
+
+
+def _host_wastage(trace: TaskTrace, bnd, vals, fi, natt) -> np.ndarray:
+    """Every recorded attempt's wastage (M, B, A), scored on the host in
+    float64 from the allocation and kill sample the ladder decided —
+    ``score_attempt_np``'s accounting (``attempt_wastage_np``).  The ladder
+    decides in its working dtype; the paper's metric is summed as the
+    sequential oracle sums it, so equal allocations give bit-equal wastage
+    whatever that dtype is."""
+    M, B, A = fi.shape
+    out = np.zeros((M, B, A))
+    for e in range(B):
+        y = np.asarray(trace.executions[e].series, dtype=np.float64)
+        t = (np.arange(len(y)) + 0.5) * trace.interval_s
+        for m in range(M):
+            na = int(natt[m, e])
+            # StepAllocation.at for every attempt at once: they share boundaries
+            seg = np.minimum(np.searchsorted(bnd[m, e], t, side="left"), bnd.shape[-1] - 1)
+            a_all = vals[m, e, :na][:, seg]
+            for a in range(na):
+                out[m, e, a] = attempt_wastage_np(a_all[a], y, int(fi[m, e, a]), trace.interval_s)
     return out
 
 

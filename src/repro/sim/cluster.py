@@ -63,6 +63,7 @@ from repro.core.timeline import (
     check_horizon,
     key_seconds,
     quantize_steps,
+    span_keys,
     time_keys,
 )
 from repro.sim.traces import TaskTrace, WorkflowTrace
@@ -262,7 +263,7 @@ def oracle_rows(
     for trace, i in queue:
         e = trace.executions[i]
         method = methods[(trace.workflow, trace.name)]
-        duration = int(time_keys(len(e.series) * trace.interval_s))
+        duration = int(span_keys(len(e.series) * trace.interval_s))
         alloc = method.predict(e.input_size)
         attempts = 0
         task_waste = 0.0
@@ -274,7 +275,7 @@ def oracle_rows(
             bnds.append(b)
             vals.append(v)
             probes.append(duration)
-            runs.append(int(time_keys((out.failure_index + 1) * trace.interval_s)) if out.failed else duration)
+            runs.append(int(span_keys((out.failure_index + 1) * trace.interval_s)) if out.failed else duration)
             task_waste += out.wastage_gib_s
             if not out.failed:
                 break
@@ -397,8 +398,8 @@ def _policy_rows(ladders, queue, policy: str):
     return (
         bnd,
         val,
-        time_keys(np.concatenate(runs)),
-        time_keys(np.concatenate(probes)),
+        span_keys(np.concatenate(runs)),
+        span_keys(np.concatenate(probes)),
         np.concatenate(counts_all),
         np.concatenate(waste),
     )

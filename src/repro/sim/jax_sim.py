@@ -107,15 +107,6 @@ def _predict_rel(rt_stats, rt_over_rel, seg_stats, seg_under_rel, u, k: int, k_e
     return bounds, jnp.maximum(v, floor_mib)
 
 
-def _acc_dtype(dt):
-    """Wastage accumulation dtype: float64 whenever an x64 context is live
-    (the CPU reference ladder), else the working dtype — the device ladder
-    is float32 end to end.  Outcome decisions (failure index, retries) stay
-    in the working dtype either way.  Resolved at trace time, so the flag is
-    part of the jit cache key."""
-    return jnp.float64 if jax.config.jax_enable_x64 else dt
-
-
 def _attempt(y, length, interval_s, bounds, values):
     """Single-row attempt scorer (same semantics as core.allocation)."""
     T = y.shape[0]
@@ -127,11 +118,8 @@ def _attempt(y, length, interval_s, bounds, values):
     failed = jnp.any(over)
     fail_idx = jnp.where(failed, jnp.argmax(over), T + 1)
     pos = jnp.arange(T)
-    adt = _acc_dtype(y.dtype)
-    a_acc, y_acc = a.astype(adt), y.astype(adt)
-    zero = jnp.asarray(0.0, adt)
-    succ_w = jnp.sum(jnp.where(valid, a_acc - y_acc, zero))
-    fail_w = jnp.sum(jnp.where((pos <= fail_idx) & valid, a_acc, zero))
+    succ_w = jnp.sum(jnp.where(valid, a - y, 0.0))
+    fail_w = jnp.sum(jnp.where((pos <= fail_idx) & valid, a, 0.0))
     waste = jnp.where(failed, fail_w, succ_w) * interval_s / MIB_PER_GIB
     return failed, fail_idx, waste
 
@@ -146,7 +134,7 @@ def _replay_multi(
     per-method retry-mode flags.  Returns (waste (M,), retries (M,)), plus —
     when ``max_attempts`` is set — the recorded per-attempt ladder
     (values (M, A, k), failure index (M, A) with -1 = success,
-    wastage (M, A), n_attempts (M,)): the rows the cluster scheduler replays
+    n_attempts (M,)): the rows the cluster scheduler replays
     placement against.  A row that would exceed A attempts stops with its
     last recorded failure index >= 0; the host consumer detects and raises.
     """
@@ -167,15 +155,14 @@ def _replay_multi(
         active = ~done
         waste = waste + jnp.where(active, w, 0.0)
         if record:
-            vbuf, fbuf, wbuf, natt = rec
+            vbuf, fbuf, natt = rec
             rows = jnp.arange(M)
             att = jnp.minimum(natt, max_attempts - 1)
             fi = jnp.where(failed, fail_idx, -1).astype(jnp.int32)
             vbuf = vbuf.at[rows, att].set(jnp.where(active[:, None], vals, vbuf[rows, att]))
             fbuf = fbuf.at[rows, att].set(jnp.where(active, fi, fbuf[rows, att]))
-            wbuf = wbuf.at[rows, att].set(jnp.where(active, w, wbuf[rows, att]))
             natt = natt + active.astype(jnp.int32)
-            rec = (vbuf, fbuf, wbuf, natt)
+            rec = (vbuf, fbuf, natt)
         t_fail = (fail_idx.astype(bounds.dtype) + 0.5) * interval_s
         seg = jnp.minimum(jnp.sum(t_fail[:, None] > bounds, axis=1), k_eff - 1)  # (M,)
         bump_sel = vals * jnp.where(seg_pos == seg[:, None], factor, 1.0)
@@ -187,16 +174,14 @@ def _replay_multi(
         vals = jnp.where(step_fail[:, None], bumped, vals)
         done = done | (active & ~failed) | (retries > MAX_RETRIES)
         if record:
-            done = done | (rec[3] >= max_attempts)  # ladder buffer full
+            done = done | (rec[2] >= max_attempts)  # ladder buffer full
         return done, retries, waste, vals, rec
 
-    adt = _acc_dtype(values.dtype)  # wastage buffers follow the accumulator
     rec0 = ()
     if record:
         rec0 = (
             jnp.zeros((M, max_attempts, k), values.dtype),
             jnp.full((M, max_attempts), -1, jnp.int32),
-            jnp.zeros((M, max_attempts), adt),
             jnp.zeros((M,), jnp.int32),
         )
     _, retries, waste, _, rec = jax.lax.while_loop(
@@ -205,7 +190,7 @@ def _replay_multi(
         (
             jnp.zeros((M,), bool),
             jnp.zeros((M,), jnp.int32),
-            jnp.zeros((M,), adt),
+            jnp.zeros((M,), values.dtype),
             jnp.minimum(values, cap_mib),
             rec0,
         ),
@@ -523,8 +508,8 @@ def _simulate_methods(
             waste, retries = replayed
             out = (waste, retries)
         else:
-            waste, retries, (vbuf, fbuf, wbuf, natt) = replayed
-            out = (waste, retries, bounds_m, vbuf, fbuf, wbuf, natt)
+            waste, retries, (vbuf, fbuf, natt) = replayed
+            out = (waste, retries, bounds_m, vbuf, fbuf, natt)
 
         # observe
         runtime = li.astype(dtype) * interval_s
@@ -712,7 +697,6 @@ def simulate_task_ladders(
     * ``values`` (M, B, A, k) — allocation values of each attempt (node-capped).
     * ``failure_index`` (M, B, A) — OOM-kill sample of each attempt, -1 on the
       final (successful) attempt.
-    * ``wastage_gib_s`` (M, B, A) — per-attempt wastage.
     * ``n_attempts`` (M, B) — recorded attempts (retries + 1).
 
     The host-side scheduler replays placement against these rows; nothing
@@ -725,7 +709,7 @@ def simulate_task_ladders(
     case where a float32 prediction flips a comparison against the float64
     numpy oracle, at ~1.5x ladder cost.  The TPU path is float32.
     """
-    _, _, bounds, vbuf, fbuf, wbuf, natt = _simulate_methods(
+    _, _, bounds, vbuf, fbuf, natt = _simulate_methods(
         x,
         y,
         lengths,
@@ -746,7 +730,6 @@ def simulate_task_ladders(
         "boundaries": bounds.transpose(1, 0, 2),  # (M, B, k)
         "values": vbuf.transpose(1, 0, 2, 3),  # (M, B, A, k)
         "failure_index": fbuf.transpose(1, 0, 2),  # (M, B, A)
-        "wastage_gib_s": wbuf.transpose(1, 0, 2),  # (M, B, A)
         "n_attempts": natt.T,  # (M, B)
     }
 

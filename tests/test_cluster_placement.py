@@ -50,7 +50,7 @@ from repro.sim.cluster import (
     run_cluster,
     run_cluster_batched,
 )
-from repro.sim.traces import generate_workflow
+from repro.sim.traces import generate_suite, generate_workflow
 
 POLICIES = ("default", "witt-lr", "ppm-improved", "ksegments-selective")
 NODE_MIB = 128 * 1024.0
@@ -144,6 +144,27 @@ def _assert_cluster_parity(wfs, policies, **kw):
             assert rs.placements == rb.placements
             np.testing.assert_allclose(rs.wastage_gib_s, rb.wastage_gib_s, rtol=1e-3, atol=1e-6)
         np.testing.assert_allclose(seq.wastage_gib_s, bat.wastage_gib_s, rtol=1e-3)
+        if policy == "default":
+            # no prediction: equal allocations, scored by the same float64 host
+            # accounting in both engines
+            assert [r.wastage_gib_s for r in seq.records] == [r.wastage_gib_s for r in bat.records]
+            assert seq.wastage_gib_s == bat.wastage_gib_s
+
+
+@pytest.mark.parametrize("seed", [0, 4])
+def test_default_policy_wastage_bit_equal(seed):
+    """The paper's metric under the float32 device ladder: the default
+    policy's wastage equals the float64 sequential oracle's bit for bit, per
+    task and in total, on a corpus spanning both workflows (the ladder
+    decides in float32; every attempt's wastage is scored on the host in
+    float64, as the oracle scores it)."""
+    wfs = generate_suite(seed=seed, scale=0.05)
+    kw = dict(n_nodes=4, max_tasks_per_type=16, min_executions=8, train_frac=0.5)
+    seq = run_cluster(wfs, "default", ksegments_config=KSegmentsConfig(error_mode="progressive"), **kw)
+    bat = run_cluster_batched(wfs, ("default", "ksegments-selective"), **kw)["default"]
+    assert seq.tasks_run == bat.tasks_run > 0
+    assert [r.wastage_gib_s for r in seq.records] == [r.wastage_gib_s for r in bat.records]
+    assert seq.wastage_gib_s == bat.wastage_gib_s
 
 
 @pytest.mark.parametrize(

@@ -12,7 +12,7 @@ program handles by masking nodes past each lane's count.
 
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import assume, given, settings
 from hypothesis import strategies as st
 
 from repro.sim.cluster import (
@@ -137,6 +137,9 @@ def test_property_sweep_parity_over_densities(seed, mtpt):
         n_nodes=2, node_mib=24 * 1024.0, max_tasks_per_type=mtpt,
         min_executions=6, train_frac=0.5,
     )
+    # about one corpus in ten has an execution that outgrows a 24 GiB node:
+    # no engine can schedule it (the oracle raises), so it is no parity case
+    assume(max(float(e.series.max()) for t in wfs[0].tasks for e in t.executions) <= kw["node_mib"])
     st_s: dict = {}
     sweep = run_cluster_batched(
         wfs, POLICIES[:2], placement="sweep", placement_stats=st_s, **kw

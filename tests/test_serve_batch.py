@@ -107,6 +107,30 @@ def test_within_batch_sequencing():
     assert 0 < sum(bat) < 32  # the budget binds inside the batch
 
 
+@pytest.mark.parametrize("engine", ["scalar", "device"])
+def test_plan_holds_until_its_end_between_ticks(engine):
+    """A plan admitted at 0.9 ms with a 1.0005 s reservation ends at
+    1.0014 s, which lies between 1 ms ticks: the plan must still hold
+    capacity against a newcomer at 1.0012 s (released at the tick after its
+    end, never before), and free it a few ticks later."""
+    from repro.core.allocation import StepAllocation
+
+    def fractional(cls):
+        class Ctl(cls):
+            def _default_alloc(self):
+                return StepAllocation(np.asarray([1.0005]), np.asarray([600.0]))
+
+        return Ctl
+
+    if engine == "scalar":
+        ctl = fractional(AdmissionController)(1000.0, k=4, interval_s=1.0)
+    else:
+        ctl = fractional(BatchedAdmissionController)(1000.0, k=4, interval_s=1.0, device_min_batch=1)
+    assert ctl.try_admit("a", 100, 0.0009) is not None
+    assert ctl.try_admit("b", 100, 1.0012) is None
+    assert ctl.try_admit("c", 100, 1.003) is not None
+
+
 def test_try_admit_many_empty():
     bc = BatchedAdmissionController(1000.0)
     assert bc.try_admit_many([], [], 0.0) == []

@@ -237,3 +237,38 @@ def test_units_round_up_and_forgive_float_noise():
     for bad in (-1.0, np.nan, HORIZON_TICKS / TICKS_PER_S):
         with pytest.raises(OverflowError):
             time_keys(bad)
+
+
+def test_span_keys_round_up_and_forgive_float_noise():
+    """Run lengths and release instants round up to ticks (a reservation is
+    never released before the time it covers); instants and boundaries
+    round down.  Both forgive float noise next to a whole tick."""
+    from repro.core.timeline import HORIZON_TICKS, NEVER, TICKS_PER_S, span_keys
+
+    assert 3 * 0.1 * TICKS_PER_S > 300  # float noise above a whole tick
+    assert span_keys([0.0, 1.2345, 3 * 0.1, 1.0, np.inf]).tolist() == [0, 2470, 600, 2000, NEVER]
+    assert 1.003 * TICKS_PER_S < 1003  # float noise below a whole tick
+    assert time_keys([1.2345, 3 * 0.1, 1.003]).tolist() == [2468, 600, 2006]
+    for bad in (-1.0, np.nan, HORIZON_TICKS / TICKS_PER_S):
+        with pytest.raises(OverflowError):
+            span_keys(bad)
+
+
+@pytest.mark.parametrize("now", [0.0, 0.0009, 2.5])
+def test_plan_release_key_never_before_its_end(now):
+    """An admission plan's final boundary is its end: ``quantize_plan``
+    rounds the instant ``now + r_k`` up, so the plan holds through it and
+    releases just after, even where ``now`` and ``r_k`` both lose a
+    fraction of a tick to the floor.  Interior boundaries still floor."""
+    from repro.core.timeline import TICKS_PER_S, quantize_plan
+
+    r = [0.4004, 1.0005]
+    b, v, start = quantize_plan(r, [100.0, 200.0], now)
+    assert int(start) == 2 * int(np.floor(now * TICKS_PER_S))
+    assert b[0] == int(time_keys(r[0]))
+    end = int(start) + int(b[-1])
+    assert end == 2 * int(np.ceil((now + r[-1]) * TICKS_PER_S))
+    assert end >= 2 * (now + r[-1]) * TICKS_PER_S
+    # batched quantization of the same plan agrees row for row
+    bb, _, ss = quantize_plan(np.tile(r, (2, 1)), np.tile([100.0, 200.0], (2, 1)), np.full(2, now))
+    assert (ss + bb[:, -1]).tolist() == [end, end]
