@@ -288,10 +288,11 @@ def run_stream(
             )
             dt = time.perf_counter() - t_w
             wall += dt
-            per = dt / len(batch)
-            latencies.extend([per] * len(batch))
+            # every decision of the batch is known only when the dispatch
+            # returns: each waited the whole dispatch
+            latencies.extend([dt] * len(batch))
             for a in batch:
-                sh_lat[_shard(a.request_id)].append(per)
+                sh_lat[_shard(a.request_id)].append(dt)
         else:
             got = []
             for a in batch:

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.allocation import AttemptLadder, attempt_wastage_np
 from repro.core.ksegments import KSegmentsConfig
 
@@ -347,31 +348,36 @@ def compute_cluster_ladders(
         # context ``jnp.asarray`` would silently keep the float64 trace
         # arrays).
         with jax.enable_x64(x64):
-            tbl = fn(
-                jnp.asarray(batch.x.astype(dt)),
-                jnp.asarray(batch.y.astype(dt)),
-                jnp.asarray(batch.lengths),
-                jnp.asarray(batch.default_mib, dt),
-                jnp.asarray(kcfg.k, jnp.int32),
-            )
-            return {name: np.asarray(v) for name, v in tbl.items()}
+            with obs.span("sched.ladder.dispatch"):
+                tbl = fn(
+                    jnp.asarray(batch.x.astype(dt)),
+                    jnp.asarray(batch.y.astype(dt)),
+                    jnp.asarray(batch.lengths),
+                    jnp.asarray(batch.default_mib, dt),
+                    jnp.asarray(kcfg.k, jnp.int32),
+                )
+            with obs.span("sched.ladder.fetch"):
+                return {name: np.asarray(v) for name, v in tbl.items()}
 
-    batches = pack_traces(tasks)
-    for batch, tbl in zip(batches, _map_concurrent(_run, batches)):
-        for li, trace in enumerate(batch.tasks):
-            n = int(batch.n_execs[li])
-            bnd = tbl["boundaries"][li, :, :n].astype(np.float64)
-            vals = tbl["values"][li, :, :n].astype(np.float64)
-            fi = tbl["failure_index"][li, :, :n]
-            natt = tbl["n_attempts"][li, :, :n]
-            out[(trace.workflow, trace.name)] = TaskLadders(
-                methods=methods,
-                boundaries=bnd,
-                values=vals,
-                failure_index=fi,
-                wastage_gib_s=_host_wastage(trace, bnd, vals, fi, natt),
-                n_attempts=natt,
-            )
+    with obs.span("sched.ladder.pack"):
+        batches = pack_traces(tasks)
+    tables = _map_concurrent(_run, batches)
+    with obs.span("sched.ladder.unpack"):
+        for batch, tbl in zip(batches, tables):
+            for li, trace in enumerate(batch.tasks):
+                n = int(batch.n_execs[li])
+                bnd = tbl["boundaries"][li, :, :n].astype(np.float64)
+                vals = tbl["values"][li, :, :n].astype(np.float64)
+                fi = tbl["failure_index"][li, :, :n]
+                natt = tbl["n_attempts"][li, :, :n]
+                out[(trace.workflow, trace.name)] = TaskLadders(
+                    methods=methods,
+                    boundaries=bnd,
+                    values=vals,
+                    failure_index=fi,
+                    wastage_gib_s=_host_wastage(trace, bnd, vals, fi, natt),
+                    n_attempts=natt,
+                )
     return out
 
 
@@ -384,16 +390,17 @@ def _host_wastage(trace: TaskTrace, bnd, vals, fi, natt) -> np.ndarray:
     whatever that dtype is."""
     M, B, A = fi.shape
     out = np.zeros((M, B, A))
-    for e in range(B):
-        y = np.asarray(trace.executions[e].series, dtype=np.float64)
-        t = (np.arange(len(y)) + 0.5) * trace.interval_s
-        for m in range(M):
-            na = int(natt[m, e])
-            # StepAllocation.at for every attempt at once: they share boundaries
-            seg = np.minimum(np.searchsorted(bnd[m, e], t, side="left"), bnd.shape[-1] - 1)
-            a_all = vals[m, e, :na][:, seg]
-            for a in range(na):
-                out[m, e, a] = attempt_wastage_np(a_all[a], y, int(fi[m, e, a]), trace.interval_s)
+    with obs.span("sched.ladder.host_wastage"):
+        for e in range(B):
+            y = np.asarray(trace.executions[e].series, dtype=np.float64)
+            t = (np.arange(len(y)) + 0.5) * trace.interval_s
+            for m in range(M):
+                na = int(natt[m, e])
+                # StepAllocation.at for every attempt at once: they share boundaries
+                seg = np.minimum(np.searchsorted(bnd[m, e], t, side="left"), bnd.shape[-1] - 1)
+                a_all = vals[m, e, :na][:, seg]
+                for a in range(na):
+                    out[m, e, a] = attempt_wastage_np(a_all[a], y, int(fi[m, e, a]), trace.interval_s)
     return out
 
 

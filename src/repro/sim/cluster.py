@@ -50,10 +50,10 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
 
 import numpy as np
 
+from repro import obs
 from repro.core.allocation import StepAllocation, score_attempt_np
 from repro.core.ksegments import KSegmentsConfig
 from repro.core.predictor import AllocationMethod, make_method
@@ -438,7 +438,8 @@ def batched_rows(
         for t, n_train in traces
     ]
     ladders = compute_cluster_ladders(trunc, tuple(policies), node_mib, kcfg, max_attempts, x64=ladder_x64)
-    return queue, {p: _policy_rows(ladders, queue, p) for p in policies}
+    with obs.span("sched.ladder.rows"):
+        return queue, {p: _policy_rows(ladders, queue, p) for p in policies}
 
 
 def _place_rows_batched(
@@ -492,12 +493,11 @@ def _place_rows_batched(
     r = 0
     congested = False
 
-    def _commit(npl, nidx, starts, t0):
+    def _commit(npl, nidx, starts):
         """Mirror one dispatch's placements into the host timelines/outputs."""
         nonlocal owner, r
         if stats is not None:
             stats["program_calls"] += 1
-            stats["program_wall_s"] += time.perf_counter() - t0
         # the device keys are int32: a fit window past the horizon would
         # have wrapped, so every placement is checked before it is kept
         check_horizon(starts[:npl] + probe_rows[r : r + npl])
@@ -525,24 +525,27 @@ def _place_rows_batched(
         if now > expired_at:
             # the clock only moves when a row waits, so most windows skip
             # the N-node expiry sweep entirely
-            for prof in profs:
-                prof.expire(now)
+            with obs.span("sched.place.expire"):
+                for prof in profs:
+                    prof.expire(now)
             expired_at = now
         w = min(window, R - r)
         if not congested:
-            t0 = time.perf_counter()
+            with obs.span("sched.place.profiles"):
+                profiles = [prof.arrays() for prof in profs]
             placed, nidx = first_fit_window(
                 now,
                 bnd_rows[r : r + w],
                 val_rows[r : r + w],
                 run_rows[r : r + w],
                 probe_rows[r : r + w],
-                [prof.arrays() for prof in profs],
+                profiles,
                 budget,
                 window,
             )
             npl = w if placed.all() else int(np.argmin(placed))
-            pending += _commit(npl, nidx, np.full(npl, now, dtype=np.int64), t0)
+            with obs.span("sched.place.commit"):
+                pending += _commit(npl, nidx, np.full(npl, now, dtype=np.int64))
             if r < R and npl < w:
                 congested = True  # row r must wait: epoch program takes over
             continue
@@ -551,14 +554,16 @@ def _place_rows_batched(
         # place a handful of rows per call and hand back to streaming
         # as soon as a window resolves without waiting
         w = min(w, 8)
-        t0 = time.perf_counter()
+        with obs.span("sched.place.profiles"):
+            node_events = [prof.events() for prof in profs]
+            heap = np.asarray(pending, dtype=np.int64)
         placed, nidx, starts, now, n_pops, n_waited, dead = schedule_epoch(
             now,
             bnd_rows[r : r + w],
             val_rows[r : r + w],
             run_rows[r : r + w],
-            [prof.events() for prof in profs],
-            np.asarray(pending, dtype=np.int64),
+            node_events,
+            heap,
             budget,
             min(window, 8),
             probe_times=probe_rows[r : r + w],
@@ -566,10 +571,12 @@ def _place_rows_batched(
         if stats is not None:
             stats["waits_program"] += n_waited
         npl = w if placed.all() else int(np.argmin(placed))
-        ends = _commit(npl, nidx, starts, t0)
+        with obs.span("sched.place.commit"):
+            ends = _commit(npl, nidx, starts)
         # the program consumed the n_pops earliest completions of the
         # merged heap (pop order among key-ties is unobservable)
-        pending = sorted(pending + ends)[n_pops:]
+        with obs.span("sched.place.pending"):
+            pending = sorted(pending + ends)[n_pops:]
         congested = n_waited > 0  # stream again once a window stops waiting
         if r < R and npl < w and not dead:
             # a full per-node commit buffer aborted the epoch; nothing of
@@ -700,6 +707,7 @@ def _merge_stats(acc: dict, stats: dict) -> None:
             acc[k] = acc.get(k, 0) + v
 
 
+@obs.call("sched.call")
 def run_cluster_batched(
     workflows: list[WorkflowTrace],
     policies: tuple[str, ...],
@@ -731,11 +739,12 @@ def run_cluster_batched(
     per attempt; tests/test_cluster_congested.py stresses the wait path).
 
     ``placement_stats``, when passed, accumulates ``{"program_calls",
-    "program_wall_s", "waits_program", "waits_host", "rows",
-    "lanes_replayed"}`` for the bench (``waits_program`` = rows whose wait
-    was resolved inside the device program; ``waits_host`` = last-resort
-    host clock walks, 0 in practice; ``lanes_replayed`` = sweep lanes that
-    overflowed and were replayed through the windows engine).
+    "waits_program", "waits_host", "rows", "lanes_replayed"}`` for the
+    bench (``waits_program`` = rows whose wait was resolved inside the
+    device program; ``waits_host`` = last-resort host clock walks, 0 in
+    practice; ``lanes_replayed`` = sweep lanes that overflowed and were
+    replayed through the windows engine).  The call's spans and counters
+    (``repro.obs``) are kept as ``obs.last("sched.call")``.
 
     k-Segments policies run with progressive error offsets (the device
     engine's bounded-carry mode) by default; ``error_mode="insample"`` is
@@ -776,7 +785,7 @@ def run_cluster_batched(
         max_attempts,
         ladder_x64,
     )
-    stats ={"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
+    stats = {"program_calls": 0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
     placed: dict[str, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
     if placement == "sweep" or (
         placement == "auto" and _auto_sweep(rows, policies, n_nodes, placement_window)
@@ -815,6 +824,7 @@ def run_cluster_batched(
     return results
 
 
+@obs.call("sched.call")
 def run_cluster_sweep(
     corpora: dict[str, list[WorkflowTrace]] | list[WorkflowTrace],
     policies: tuple[str, ...],
@@ -853,7 +863,7 @@ def run_cluster_sweep(
     if not isinstance(corpora, dict):
         corpora = {"": corpora}
     policies = tuple(policies)
-    stats = {"program_calls": 0, "program_wall_s": 0.0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
+    stats = {"program_calls": 0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
     lane_rows, lane_nodes, lane_keys = [], [], []
     meta: dict[str, tuple[list, dict]] = {}
     for cname, wfs in corpora.items():

@@ -36,12 +36,12 @@ from __future__ import annotations
 
 import collections
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.timeline import NEVER, check_horizon
 from repro.kernels.ops import compact_events
 from repro.sim.traces import bucket_size, fine_bucket
@@ -355,7 +355,7 @@ def admission_program():
     candidates probe.
     """
 
-    def run(P, prof, starts, ends, rels, bnd, val, valext, sw, live, valid, budget):
+    def admission_batch(P, prof, starts, ends, rels, bnd, val, valext, sw, live, valid, budget):
         A, M, D = candidate_probe_parts(
             P, starts, ends, rels, bnd, val, valext, sw, live, inclusive_end=True
         )
@@ -368,7 +368,7 @@ def admission_program():
         _, admits = jax.lax.scan(step, jnp.zeros_like(P), (A, D, M, valid))
         return admits
 
-    return jax.jit(run)
+    return jax.jit(admission_batch)
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +391,7 @@ def _window_program_shared(n_nodes: int):
     functions — the host picks whichever costs less for the call's shapes.
     """
 
-    def run(P, prof, now, ends, rels, bnd, val, valid, cap):
+    def window_shared(P, prof, now, ends, rels, bnd, val, valid, cap):
         # Derive the per-row pieces on device (fewer host arrays per call):
         # all candidates share the epoch clock, switch keys are the same
         # ``now + b + 1`` the host used building P, and a cluster reservation
@@ -422,7 +422,7 @@ def _window_program_shared(n_nodes: int):
         _, (placed, node) = jax.lax.scan(step, init, (A, D, M, valid), unroll=8)
         return placed, node
 
-    return jax.jit(run)
+    return jax.jit(window_shared)
 
 
 @functools.lru_cache(maxsize=None)
@@ -452,7 +452,7 @@ def _window_program_pernode(n_nodes: int):
     same deltas the host ``Timeline`` accumulates).
     """
 
-    def run(P, prof, now, ends, rels, bnd, val, valid, cap):
+    def window_pernode(P, prof, now, ends, rels, bnd, val, valid, cap):
         # all candidates share the epoch clock; every probe is at or after
         # it (the host builds P from the clock, switch instants past it and
         # strictly-future node events), so window membership per row is just
@@ -492,7 +492,7 @@ def _window_program_pernode(n_nodes: int):
         )
         return placed, node
 
-    return jax.jit(run)
+    return jax.jit(window_pernode)
 
 
 def first_fit_window(
@@ -535,6 +535,26 @@ def first_fit_window(
     scalar path uses); the programs only probe, sequence and pick nodes.
     Returns ``(placed, node)``; ``placed`` is a prefix.
     """
+    with obs.span("sched.window.prepare"):
+        variant, program, args = _window_inputs(
+            now, bnd, val, run_times, probe_times, profiles, capacity_budget, window_bucket
+        )
+    with obs.span("sched.window.launch"):
+        out = program(*args)
+    w = len(bnd)
+    with obs.span("sched.window.wait"):
+        placed = np.asarray(out[0])[:w]
+    with obs.span("sched.window.readback"):
+        node = np.asarray(out[1])[:w]
+    # the compiled shape: variant, nodes, probe axis, row bucket, segments
+    obs.distinct("sched.window.shapes", (variant, *args[1].shape, *args[5].shape))
+    return placed, node
+
+
+def _window_inputs(now, bnd, val, run_times, probe_times, profiles, capacity_budget, window_bucket):
+    """``first_fit_window``'s host preparation: the probe set, the nodes'
+    profile reads at it and the padded rows.  Returns ``(variant, program,
+    args)``, the variant ``"shared"`` or ``"pernode"``."""
     from repro.core.timeline import shared_probe_set
 
     w, k = bnd.shape
@@ -578,9 +598,9 @@ def first_fit_window(
         pad_rows(bnd.astype(i32), Wb, NEVER),
         pad_rows(val.astype(i32), Wb, 0),
         pad_rows(np.ones(w, dtype=bool), Wb, False),
+        i32(capacity_budget),
     )
-    placed, node = program(*args, i32(capacity_budget))
-    return np.asarray(placed)[:w], np.asarray(node)[:w]
+    return ("shared" if use_shared else "pernode"), program, args
 
 
 # ---------------------------------------------------------------------------
@@ -778,6 +798,33 @@ def schedule_epoch(
     order among key-ties is unobservable); ``n_waited`` rows waited
     in-program.
     """
+    with obs.span("sched.epoch.prepare"):
+        args = _epoch_inputs(
+            now, bnd, val, run_times, node_events, pending, capacity_budget, window_bucket, probe_times
+        )
+    with obs.span("sched.epoch.launch"):
+        out = _schedule_program(*args)
+    w = len(bnd)
+    # the first read waits for the device, the rest only copy: the spans
+    # time the reads a dispatch always made and add no call of their own
+    with obs.span("sched.epoch.wait"):
+        placed = np.asarray(out[0])[:w]
+    with obs.span("sched.epoch.readback"):
+        _, node, start, now_f, pops, waited, dead = out
+        node = np.asarray(node, dtype=np.int64)[:w]
+        start = np.asarray(start, dtype=np.int64)[:w]
+        now_f, pops, waited, dead = int(now_f), int(pops), int(waited), bool(dead)
+    # the compiled shape: timelines (N, L), heap H, rows (Wb, k)
+    obs.distinct("sched.epoch.shapes", (*args[0].shape, *args[3].shape, *args[6].shape))
+    return placed, node, start, now_f, pops, waited, dead
+
+
+def _epoch_inputs(
+    now, bnd, val, run_times, node_events, pending, capacity_budget, window_bucket, probe_times
+):
+    """``schedule_epoch``'s host preparation: the nodes' timelines folded at
+    the clock and padded, the sorted pending heap and the padded rows, as
+    ``_schedule_program``'s arguments."""
     w, k = bnd.shape
     Wb = int(window_bucket)
     N = len(node_events)
@@ -803,7 +850,7 @@ def schedule_epoch(
     if probe_times is None:
         probe_times = run_times
     i32 = np.int32
-    args = (
+    return (
         tl_t,
         tl_d,
         base0,
@@ -816,16 +863,6 @@ def schedule_epoch(
         pad_rows(np.asarray(probe_times, dtype=i32), Wb, 0),
         pad_rows(np.ones(w, dtype=bool), Wb, False),
         i32(capacity_budget),
-    )
-    placed, node, start, now_f, pops, waited, dead = _schedule_program(*args)
-    return (
-        np.asarray(placed)[:w],
-        np.asarray(node, dtype=np.int64)[:w],
-        np.asarray(start, dtype=np.int64)[:w],
-        int(now_f),
-        int(pops),
-        int(waited),
-        bool(dead),
     )
 
 
@@ -1300,7 +1337,11 @@ def admission_epoch(n_dev: int = 1, Lp: int | None = None):
             in_specs=(sh,) * 13 + (rep, rep),
             out_specs=(sh,) * 8,
         )
-    return jax.jit(run)
+
+    def admission_epoch(*args):  # the program's name in a trace
+        return run(*args)
+
+    return jax.jit(admission_epoch)
 
 
 # Timeline-axis hint per padded grid signature: a grid that needed an
@@ -1399,9 +1440,8 @@ def sweep_schedule(
         overflow and the whole grid re-dispatches with the axis doubled
         (each axis size is its own compiled variant, so the floor is chosen
         generously); a lane still overflowing at the cap is reported dead.
-      stats: optional ``{"program_calls", "program_wall_s",
-        "waits_program"}`` accumulator (the bench's counters), plus the
-        last dispatch's compaction health: ``carried_hw`` (per-lane
+      stats: optional ``{"program_calls", "waits_program"}`` accumulator
+        (the bench's counters), plus the last dispatch's compaction health: ``carried_hw`` (per-lane
         carried-breakpoint high-water marks) and ``timeline_axis`` (the L
         the grid settled on).
 
@@ -1413,44 +1453,47 @@ def sweep_schedule(
     caller replays that lane through the per-policy windows engine.  Raises
     ``OverflowError`` when a live lane's schedule passes the time horizon.
     """
-    S = len(lane_rows)
-    rmax = max((b.shape[0] for b, _, _, _ in lane_rows), default=1)
-    R = _row_bucket(max(rmax, 1))
-    kmax = max(b.shape[1] for b, _, _, _ in lane_rows)
-    N = max(lane_nodes)
-    bnd = np.full((S, R, kmax), NEVER, dtype=np.int32)
-    val = np.zeros((S, R, kmax), dtype=np.int32)
-    run = np.zeros((S, R), dtype=np.int32)
-    pdur = np.zeros((S, R), dtype=np.int32)
-    valid = np.zeros((S, R), dtype=bool)
-    nmask = np.zeros((S, N), dtype=bool)
-    for s, ((b, v, rr, pr), nn) in enumerate(zip(lane_rows, lane_nodes)):
-        r, k = b.shape
-        bnd[s, :r, :k] = b
-        val[s, :r, :k] = v
-        if k < kmax:
-            val[s, :r, k:] = v[:, -1:]
-        run[s, :r] = rr
-        pdur[s, :r] = pr
-        valid[s, :r] = True
-        nmask[s, :nn] = True
-    budget = np.asarray(lane_budgets, dtype=np.int32)
-    hint_key = (S, R, kmax, N)
-    L = max(
-        bucket_size(_SWEEP_W * (kmax + 2), floor=timeline_floor),
-        min(_hint_get(hint_key), timeline_cap),
-    )
-    while True:
-        t0 = time.perf_counter()
-        placed, node, start, _, pops, waited, dead, over, hw = _sweep_program(
-            bnd, val, run, pdur, valid, nmask, budget, L=L
+    with obs.span("sched.sweep.prepare"):
+        S = len(lane_rows)
+        rmax = max((b.shape[0] for b, _, _, _ in lane_rows), default=1)
+        R = _row_bucket(max(rmax, 1))
+        kmax = max(b.shape[1] for b, _, _, _ in lane_rows)
+        N = max(lane_nodes)
+        bnd = np.full((S, R, kmax), NEVER, dtype=np.int32)
+        val = np.zeros((S, R, kmax), dtype=np.int32)
+        run = np.zeros((S, R), dtype=np.int32)
+        pdur = np.zeros((S, R), dtype=np.int32)
+        valid = np.zeros((S, R), dtype=bool)
+        nmask = np.zeros((S, N), dtype=bool)
+        for s, ((b, v, rr, pr), nn) in enumerate(zip(lane_rows, lane_nodes)):
+            r, k = b.shape
+            bnd[s, :r, :k] = b
+            val[s, :r, :k] = v
+            if k < kmax:
+                val[s, :r, k:] = v[:, -1:]
+            run[s, :r] = rr
+            pdur[s, :r] = pr
+            valid[s, :r] = True
+            nmask[s, :nn] = True
+        budget = np.asarray(lane_budgets, dtype=np.int32)
+        hint_key = (S, R, kmax, N)
+        L = max(
+            bucket_size(_SWEEP_W * (kmax + 2), floor=timeline_floor),
+            min(_hint_get(hint_key), timeline_cap),
         )
-        placed, dead, over = np.asarray(placed), np.asarray(dead), np.asarray(over)
+    while True:
+        with obs.span("sched.sweep.launch"):
+            placed, node, start, _, pops, waited, dead, over, hw = _sweep_program(
+                bnd, val, run, pdur, valid, nmask, budget, L=L
+            )
+        # the first read waits for the device; the rest of the outputs are
+        # read once, after the loop
+        with obs.span("sched.sweep.wait"):
+            placed = np.asarray(placed)
+        with obs.span("sched.sweep.readback"):
+            dead, over = np.asarray(dead), np.asarray(over)
         if stats is not None:
             stats["program_calls"] = stats.get("program_calls", 0) + 1
-            stats["program_wall_s"] = stats.get("program_wall_s", 0.0) + (
-                time.perf_counter() - t0
-            )
         if not over.any() or L >= timeline_cap:
             break
         L *= 2

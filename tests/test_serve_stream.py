@@ -211,3 +211,46 @@ def test_diurnal_arrivals_deterministic_and_modulated():
 def test_diurnal_amp_validated():
     with pytest.raises(ValueError):
         generate_arrivals(StreamConfig(arrival="diurnal", diurnal_amp=1.0, n_requests=1))
+
+
+def test_batched_decisions_each_wait_the_whole_dispatch(monkeypatch):
+    """A batch of n decisions is known only when its dispatch returns, so it
+    gives n latencies each equal to the dispatch's wall, per shard too."""
+    import types
+
+    import repro.serve.stream as stream
+
+    wall = 0.004
+    now = [0.0]
+    monkeypatch.setattr(stream, "time", types.SimpleNamespace(perf_counter=lambda: now[0]))
+
+    class Clocked:
+        """Rejects every candidate (so nothing finishes and the whole stream
+        is one batch), taking ``wall`` of the fake clock per dispatch."""
+
+        n_shards = 2
+
+        def shard_of(self, rid):
+            return int(rid[1:]) % 2
+
+        def observe(self, *a):
+            pass
+
+        def try_admit_many(self, ids, plens, ts):
+            now[0] += wall
+            return [None] * len(ids)
+
+        def release(self, rid):
+            pass
+
+        def reservation_wastage(self, plans):
+            return {}
+
+    cfg = StreamConfig(n_requests=12, n_warmup=0, batch_window_s=1e9)
+    res = run_stream(cfg, "batched", controller=Clocked())
+    assert len(res.decisions) == 12 and res.rejected == 12
+    assert res.wall_s == pytest.approx(wall)
+    assert res.p50_latency_s == res.p99_latency_s == pytest.approx(wall)
+    assert res.slo["violations"] == 12  # each of the 12 waited 4 ms against a 2 ms target
+    assert [s["decisions"] for s in res.shards] == [6, 6]
+    assert all(s["p50_latency_s"] == s["p99_latency_s"] == pytest.approx(wall) for s in res.shards)
