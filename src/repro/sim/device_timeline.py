@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -609,11 +610,39 @@ def _window_inputs(now, bnd, val, run_times, probe_times, profiles, capacity_bud
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, valid, budget):
-    """One scheduling epoch on device (shapes fix the compiled variant).
+_EPOCH_FIELDS = ("tl_t", "tl_d", "base0", "ev", "h0", "now0", "bnd", "val", "run", "pdur", "valid", "budget")
 
-    Args (int32 keys and demand units):
+
+@functools.cache
+def _epoch_layout(shape: tuple) -> tuple[tuple, int]:
+    """Where ``_schedule_program``'s inputs lie in its one flat int32 buffer:
+    ``((name, slice, dims), ...)`` in buffer order and the buffer's length,
+    at offsets fixed by ``shape = (N, L, H, Wb, k)``."""
+    N, L, H, Wb, k = shape
+    dims = ((N, L), (N, L), (N,), (H,), (), (), (Wb, k), (Wb, k), (Wb,), (Wb,), (Wb,), ())
+    fields, at = [], 0
+    for name, d in zip(_EPOCH_FIELDS, dims):
+        fields.append((name, slice(at, at + math.prod(d)), d))
+        at += math.prod(d)
+    return tuple(fields), at
+
+
+def _epoch_unpack(buf, shape: tuple) -> dict:
+    """The fields of a packed epoch input by name: views of a numpy ``buf``
+    (writing one writes the buffer), slices of a traced one."""
+    fields, _ = _epoch_layout(shape)
+    return {name: buf[part].reshape(d) for name, part, d in fields}
+
+
+@functools.partial(jax.jit, static_argnames="shape")
+def _schedule_program(buf, shape):
+    """One scheduling epoch on device (``shape`` fixes the compiled variant).
+
+    ``buf`` is the whole input as one flat int32 vector (one host-to-device
+    copy per dispatch), laid out by ``_epoch_layout(shape)`` with
+    ``shape = (N, L, H, Wb, k)``; the static tuple, never ``buf``'s length,
+    selects the compiled variant (two tuples can give one length).  Its
+    fields (int32 keys and demand units):
       tl_t/tl_d: (N, L) per-node event keys (sorted, ``NEVER`` padded) and
         demand deltas (0 padded) — ``Timeline.events()`` seeded.  Only
         events after the epoch clock are carried; ``base0`` (N,) is each
@@ -628,7 +657,7 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
       run: (W,) occupancy durations (a failed attempt holds its node only
         up to the kill); pdur: (W,) fit-check window durations (the
         scheduler probes the full predicted duration — it cannot know an
-        attempt will die early); valid: (W,) real-row mask.
+        attempt will die early); valid: (W,) real-row mask (0/1).
       budget: the fits budget (``NodeState.budget``).
 
     A ``lax.scan`` walks the rows in queue order.  Per row, a bounded
@@ -643,9 +672,13 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
     fit (unreachable for node-capped allocations), the row and everything
     after it return unplaced and the host takes over.
 
-    Returns (placed, node, start) per row plus (final clock, events popped,
-    rows that waited).  ``placed`` is always a prefix of the valid rows.
+    Returns one int32 vector of length ``3 * W + 4`` (one device-to-host
+    copy): ``placed`` (0/1), ``node`` and ``start`` per row, then the final
+    clock, events popped, rows that waited and ``dead`` (0/1).  ``placed``
+    is always a prefix of the valid rows.
     """
+    tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, valid, budget = _epoch_unpack(buf, shape).values()
+    valid = valid != 0
     N, L = tl_t.shape
     W, k = bnd.shape
     CH = 8  # pending completions probed per wait iteration
@@ -757,7 +790,8 @@ def _schedule_program(tl_t, tl_d, base0, ev, h0, now0, bnd, val, run, pdur, vali
     (now_f, _, _, _, pops, waited, _, _, dead_any), (placed, node, start) = jax.lax.scan(
         row_step, init, xs
     )
-    return placed, node, start, now_f, pops, waited, dead_any
+    tail = jnp.stack([now_f, pops, waited, dead_any.astype(jnp.int32)])
+    return jnp.concatenate([placed.astype(jnp.int32), node, start, tail])
 
 
 def schedule_epoch(
@@ -799,23 +833,24 @@ def schedule_epoch(
     in-program.
     """
     with obs.span("sched.epoch.prepare"):
-        args = _epoch_inputs(
+        buf, shape = _epoch_inputs(
             now, bnd, val, run_times, node_events, pending, capacity_budget, window_bucket, probe_times
         )
     with obs.span("sched.epoch.launch"):
-        out = _schedule_program(*args)
-    w = len(bnd)
-    # the first read waits for the device, the rest only copy: the spans
-    # time the reads a dispatch always made and add no call of their own
+        out = _schedule_program(buf, shape)
+    w, Wb = len(bnd), shape[3]
+    # one copy each way: the read waits for the device and brings back every
+    # output; readback only slices the host copy
     with obs.span("sched.epoch.wait"):
-        placed = np.asarray(out[0])[:w]
+        out = np.asarray(out)
     with obs.span("sched.epoch.readback"):
-        _, node, start, now_f, pops, waited, dead = out
-        node = np.asarray(node, dtype=np.int64)[:w]
-        start = np.asarray(start, dtype=np.int64)[:w]
-        now_f, pops, waited, dead = int(now_f), int(pops), int(waited), bool(dead)
+        placed = out[:w] != 0
+        node = out[Wb : Wb + w].astype(np.int64)
+        start = out[2 * Wb : 2 * Wb + w].astype(np.int64)
+        now_f, pops, waited, dead = out[3 * Wb :].tolist()
+        dead = bool(dead)
     # the compiled shape: timelines (N, L), heap H, rows (Wb, k)
-    obs.distinct("sched.epoch.shapes", (*args[0].shape, *args[3].shape, *args[6].shape))
+    obs.distinct("sched.epoch.shapes", shape)
     return placed, node, start, now_f, pops, waited, dead
 
 
@@ -823,8 +858,9 @@ def _epoch_inputs(
     now, bnd, val, run_times, node_events, pending, capacity_budget, window_bucket, probe_times
 ):
     """``schedule_epoch``'s host preparation: the nodes' timelines folded at
-    the clock and padded, the sorted pending heap and the padded rows, as
-    ``_schedule_program``'s arguments."""
+    the clock and padded, the sorted pending heap and the padded rows,
+    written straight into ``_schedule_program``'s one flat int32 buffer.
+    Returns ``(buf, shape)``, ``shape = (N, L, H, Wb, k)``."""
     w, k = bnd.shape
     Wb = int(window_bucket)
     N = len(node_events)
@@ -833,37 +869,32 @@ def _epoch_inputs(
     # the prefix only ever enters as its sum — carrying it as a scalar keeps
     # the padded timeline axis sized by *future* events.
     cuts = [np.searchsorted(t, now, side="right") for t, _ in node_events]
-    base0 = np.asarray([d[:c].sum() for (_, d), c in zip(node_events, cuts)], dtype=np.int32)
     e0 = max((len(t) - c for (t, _), c in zip(node_events, cuts)), default=0)
     # capacity for one node's in-epoch commits (the program's CAP; beyond it
     # the epoch aborts and the host re-dispatches with fresh timelines)
     L = fine_bucket(e0 + max(2, min(Wb, 8)) * (k + 2), floor=64)
-    tl_t = np.full((N, L), NEVER, dtype=np.int32)
-    tl_d = np.zeros((N, L), dtype=np.int32)
-    for n, ((t, d), c) in enumerate(zip(node_events, cuts)):
-        tl_t[n, : len(t) - c] = t[c:]
-        tl_d[n, : len(d) - c] = d[c:]
     h0 = len(pending)
     H = bucket_size(h0 + Wb, floor=32)
-    ev = np.full(H, NEVER, dtype=np.int32)
-    ev[:h0] = np.sort(np.asarray(pending, dtype=np.int64))
-    if probe_times is None:
-        probe_times = run_times
-    i32 = np.int32
-    return (
-        tl_t,
-        tl_d,
-        base0,
-        ev,
-        i32(h0),
-        i32(now),
-        pad_rows(np.asarray(bnd, dtype=i32), Wb, NEVER),
-        pad_rows(np.asarray(val, dtype=i32), Wb, 0),
-        pad_rows(np.asarray(run_times, dtype=i32), Wb, 0),
-        pad_rows(np.asarray(probe_times, dtype=i32), Wb, 0),
-        pad_rows(np.ones(w, dtype=bool), Wb, False),
-        i32(capacity_budget),
-    )
+    shape = (N, L, H, Wb, k)
+    buf = np.zeros(_epoch_layout(shape)[1], dtype=np.int32)
+    f = _epoch_unpack(buf, shape)
+    f["tl_t"].fill(NEVER)
+    for n, ((t, d), c) in enumerate(zip(node_events, cuts)):
+        f["tl_t"][n, : len(t) - c] = t[c:]
+        f["tl_d"][n, : len(d) - c] = d[c:]
+        f["base0"][n] = d[:c].sum()
+    f["ev"].fill(NEVER)
+    f["ev"][:h0] = np.sort(np.asarray(pending, dtype=np.int64))
+    f["h0"][...] = h0
+    f["now0"][...] = now
+    f["bnd"].fill(NEVER)
+    f["bnd"][:w] = bnd
+    f["val"][:w] = val
+    f["run"][:w] = run_times
+    f["pdur"][:w] = run_times if probe_times is None else probe_times
+    f["valid"][:w] = 1
+    f["budget"][...] = capacity_budget
+    return buf, shape
 
 
 # ---------------------------------------------------------------------------

@@ -68,6 +68,9 @@ def chip_compile(one_chip):
         yield compile_
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+    # the traces above hold compiled (not interpreted) kernels: drop them so
+    # a later test tracing the same program and shapes on the CPU retraces
+    jax.clear_caches()
 
 
 I32, F32, BOOL = jnp.int32, jnp.float32, jnp.bool_
@@ -90,14 +93,13 @@ def test_compaction_kernel_compiles(chip_compile, L):
 
 
 def test_schedule_epoch_compiles(chip_compile):
-    """The scheduling-epoch program, with the range-max kernel inside."""
-    from repro.sim.device_timeline import _schedule_program
+    """The scheduling-epoch program, with the range-max kernel inside: one
+    flat int32 operand, laid out by the static shape tuple."""
+    from repro.sim.device_timeline import _epoch_layout, _schedule_program
 
-    L, H, W = 128, 64, 8
+    shape = (N, 128, 64, 8, K)  # (N, L, H, Wb, k)
     hlo = chip_compile(
-        _schedule_program,
-        ((N, L), I32), ((N, L), I32), ((N,), I32), ((H,), I32), ((), I32), ((), I32),
-        ((W, K), I32), ((W, K), I32), ((W,), I32), ((W,), I32), ((W,), BOOL), ((), I32),
+        functools.partial(_schedule_program, shape=shape), ((_epoch_layout(shape)[1],), I32)
     )
     assert "tpu_custom_call" in hlo
 
