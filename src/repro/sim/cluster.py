@@ -11,6 +11,12 @@ Outputs per policy: makespan, wastage (reserved-minus-used GiB*s), retries —
 so the scheduler-level benefit of segment-wise reservations (vs static peak
 reservations) is measurable end to end, not just per task.
 
+The pool is a capacity per node (``node_mib``: one float for ``n_nodes``
+equal nodes, or a sequence of ``n_nodes`` capacities in node-index order,
+which is first-fit's order), made a per-node vector once at each entry point
+(``node_capacities``).  Every fit test, host and device, holds a node to its
+own capacity; the retry ladders cap an allocation at the largest node.
+
 Two engines share the node bookkeeping (``NodeState``, backed by the serving
 path's ``IncrementalDemandProfile``):
 
@@ -96,7 +102,7 @@ class NodeState:
 
     @property
     def budget(self) -> int:
-        """The fits budget: the capacity in whole demand units."""
+        """The node's capacity in whole demand units."""
         return int(budget_units(self.capacity_mib))
 
     def _key(self) -> tuple[int, ...]:
@@ -205,6 +211,18 @@ def _eligible_queue(
     return queue, traces
 
 
+def node_capacities(node_mib, n_nodes: int) -> np.ndarray:
+    """The pool as a capacity per node, (n_nodes,) float64 MiB: one float is
+    ``n_nodes`` equal nodes, a sequence gives each node's capacity in
+    node-index order."""
+    caps = np.asarray(node_mib, dtype=np.float64)
+    if caps.ndim == 0:
+        return np.full(n_nodes, float(caps))
+    if caps.shape != (n_nodes,):
+        raise ValueError(f"node_mib gives {caps.size} capacities for {n_nodes} nodes")
+    return caps
+
+
 def _gc(nodes: list[NodeState], t: float) -> None:
     for nd in nodes:
         nd.expire(t)
@@ -244,17 +262,19 @@ def oracle_rows(
     predictors: per queued execution, predict, score, ``on_failure`` until
     an attempt succeeds, then ``observe``.  A prediction never depends on
     where earlier attempts were placed, so the ladders come first and
-    ``place_rows`` places them.
+    ``place_rows`` places them.  Allocations cap at the largest node of
+    ``node_mib`` (a float or a capacity per node).
 
     Returns ``(queue, rows)`` in ``_policy_rows``' layout, except that the
     boundary-key and value-unit rows are ragged (each row has its method's
     k): (boundary keys, value units, run keys, probe keys, attempts per
     task, wastage per task)."""
     queue, traces = _eligible_queue(workflows, train_frac, max_tasks_per_type, min_executions)
+    cap = float(np.max(node_mib))
     # keyed by (workflow, task name): task names can collide across workflows
     methods: dict[tuple[str, str], AllocationMethod] = {}
     for trace, n_train in traces:
-        m = make_method(policy, trace.default_mib, node_mib, ksegments_config)
+        m = make_method(policy, trace.default_mib, cap, ksegments_config)
         for e in trace.executions[:n_train]:
             m.observe(e.input_size, e.series)
         methods[(trace.workflow, trace.name)] = m
@@ -269,7 +289,7 @@ def oracle_rows(
         task_waste = 0.0
         while True:  # retry loop: each attempt is a fresh placement row
             attempts += 1
-            alloc = StepAllocation(alloc.boundaries, np.minimum(alloc.values, node_mib))
+            alloc = StepAllocation(alloc.boundaries, np.minimum(alloc.values, cap))
             b, v = quantize_steps(alloc.boundaries, alloc.values)
             out = score_attempt_np(e.series, trace.interval_s, alloc)
             bnds.append(b)
@@ -282,7 +302,7 @@ def oracle_rows(
             if attempts > 64:
                 raise RuntimeError("unschedulable task")
             seg = alloc.segment_of((out.failure_index + 0.5) * trace.interval_s)
-            alloc = method.on_failure(alloc, seg, node_mib)
+            alloc = method.on_failure(alloc, seg, cap)
         method.observe(e.input_size, e.series)
         counts.append(attempts)
         waste.append(task_waste)
@@ -296,14 +316,15 @@ def oracle_rows(
     )
 
 
-def place_rows(bnd_rows, val_rows, run_rows, probe_rows, n_nodes: int, node_mib: float):
+def place_rows(bnd_rows, val_rows, run_rows, probe_rows, n_nodes: int, node_mib):
     """The sequential oracle's placement of flat attempt rows (timeline
     units), one ``_find_slot`` per row: fit-check the full predicted
-    duration (the scheduler cannot know an attempt will die early),
-    first-fit across nodes, waiting on the completion heap, then occupy the
-    node for the row's real run.  Returns per-row (node, start key, end
-    key) int64 arrays."""
-    nodes = [NodeState(node_mib) for _ in range(n_nodes)]
+    duration (the scheduler cannot know an attempt will die early) against
+    each node's own capacity (``node_mib``: a float, or a capacity per
+    node), first-fit across nodes, waiting on the completion heap, then
+    occupy the node for the row's real run.  Returns per-row (node, start
+    key, end key) int64 arrays."""
+    nodes = [NodeState(float(c)) for c in node_capacities(node_mib, n_nodes)]
     # event heap of (end key, node_idx) completions to garbage-collect reservations
     events: list[tuple[int, int]] = []
     now = 0
@@ -323,7 +344,7 @@ def run_cluster(
     workflows: list[WorkflowTrace],
     policy: str,
     n_nodes: int = 4,
-    node_mib: float = 128 * 1024.0,
+    node_mib=128 * 1024.0,
     train_frac: float = 0.5,
     max_tasks_per_type: int = 40,
     min_executions: int = 10,
@@ -332,18 +353,20 @@ def run_cluster(
     """Replay workflow executions through an n-node cluster under a policy
     ("ksegments-selective", "ppm-improved", "default", ...).
 
-    Tasks arrive in trace order; each waits until some node fits its
+    ``node_mib`` is one float (``n_nodes`` equal nodes) or a capacity per
+    node.  Tasks arrive in trace order; each waits until some node fits its
     reservation.  Per-method online learning happens as tasks finish.  This
     is the sequential oracle (``oracle_rows`` then ``place_rows``);
     ``run_cluster_batched`` is the device-backed twin (pass
     ``ksegments_config=KSegmentsConfig(error_mode="progressive")`` here to
     compare them cell by cell).
     """
+    caps = node_capacities(node_mib, n_nodes)
     queue, rows = oracle_rows(
-        workflows, policy, node_mib, train_frac, max_tasks_per_type, min_executions, ksegments_config
+        workflows, policy, caps, train_frac, max_tasks_per_type, min_executions, ksegments_config
     )
     bnd, val, run, probe, counts, waste = rows
-    return _policy_result(policy, queue, counts, waste, *place_rows(bnd, val, run, probe, n_nodes, node_mib))
+    return _policy_result(policy, queue, counts, waste, *place_rows(bnd, val, run, probe, n_nodes, caps))
 
 
 def _policy_rows(ladders, queue, policy: str):
@@ -408,7 +431,7 @@ def _policy_rows(ladders, queue, policy: str):
 def batched_rows(
     workflows: list[WorkflowTrace],
     policies: tuple[str, ...],
-    node_mib: float = 128 * 1024.0,
+    node_mib=128 * 1024.0,
     train_frac: float = 0.5,
     max_tasks_per_type: int = 40,
     min_executions: int = 10,
@@ -418,8 +441,9 @@ def batched_rows(
 ):
     """The device engine's attempt rows: every policy's retry ladders from
     one batched ladder pass (``compute_cluster_ladders``), flattened by
-    ``_policy_rows``.  Returns ``(queue, {policy: rows})`` — the rows both
-    placement engines consume, and ``oracle_rows``' twin."""
+    ``_policy_rows``, allocations capped at the largest node of ``node_mib``
+    (a float or a capacity per node).  Returns ``(queue, {policy: rows})`` —
+    the rows both placement engines consume, and ``oracle_rows``' twin."""
     from repro.sim.batch_engine import compute_cluster_ladders  # deferred: keeps the oracle jax-free
 
     kcfg = ksegments_config or KSegmentsConfig(error_mode="progressive")
@@ -437,7 +461,8 @@ def batched_rows(
         dataclasses.replace(t, executions=t.executions[: n_train + max_tasks_per_type])
         for t, n_train in traces
     ]
-    ladders = compute_cluster_ladders(trunc, tuple(policies), node_mib, kcfg, max_attempts, x64=ladder_x64)
+    cap = float(np.max(node_mib))
+    ladders = compute_cluster_ladders(trunc, tuple(policies), cap, kcfg, max_attempts, x64=ladder_x64)
     with obs.span("sched.ladder.rows"):
         return queue, {p: _policy_rows(ladders, queue, p) for p in policies}
 
@@ -448,13 +473,15 @@ def _place_rows_batched(
     run_rows: np.ndarray,
     probe_rows: np.ndarray,
     n_nodes: int,
-    node_mib: float,
+    node_mib,
     window: int,
     stats: dict | None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Place all of one policy's attempt rows (``_policy_rows`` units) with
-    the device timeline programs.  Returns per-row (node, start key, end key)
-    arrays with the sequential oracle's exact placement semantics.
+    the device timeline programs, each node held to its own capacity
+    (``node_mib``: a float, or a capacity per node).  Returns per-row (node,
+    start key, end key) arrays with the sequential oracle's exact placement
+    semantics.
 
     Two device regimes, zero host-resolved waits:
 
@@ -477,14 +504,22 @@ def _place_rows_batched(
     same profiles the oracle probes.  The only remaining host placement is
     the oracle's last-resort one-second clock walk when the completion heap
     drains with a row still unplaced — unreachable for node-capped
-    allocations, counted in ``waits_host``."""
+    allocations, counted in ``waits_host``.
+
+    With ``stats`` it also counts the rows that are *wide*, whose peak
+    demand exceeds the smallest node's capacity: ``waits_wide``, wide rows
+    that waited in the epoch program (a placed row waited when its start is
+    later than the clock before it in that dispatch), and ``wide_blocks``,
+    entries into the congested path whose first blocked row is wide.  Both
+    are 0 on a uniform pool, where the ladders cap every row at the node."""
     # deferred import keeps the oracle path (run_cluster) jax-free
     from repro.sim.device_timeline import first_fit_window, schedule_epoch
 
     R = len(run_rows)
     profs = [Timeline() for _ in range(n_nodes)]
     pending: list[int] = []  # completion keys not yet consumed by a wait
-    budget = int(budget_units(node_mib))  # NodeState.fits budget
+    budget = budget_units(node_capacities(node_mib, n_nodes))  # each NodeState.budget
+    wide = val_rows.max(axis=1, initial=0) > budget.min()
     row_node = np.empty(R, dtype=np.int64)
     row_start = np.empty(R, dtype=np.int64)
     row_end = np.empty(R, dtype=np.int64)
@@ -548,6 +583,8 @@ def _place_rows_batched(
                 pending += _commit(npl, nidx, np.full(npl, now, dtype=np.int64))
             if r < R and npl < w:
                 congested = True  # row r must wait: epoch program takes over
+                if stats is not None:
+                    stats["wide_blocks"] += int(wide[r])
             continue
         # small wait windows: every row-step of the epoch program pays
         # for its carried clock/heap machinery, so congested dispatches
@@ -557,6 +594,7 @@ def _place_rows_batched(
         with obs.span("sched.place.profiles"):
             node_events = [prof.events() for prof in profs]
             heap = np.asarray(pending, dtype=np.int64)
+        clock = now
         placed, nidx, starts, now, n_pops, n_waited, dead = schedule_epoch(
             now,
             bnd_rows[r : r + w],
@@ -568,9 +606,11 @@ def _place_rows_batched(
             min(window, 8),
             probe_times=probe_rows[r : r + w],
         )
+        npl = w if placed.all() else int(np.argmin(placed))
         if stats is not None:
             stats["waits_program"] += n_waited
-        npl = w if placed.all() else int(np.argmin(placed))
+            before = np.concatenate([[clock], starts[: npl - 1]])
+            stats["waits_wide"] += int(np.sum(wide[r : r + npl] & (starts[:npl] > before)))
         with obs.span("sched.place.commit"):
             ends = _commit(npl, nidx, starts)
         # the program consumed the n_pops earliest completions of the
@@ -597,7 +637,7 @@ def _place_rows_batched(
                 for prof in profs:
                     prof.expire(now)
                 for i, prof in enumerate(profs):
-                    if not prof.demand_exceeds(b, v, now, now + pdur, budget):
+                    if not prof.demand_exceeds(b, v, now, now + pdur, int(budget[i])):
                         ni = i
                         break
             check_horizon(now + pdur)
@@ -695,6 +735,10 @@ def _auto_sweep(rows: dict, policies: tuple, n_nodes: int, window: int) -> bool:
     return est_sweep <= est_windows
 
 
+# the counters every call's ``placement_stats`` starts from
+_STATS = ("program_calls", "waits_program", "waits_host", "rows", "lanes_replayed", "waits_wide", "wide_blocks")
+
+
 def _merge_stats(acc: dict, stats: dict) -> None:
     """Fold one run's placement stats into the caller's accumulator:
     counters add, per-lane lists replace, the timeline axis keeps its max."""
@@ -712,7 +756,7 @@ def run_cluster_batched(
     workflows: list[WorkflowTrace],
     policies: tuple[str, ...],
     n_nodes: int = 4,
-    node_mib: float = 128 * 1024.0,
+    node_mib=128 * 1024.0,
     train_frac: float = 0.5,
     max_tasks_per_type: int = 40,
     min_executions: int = 10,
@@ -733,17 +777,21 @@ def run_cluster_batched(
     (``device_timeline.schedule_epoch``): each dispatch places a whole window
     of attempt rows with the event clock and release heap in the program's
     carry, so blocked rows wait in-program instead of paying a host
-    round-trip per wait.  Returns {policy: ClusterResult} with the same
-    per-task records as the sequential oracle
+    round-trip per wait.  ``node_mib`` is one float (``n_nodes`` equal
+    nodes) or a capacity per node.  Returns {policy: ClusterResult} with the
+    same per-task records as the sequential oracle
     (tests/test_cluster_placement.py asserts exact (node, start, end) parity
     per attempt; tests/test_cluster_congested.py stresses the wait path).
 
     ``placement_stats``, when passed, accumulates ``{"program_calls",
-    "waits_program", "waits_host", "rows", "lanes_replayed"}`` for the
-    bench (``waits_program`` = rows whose wait was resolved inside the
-    device program; ``waits_host`` = last-resort host clock walks, 0 in
-    practice; ``lanes_replayed`` = sweep lanes that overflowed and were
-    replayed through the windows engine).  The call's spans and counters
+    "waits_program", "waits_host", "rows", "lanes_replayed", "waits_wide",
+    "wide_blocks"}`` for the bench (``waits_program`` = rows whose wait was
+    resolved inside the device program; ``waits_host`` = last-resort host
+    clock walks, 0 in practice; ``lanes_replayed`` = sweep lanes that
+    overflowed and were replayed through the windows engine;
+    ``waits_wide`` / ``wide_blocks`` = the windows engine's waits and
+    congested entries by rows wider than the smallest node, see
+    ``_place_rows_batched``).  The call's spans and counters
     (``repro.obs``) are kept as ``obs.last("sched.call")``.
 
     k-Segments policies run with progressive error offsets (the device
@@ -774,10 +822,11 @@ def run_cluster_batched(
     if placement not in ("auto", "sweep", "windows"):
         raise ValueError(f"unknown placement engine: {placement!r}")
     policies = tuple(policies)
+    caps = node_capacities(node_mib, n_nodes)
     queue, rows = batched_rows(
         workflows,
         policies,
-        node_mib,
+        caps,
         train_frac,
         max_tasks_per_type,
         min_executions,
@@ -785,7 +834,7 @@ def run_cluster_batched(
         max_attempts,
         ladder_x64,
     )
-    stats = {"program_calls": 0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
+    stats = dict.fromkeys(_STATS, 0)
     placed: dict[str, tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
     if placement == "sweep" or (
         placement == "auto" and _auto_sweep(rows, policies, n_nodes, placement_window)
@@ -795,7 +844,7 @@ def run_cluster_batched(
         node_s, start_s, _, _, dead = sweep_schedule(
             [rows[p][:4] for p in policies],
             [n_nodes] * len(policies),
-            [int(budget_units(node_mib))] * len(policies),
+            [budget_units(caps)] * len(policies),
             stats=stats,
         )
         stats["lanes_replayed"] += int(np.sum(dead))
@@ -812,7 +861,7 @@ def run_cluster_batched(
         if p not in placed:
             bnd_rows, val_rows, run_rows, probe_rows = rows[p][:4]
             placed[p] = _place_rows_batched(
-                bnd_rows, val_rows, run_rows, probe_rows, n_nodes, node_mib, placement_window, stats
+                bnd_rows, val_rows, run_rows, probe_rows, n_nodes, caps, placement_window, stats
             )
     results: dict[str, ClusterResult] = {}
     for p in policies:
@@ -829,7 +878,7 @@ def run_cluster_sweep(
     corpora: dict[str, list[WorkflowTrace]] | list[WorkflowTrace],
     policies: tuple[str, ...],
     node_counts: tuple[int, ...] = (4,),
-    node_mib: float = 128 * 1024.0,
+    node_mib=128 * 1024.0,
     train_frac: float = 0.5,
     max_tasks_per_type: int = 40,
     min_executions: int = 10,
@@ -845,9 +894,11 @@ def run_cluster_sweep(
     Every design point becomes one lane of the vmapped whole-run program
     (``device_timeline.sweep_schedule``): per-lane event clocks, node
     timelines and release heaps are stacked along a leading lane axis, with
-    heterogeneous node counts masked up to the grid maximum.  Retry ladders
-    are computed once per corpus (they depend on ``node_mib``, not the node
-    count) and shared across that corpus's lanes.  Each lane's placements
+    heterogeneous node counts masked up to the grid maximum.  ``node_mib``
+    is one float or a capacity per node of the largest count; a lane of n
+    nodes runs the first n.  Retry ladders are computed once per corpus
+    and largest node of a lane (they depend on the cap, not the node count)
+    and shared across those lanes.  Each lane's placements
     carry the sequential oracle's exact (node, start, end) semantics — the
     same correctness bar as ``run_cluster_batched`` — and a lane that
     overflows the program's bounded timeline axis is replayed through the
@@ -863,45 +914,46 @@ def run_cluster_sweep(
     if not isinstance(corpora, dict):
         corpora = {"": corpora}
     policies = tuple(policies)
-    stats = {"program_calls": 0, "waits_program": 0, "waits_host": 0, "rows": 0, "lanes_replayed": 0}
-    lane_rows, lane_nodes, lane_keys = [], [], []
-    meta: dict[str, tuple[list, dict]] = {}
+    caps = node_capacities(node_mib, max(node_counts))
+    stats = dict.fromkeys(_STATS, 0)
+    lane_rows, lane_nodes, lane_budgets, lane_keys = [], [], [], []
+    meta: dict[tuple[str, float], tuple[list, dict]] = {}
     for cname, wfs in corpora.items():
-        queue, rows = batched_rows(
-            wfs,
-            policies,
-            node_mib,
-            train_frac,
-            max_tasks_per_type,
-            min_executions,
-            ksegments_config,
-            max_attempts,
-            ladder_x64,
-        )
-        meta[cname] = (queue, rows)
         for p in policies:
             for nn in node_counts:
-                lane_rows.append(rows[p][:4])
+                key = (cname, float(caps[:nn].max()))
+                if key not in meta:
+                    meta[key] = batched_rows(
+                        wfs,
+                        policies,
+                        key[1],
+                        train_frac,
+                        max_tasks_per_type,
+                        min_executions,
+                        ksegments_config,
+                        max_attempts,
+                        ladder_x64,
+                    )
+                lane_rows.append(meta[key][1][p][:4])
                 lane_nodes.append(int(nn))
-                lane_keys.append((cname, p, int(nn)))
-    node_s, start_s, _, _, dead = sweep_schedule(
-        lane_rows, lane_nodes, [int(budget_units(node_mib))] * len(lane_rows), stats=stats
-    )
+                lane_budgets.append(budget_units(caps[:nn]))
+                lane_keys.append((key, p, int(nn)))
+    node_s, start_s, _, _, dead = sweep_schedule(lane_rows, lane_nodes, lane_budgets, stats=stats)
     stats["lanes_replayed"] += int(np.sum(dead))
     results: dict[tuple[str, str, int], ClusterResult] = {}
-    for s, (cname, p, nn) in enumerate(lane_keys):
-        queue, rows = meta[cname]
+    for s, (key, p, nn) in enumerate(lane_keys):
+        queue, rows = meta[key]
         bnd_rows, val_rows, run_rows, probe_rows, counts, waste = rows[p]
         stats["rows"] += len(run_rows)
         if dead[s]:
             node, start, end = _place_rows_batched(
-                bnd_rows, val_rows, run_rows, probe_rows, nn, node_mib, placement_window, stats
+                bnd_rows, val_rows, run_rows, probe_rows, nn, caps[:nn], placement_window, stats
             )
         else:
             r = len(run_rows)
             node, start = node_s[s, :r], start_s[s, :r]
             end = start + run_rows
-        results[(cname, p, nn)] = _policy_result(p, queue, counts, waste, node, start, end)
+        results[(key[0], p, nn)] = _policy_result(p, queue, counts, waste, node, start, end)
     if placement_stats is not None:
         _merge_stats(placement_stats, stats)
     return results

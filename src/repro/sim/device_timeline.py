@@ -231,7 +231,8 @@ def _fit_tables(tl_t, tl_d, base0):
 def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
     """(C, N) fit masks of one row at clocks ``cc`` (C,) — the range-max
     formulation of the scalar ``demand_exceeds`` pass over the full-duration
-    window [c, c + pd), decision-identical to the dense per-event scan:
+    window [c, c + pd), each node against its own capacity ``budget`` (N,),
+    decision-identical to the dense per-event scan:
 
     * own probes (the clock + the row's switch instants): profile reads at
       ``#(t <= p)`` via binary search instead of dense compare-counts —
@@ -298,8 +299,9 @@ def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
     l0 = cnt_all[:, n_own + C : n_own + 2 * C]
     cs0 = jnp.concatenate([base0[:, None], csm], axis=1)
     prof_own = jnp.take_along_axis(cs0, cnt, axis=1).reshape(N, C, k + 1)
+    cap = budget[:, None, None]  # each node's own capacity
     over = jnp.any(
-        own_ok[None, :, :] & (prof_own + cand_own[None, :, :] > budget), axis=2
+        own_ok[None, :, :] & (prof_own + cand_own[None, :, :] > cap), axis=2
     )  # (N, C)
     # in-window event suffixes: [l_j, r) index ranges per (clock, segment)
     if k > 1:
@@ -308,7 +310,7 @@ def _fit_probes(tl_t, csm, qmax, base0, b, v, pd, budget, cc, nmask=None):
     else:
         ls = l0[:, :, None]  # (N, C, k)
     m = qmax(ls, r_win)  # (N, C, k) suffix maxima over [l_j, r)
-    over_ev = jnp.any(m + v[None, None, :] > budget, axis=2)
+    over_ev = jnp.any(m + v[None, None, :] > cap, axis=2)
     fit = ~(over | over_ev)
     if nmask is not None:
         fit &= nmask[:, None]
@@ -390,6 +392,7 @@ def _window_program_shared(n_nodes: int):
     three fused (N, Pp) passes.  Decisions are identical to
     ``_window_program_pernode`` — extra probes only re-sample step
     functions — the host picks whichever costs less for the call's shapes.
+    ``cap`` (N,) is each node's capacity in demand units.
     """
 
     def window_shared(P, prof, now, ends, rels, bnd, val, valid, cap):
@@ -410,7 +413,7 @@ def _window_program_shared(n_nodes: int):
         def step(carry, row):
             extra, blocked = carry  # extra: (N, Pp) this epoch's placed demand
             a, d, m, ok = row
-            over = jnp.any(m[None, :] & (prof + extra + a[None, :] > cap), axis=-1)  # (N,)
+            over = jnp.any(m[None, :] & (prof + extra + a[None, :] > cap[:, None]), axis=-1)  # (N,)
             fit = ~over
             can = ok & ~blocked & jnp.any(fit)
             node = jnp.argmax(fit)  # first-fit: lowest fitting node index
@@ -434,7 +437,7 @@ def _window_program_pernode(n_nodes: int):
     window of queued attempt rows sharing the epoch clock: per candidate the
     fit check is the scalar ``NodeState.fits`` — any probe in the right-open
     fit window where node profile + earlier in-window placements + own
-    allocation exceeds capacity(+eps) — evaluated against every node at
+    allocation exceeds that node's capacity ``cap[n]`` — evaluated against every node at
     once, with first-fit the lowest fitting node index.  A ``lax.scan``
     threads within-epoch sequencing: a placed candidate's demand is added to
     its node's carry, exactly as if the host had committed it before probing
@@ -469,9 +472,9 @@ def _window_program_pernode(n_nodes: int):
             S, blocked = carry  # S: (N, Pp) profile + this epoch's placed demand
             b, v, sw_r, live_r, st_r, end, rel, ok = row
             m = fin & (P < end)  # right-open fit window
-            over = jnp.any(m & (S + v[0] > cap), axis=-1)  # (N,)
+            over = jnp.any(m & (S + v[0] > cap[:, None]), axis=-1)  # (N,)
             for j in range(1, k):
-                over |= jnp.any(m & (off > b[j - 1]) & (S + v[j] > cap), axis=-1)
+                over |= jnp.any(m & (off > b[j - 1]) & (S + v[j] > cap[:, None]), axis=-1)
             fit = ~over
             can = ok & ~blocked & jnp.any(fit)
             node = jnp.argmax(fit)  # first-fit: lowest fitting node index
@@ -515,7 +518,8 @@ def first_fit_window(
         (w,) fit-window durations (the full predicted duration).
       profiles: per node, the cached ``(event keys, cumulative demand)``
         arrays of its ``Timeline`` (``NodeState.profile_arrays``).
-      capacity_budget: the fits budget (``NodeState.budget``).
+      capacity_budget: the capacity per node in demand units
+        (``NodeState.budget`` of each node; one number is a uniform pool).
       window_bucket: rows are padded to this static size.
 
     Probes are the keys where combined step demand can rise: the clock,
@@ -599,7 +603,7 @@ def _window_inputs(now, bnd, val, run_times, probe_times, profiles, capacity_bud
         pad_rows(bnd.astype(i32), Wb, NEVER),
         pad_rows(val.astype(i32), Wb, 0),
         pad_rows(np.ones(w, dtype=bool), Wb, False),
-        i32(capacity_budget),
+        np.full(N, capacity_budget, dtype=i32),
     )
     return ("shared" if use_shared else "pernode"), program, args
 
@@ -619,7 +623,7 @@ def _epoch_layout(shape: tuple) -> tuple[tuple, int]:
     ``((name, slice, dims), ...)`` in buffer order and the buffer's length,
     at offsets fixed by ``shape = (N, L, H, Wb, k)``."""
     N, L, H, Wb, k = shape
-    dims = ((N, L), (N, L), (N,), (H,), (), (), (Wb, k), (Wb, k), (Wb,), (Wb,), (Wb,), ())
+    dims = ((N, L), (N, L), (N,), (H,), (), (), (Wb, k), (Wb, k), (Wb,), (Wb,), (Wb,), (N,))
     fields, at = [], 0
     for name, d in zip(_EPOCH_FIELDS, dims):
         fields.append((name, slice(at, at + math.prod(d)), d))
@@ -658,12 +662,14 @@ def _schedule_program(buf, shape):
         up to the kill); pdur: (W,) fit-check window durations (the
         scheduler probes the full predicted duration — it cannot know an
         attempt will die early); valid: (W,) real-row mask (0/1).
-      budget: the fits budget (``NodeState.budget``).
+      budget: (N,) the capacity per node in demand units (each node's
+        ``NodeState.budget``).
 
     A ``lax.scan`` walks the rows in queue order.  Per row, a bounded
     ``while_loop`` mirrors the sequential oracle's ``_find_slot``: probe
-    every node at the current clock (the scalar ``demand_exceeds``
-    expressions, evaluated against the carried timelines); when no node
+    every node at the current clock against its own capacity (the scalar
+    ``demand_exceeds`` expressions, evaluated against the carried
+    timelines); when no node
     fits, pop the earliest pending completion, advance the clock to it and
     re-probe.  A placed row's events are spliced into its node's carried
     timeline (``side="right"`` tie order, identical to the host
@@ -814,7 +820,8 @@ def schedule_epoch(
       node_events: per node, ``Timeline.events()`` — the sorted event keys
         and demand deltas of its reservation profile.
       pending: (E,) completion keys still in the scheduler's wait heap.
-      capacity_budget: the fits budget (``NodeState.budget``).
+      capacity_budget: the capacity per node in demand units
+        (``NodeState.budget`` of each node; one number is a uniform pool).
       window_bucket: rows are padded to this static size; timeline/heap axes
         are bucketed so compiled shapes stay bounded.
       probe_times: (w,) fit-check window durations — the full predicted
@@ -941,8 +948,9 @@ def _sweep_lane(bnd, val, run, pdur, valid, nmask, budget, *, L):
       row scan is unrolled: each step is many small (N, ...) vector ops, so
       on CPU the scan bookkeeping dominates an un-unrolled body.
 
-    Per-lane node counts are handled by ``nmask`` (invalid nodes never fit);
-    rows are ``NEVER``/False padded to the lane grid's shared shape.  ``overflow``
+    Per-lane node counts are handled by ``nmask`` (invalid nodes never fit)
+    and ``budget`` (N,) is the lane's capacity per node; rows are
+    ``NEVER``/False padded to the lane grid's shared shape.  ``overflow``
     reports a node timeline outgrowing L — the commits' ``mode="drop"``
     splices silently lose events past it, so the host re-dispatches with a
     doubled axis.  ``dead`` is a drained heap with no fit (unreachable for
@@ -1451,7 +1459,7 @@ def _sweep_program(bnd, val, run, pdur, valid, nmask, budget, *, L):
 def sweep_schedule(
     lane_rows: list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]],
     lane_nodes: list[int],
-    lane_budgets: list[float],
+    lane_budgets: list,
     *,
     timeline_floor: int = 256,
     timeline_cap: int = 8192,
@@ -1465,7 +1473,9 @@ def sweep_schedule(
         node-capped, run = occupancy, probe = fit-check duration).
       lane_nodes: per lane, its cluster's node count (lanes may differ; the
         program masks nodes past each lane's count).
-      lane_budgets: per lane, the fits budget (``NodeState.budget``).
+      lane_budgets: per lane, the capacity per node in demand units
+        (``NodeState.budget`` of each of the lane's nodes; one number is a
+        uniform pool).
       timeline_floor/timeline_cap: initial / maximal per-node timeline axis.
         A lane whose concurrent future events outgrow the axis flags
         overflow and the whole grid re-dispatches with the axis doubled
@@ -1496,7 +1506,8 @@ def sweep_schedule(
         pdur = np.zeros((S, R), dtype=np.int32)
         valid = np.zeros((S, R), dtype=bool)
         nmask = np.zeros((S, N), dtype=bool)
-        for s, ((b, v, rr, pr), nn) in enumerate(zip(lane_rows, lane_nodes)):
+        budget = np.zeros((S, N), dtype=np.int32)
+        for s, ((b, v, rr, pr), nn, cap) in enumerate(zip(lane_rows, lane_nodes, lane_budgets)):
             r, k = b.shape
             bnd[s, :r, :k] = b
             val[s, :r, :k] = v
@@ -1506,7 +1517,7 @@ def sweep_schedule(
             pdur[s, :r] = pr
             valid[s, :r] = True
             nmask[s, :nn] = True
-        budget = np.asarray(lane_budgets, dtype=np.int32)
+            budget[s, :nn] = cap
         hint_key = (S, R, kmax, N)
         L = max(
             bucket_size(_SWEEP_W * (kmax + 2), floor=timeline_floor),

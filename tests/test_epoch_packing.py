@@ -2,9 +2,10 @@
 
 ``schedule_epoch`` crosses the host-device boundary once each way per
 dispatch: ``_epoch_inputs`` writes all twelve inputs into one flat int32
-buffer at offsets fixed by the static shape ``(N, L, H, Wb, k)``, and
-``_schedule_program`` returns its seven outputs as one int32 vector.  These
-tests pin the layout against a plain construction of the twelve arrays, the
+buffer at offsets fixed by the static shape ``(N, L, H, Wb, k)`` (the
+capacity per node, ``budget``, as N values), and ``_schedule_program``
+returns its seven outputs as one int32 vector.  These tests pin the layout
+against a plain construction of the twelve arrays, the
 program's operand and result, and that the shape tuple (never the buffer's
 length) selects the compiled variant.
 """
@@ -71,8 +72,10 @@ def _fields_reference(now, bnd, val, run, node_events, pending, budget, Wb, prob
         "run": np.zeros(Wb, np.int32),
         "pdur": np.zeros(Wb, np.int32),
         "valid": np.zeros(Wb, np.int32),
-        "budget": np.int32(budget),
+        "budget": np.zeros(N, np.int32),
     }
+    for n in range(N):
+        f["budget"][n] = budget if np.ndim(budget) == 0 else budget[n]
     for n, ((t, d), c) in enumerate(zip(node_events, cut)):
         f["base0"][n] = sum(int(x) for x in d[:c])
         for j in range(c, len(t)):
@@ -94,8 +97,14 @@ def _fields_reference(now, bnd, val, run, node_events, pending, budget, Wb, prob
         dict(n_nodes=3, per_node=6, n_pending=5, w=8, k=2, Wb=8, now=2.5, probe=True),
         # a wide window of four-step rows on sixteen nodes
         dict(n_nodes=16, per_node=4, n_pending=40, w=20, k=4, Wb=32, now=4.0, probe=True),
+        # a capacity per node: three nodes of three sizes
+        dict(n_nodes=3, per_node=6, n_pending=5, w=8, k=2, Wb=8, now=2.5, probe=True,
+             budget=[32_768, 8_192, 16_384]),
+        # sixteen nodes of the mixed pool's four sizes, in its order (GiB)
+        dict(n_nodes=16, per_node=4, n_pending=40, w=20, k=4, Wb=32, now=4.0, probe=True,
+             budget=[g * 1024 for g in (64, 32, 64, 128, 64, 32, 64, 96, 64, 32, 64, 32, 64, 32, 64, 64)]),
     ],
-    ids=["empty", "folded", "wide"],
+    ids=["empty", "folded", "wide", "pernode", "mixed16"],
 )
 def test_epoch_inputs_unpack_bit_for_bit(case):
     bnd, val, run, probe = _rows(case["w"], case["k"], seed=case["w"])
@@ -103,9 +112,10 @@ def test_epoch_inputs_unpack_bit_for_bit(case):
     pending = np.random.default_rng(2).permutation(time_keys(np.arange(case["n_pending"]) * 0.75 + 1.0))
     now = int(time_keys(case["now"]))
     probe = probe if case["probe"] else None
-    buf, shape = _epoch_inputs(now, bnd, val, run, node_events, pending, 12_345, case["Wb"], probe)
+    budget = case.get("budget", 12_345)
+    buf, shape = _epoch_inputs(now, bnd, val, run, node_events, pending, budget, case["Wb"], probe)
     want_shape, want = _fields_reference(
-        now, bnd, val, run, node_events, pending, 12_345, case["Wb"], run if probe is None else probe
+        now, bnd, val, run, node_events, pending, budget, case["Wb"], run if probe is None else probe
     )
     assert shape == want_shape
     assert buf.dtype == np.int32 and buf.shape == (_epoch_layout(shape)[1],)

@@ -112,7 +112,7 @@ def test_sweep_schedule_compiles(chip_compile):
     hlo = chip_compile(
         functools.partial(_sweep_program, L=L),
         ((S, R, K), I32), ((S, R, K), I32), ((S, R), I32), ((S, R), I32), ((S, R), BOOL),
-        ((S, N), BOOL), ((S,), I32),
+        ((S, N), BOOL), ((S, N), I32),
     )
     assert "tpu_custom_call" in hlo
 
@@ -139,7 +139,7 @@ def test_first_fit_window_compiles(chip_compile, variant):
     P = ((Pp,), I32) if variant == "shared" else ((N, Pp), I32)
     chip_compile(
         program, P, ((N, Pp), I32), ((), I32), ((W,), I32), ((W,), I32),
-        ((W, K), I32), ((W, K), I32), ((W,), BOOL), ((), I32),
+        ((W, K), I32), ((W, K), I32), ((W,), BOOL), ((N,), I32),
     )
 
 
